@@ -74,9 +74,25 @@ register_backend("cpu", cpu_apply_matrix)
 # builds, else numpy (which beats XLA-on-CPU for this workload). All
 # backends are byte-identical; selection is purely a performance
 # choice, so a process-wide cached default is safe.
+#
+# Auto-detect INITIALISES the accelerator (jax.devices()), and a chip
+# belongs to one process at a time: only the daemon that runs EC math
+# (the volume server) may resolve the default. Admin-side processes
+# (shell, upload, benchmark, filer, s3) name host_backend() explicitly.
 
 _default_backend = ""  # "" = undecided; resolved lazily
 _LAZY_BACKENDS = ("tpu", "native")  # registered on first resolve
+
+
+def host_backend() -> str:
+    """The best codec that never touches an accelerator: the native
+    SIMD shim when it builds, else numpy."""
+    try:
+        from seaweedfs_tpu.ec import codec_native  # noqa: F401
+
+        return "native"
+    except ImportError:
+        return "cpu"
 
 
 def default_backend() -> str:
@@ -94,18 +110,15 @@ def default_backend() -> str:
     if not _default_backend:
         try:
             import jax
-
-            has_accel = any(d.platform != "cpu" for d in jax.devices())
-            _default_backend = "tpu" if has_accel else ""
-        except Exception:
-            pass
-        if not _default_backend:
-            try:
-                from seaweedfs_tpu.ec import codec_native  # noqa: F401
-
-                _default_backend = "native"
-            except ImportError:
-                _default_backend = "cpu"
+        except ImportError:
+            jax = None  # no JAX installed: the one thing that means "no accelerator"
+        # anything jax.devices() raises (the chip could not be
+        # initialised, e.g. another process holds it) propagates: a
+        # silent host codec on a TPU host would hide the device
+        if jax is not None and any(d.platform != "cpu" for d in jax.devices()):
+            _default_backend = "tpu"
+        else:
+            _default_backend = host_backend()
     return _default_backend
 
 
@@ -156,9 +169,13 @@ class ReedSolomon:
 
     @staticmethod
     def _resolve_backend(name: str) -> Callable[[np.ndarray, np.ndarray], np.ndarray]:
-        if name == "tpu" and "tpu" not in _BACKENDS:
+        if name == "tpu":
             # lazy import so CPU-only users never touch jax
-            from seaweedfs_tpu.ec import codec_tpu  # noqa: F401
+            from seaweedfs_tpu.ec import codec_tpu
+
+            # initialise the backend now and say (once per process)
+            # which platform and kernel arm "tpu" means here
+            codec_tpu.device_report()
         if name == "native" and "native" not in _BACKENDS:
             from seaweedfs_tpu.ec import codec_native  # noqa: F401
         try:

@@ -41,6 +41,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from seaweedfs_tpu.ec import gf256
 from seaweedfs_tpu.ec.codec import register_backend
+from seaweedfs_tpu.ec.compile_cache import place_compile_cache
+from seaweedfs_tpu.util import wlog
+
+place_compile_cache()
 
 
 def gf_matrix_to_bits(matrix: np.ndarray) -> np.ndarray:
@@ -428,16 +432,35 @@ def _swar_tn(n32: int) -> int:
     return tn
 
 
+@functools.cache
+def device_report() -> dict:
+    """What the "tpu" codec means in THIS process: the devices JAX
+    found and the kernel arm they select — the SWAR kernel lowers via
+    Mosaic-TPU (pltpu.VMEM block specs), so on any other platform the
+    portable bit-matmul serves instead. Resolved once (initialising
+    the backend; whatever that raises propagates — a chip that cannot
+    be reached must not read as "no chip") and logged once, so the
+    choice is visible in the node's own output."""
+    devices = jax.devices()
+    platform = devices[0].platform
+    report = {
+        "platform": platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "arm": "swar" if platform == "tpu" else "bit-matmul",
+    }
+    wlog.info(
+        "ec codec tpu: platform=%s device_kind=%r devices=%d arm=%s",
+        platform, report["device_kind"], len(devices), report["arm"],
+    )
+    return report
+
+
 def _on_tpu() -> bool:
-    """True only on a real TPU backend: the SWAR kernel lowers via
-    Mosaic-TPU (pltpu.VMEM block specs), so on any other accelerator
-    (GPU) the portable bit-matmul path must serve instead. Distinct
+    """True only on a real TPU backend (see device_report). Distinct
     from codec.default_backend()'s any-accelerator probe, which picks
     the *backend name*; this picks the kernel within it."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return device_report()["platform"] == "tpu"
 
 
 def swar_apply_matrix_u32(
@@ -502,6 +525,14 @@ def _bucket_len(n: int) -> int:
     return max(1024, 1 << (n - 1).bit_length())
 
 
+# host-interop calls per kernel arm, for the node's status report: the
+# serving paths (degraded reads, scrub parity verify) reach the device
+# only through tpu_apply_matrix, and which arm served them is
+# otherwise invisible (unlocked: a racing increment may be lost, which
+# a did-it-run count tolerates)
+APPLY_CALLS = {"swar": 0, "bit-matmul": 0}
+
+
 def tpu_apply_matrix(matrix: np.ndarray, inputs: np.ndarray) -> np.ndarray:
     """Host-interop backend for codec.ReedSolomon: numpy in, numpy out.
 
@@ -516,7 +547,9 @@ def tpu_apply_matrix(matrix: np.ndarray, inputs: np.ndarray) -> np.ndarray:
         padded[:, :n] = inputs
         inputs = padded
     if nb >= _SWAR_MIN_BYTES and _on_tpu():
+        APPLY_CALLS["swar"] += 1
         return swar_apply_matrix_host(matrix, inputs)[:, :n]
+    APPLY_CALLS["bit-matmul"] += 1
     out = apply_matrix_bits(_cached_bits(matrix), jnp.asarray(inputs))
     return np.asarray(jax.device_get(out))[:, :n]
 

@@ -37,7 +37,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from seaweedfs_tpu.ec.compile_cache import place_compile_cache
 from seaweedfs_tpu.util import crc as _crc
+
+place_compile_cache()
 
 
 def crc_supported(nbytes: int) -> bool:

@@ -213,6 +213,8 @@ def write_ec_files(
     for block in (large_block_size, small_block_size):
         if block % buffer_size != 0 and buffer_size % block != 0:
             raise ValueError("buffer size must tile the block sizes")
+    if stats is not None:
+        stats["driver"] = "classic"
 
     import time as _time
 
@@ -504,6 +506,8 @@ def rebuild_ec_files(
             want_crcs=want_crcs,
         )
     buffer_size = buffer_size or SMALL_BLOCK_SIZE
+    if stats is not None:
+        stats["driver"] = "classic"
     present, missing = shard_presence(base_file_name)
     if not missing:
         return []

@@ -29,10 +29,8 @@ version removes both bottlenecks:
     writes make tile COMPLETION ORDER irrelevant to the bytes — every
     byte offset is written exactly once — so the pool needs no
     re-sequencing to stay byte-identical to the synchronous drivers;
-  * the in-flight window is 3 dispatched-but-unfetched tiles deep (on
-    TPU hosts the H2D stage donates its staging buffer to XLA, see
-    _tpu_encode_fns), so H2D, kernel, and D2H genuinely
-    triple-overlap.
+  * the in-flight window is 3 dispatched-but-unfetched tiles deep, so
+    H2D, kernel, and D2H genuinely triple-overlap.
 
 Only the [4, N] parity ever crosses device→host on encode — the ten
 data-shard files are byte copies of the blocks read from the .dat,
@@ -402,7 +400,8 @@ def stream_write_ec_files(
     memory is bounded and allocator churn stays out of the hot loop."""
     if (parity_fn is None) != (fetch_fn is None):
         raise ValueError("parity_fn and fetch_fn must be injected together")
-    if parity_fn is None:
+    device_stage = parity_fn is None
+    if device_stage:
         parity_fn, fetch_fn = _tpu_encode_fns(want_crcs=want_crcs)
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
     writer_threads = writer_threads or DEFAULT_WRITER_THREADS
@@ -689,6 +688,9 @@ def stream_write_ec_files(
                     )
                     stats["pipeline_depth"] = depth
                     stats["ring_slots"] = ring.slots
+                    stats["driver"] = _driver_name(device_stage)
+                    if hasattr(parity_fn, "arms"):
+                        stats["arms"] = dict(parity_fn.arms)
                     if (
                         want_crcs
                         and ok
@@ -775,7 +777,8 @@ def stream_rebuild_ec_files(
     shard set survives a crash — docs/ANALYSIS.md v3)."""
     if (rebuild_fn is None) != (fetch_fn is None):
         raise ValueError("rebuild_fn and fetch_fn must be injected together")
-    if rebuild_fn is None:
+    device_stage = rebuild_fn is None
+    if device_stage:
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs)
     # rebuild tiles read one span from each of 10 FILES. Re-swept with
     # the staging ring (BENCH_r12): LOCAL rebuilds want fine tiles —
@@ -1098,6 +1101,9 @@ def stream_rebuild_ec_files(
                     )
                     stats["pipeline_depth"] = depth
                     stats["ring_slots"] = ring.slots
+                    stats["driver"] = _driver_name(device_stage)
+                    if hasattr(rebuild_fn, "arms"):
+                        stats["arms"] = dict(rebuild_fn.arms)
                     if (
                         want_crcs
                         and ok
@@ -1240,6 +1246,21 @@ def _crc_ok(step: int, want_crcs: bool) -> bool:
     return want_crcs and crc_kernel.crc_supported(step)
 
 
+def _driver_name(device_stage: bool) -> str:
+    """stats["driver"] of the single-volume stream drivers: which side
+    of the staging ring applies the matrix — the self-provisioned
+    device stage pair, or one the caller injected (a host codec)."""
+    return "stream-device" if device_stage else "stream-host"
+
+
+def _new_arms() -> dict:
+    """Dispatches per kernel arm, counted by the device stage pair on
+    the dispatcher thread and published as stats["arms"] — how a
+    caller sees that the SWAR kernel ran and not the bit-matmul the
+    same code takes off-TPU or on an odd tail."""
+    return {"swar+crc": 0, "swar": 0, "bit-matmul": 0}
+
+
 def _tpu_encode_fns(want_crcs: bool = False):
     import jax
     import jax.numpy as jnp
@@ -1247,35 +1268,37 @@ def _tpu_encode_fns(want_crcs: bool = False):
     from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
 
     kern = TpuCodecKernels(DATA_SHARDS, PARITY_SHARDS)
-    # donate the H2D staging buffer: the [10, n32] tile is dead the
-    # moment the kernel has read it, and with 3 tiles in flight XLA
-    # recycling the donated extent keeps the deepened window from
-    # growing HBM residency per tile
-    encode_u32_don = jax.jit(
-        lambda u32: kern.encode_u32(u32), donate_argnums=0
-    )
+    # no donate_argnums: no output has the [10, n32] tile's shape, so
+    # XLA cannot reuse its buffer ("Some donated buffers were not
+    # usable" on the chip, PR 21); the tile is freed once the kernel
+    # has read it either way, because nothing keeps a reference
+    encode_u32 = jax.jit(kern.encode_u32)
     # fused encode+CRC program (ec/crc_kernel.py rides the same
     # dispatch): parity AND all 14 per-row CRCs come back from one
     # device pass, so the host never re-reads parity bytes to
     # checksum them
-    encode_u32_crc_don = jax.jit(
-        lambda u32: kern.encode_u32_crc(u32), donate_argnums=0
-    )
+    encode_u32_crc = jax.jit(kern.encode_u32_crc)
+
+    arms = _new_arms()
 
     def parity_fn(tile: np.ndarray):
         swar = _swar_ok(tile.shape[1])
         fused_crc = _crc_ok(tile.shape[1], want_crcs)
         if swar and fused_crc:
             u32 = jnp.asarray(tile.view(np.uint32))  # async H2D
-            out = encode_u32_crc_don(u32)
+            out = encode_u32_crc(u32)
+            arms["swar+crc"] += 1
         elif swar:
             u32 = jnp.asarray(tile.view(np.uint32))  # async H2D
-            out = encode_u32_don(u32)  # async dispatch
+            out = encode_u32(u32)  # async dispatch
+            arms["swar"] += 1
         else:
             out = kern.encode(jnp.asarray(tile))
             fused_crc = False
+            arms["bit-matmul"] += 1
         return out, swar, fused_crc
 
+    parity_fn.arms = arms
     return parity_fn, _fetch
 
 
@@ -1286,31 +1309,29 @@ def _tpu_rebuild_fns(want_crcs: bool = False):
     from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
 
     kern = TpuCodecKernels(DATA_SHARDS, PARITY_SHARDS)
-    recon_don = jax.jit(
-        lambda s, t, u32: kern.reconstruct_u32(s, t, u32),
-        static_argnums=(0, 1),
-        donate_argnums=2,
-    )
-    recon_crc_don = jax.jit(
-        lambda s, t, u32: kern.reconstruct_u32_crc(s, t, u32),
-        static_argnums=(0, 1),
-        donate_argnums=2,
-    )
+    recon = jax.jit(kern.reconstruct_u32, static_argnums=(0, 1))
+    recon_crc = jax.jit(kern.reconstruct_u32_crc, static_argnums=(0, 1))
+
+    arms = _new_arms()
 
     def rebuild_fn(survivors, targets, tile: np.ndarray):
         swar = _swar_ok(tile.shape[1])
         fused_crc = _crc_ok(tile.shape[1], want_crcs)
         if swar and fused_crc:
             u32 = jnp.asarray(tile.view(np.uint32))
-            out = recon_crc_don(tuple(survivors), tuple(targets), u32)
+            out = recon_crc(tuple(survivors), tuple(targets), u32)
+            arms["swar+crc"] += 1
         elif swar:
             u32 = jnp.asarray(tile.view(np.uint32))
-            out = recon_don(tuple(survivors), tuple(targets), u32)
+            out = recon(tuple(survivors), tuple(targets), u32)
+            arms["swar"] += 1
         else:
             out = kern.reconstruct(survivors, targets, jnp.asarray(tile))
             fused_crc = False
+            arms["bit-matmul"] += 1
         return out, swar, fused_crc
 
+    rebuild_fn.arms = arms
     return rebuild_fn, _fetch
 
 
@@ -1575,6 +1596,9 @@ def _stream_batch_chunk(
     idx_lock = threading.Lock()
     idx_iter = iter(range(rounds))
     out_fds: list[list[int]] = []
+    # fewest mesh devices that held part of a round's batch: the
+    # sharding can silently land everything on device 0
+    held = vol_axis * stripe
 
     def reader():
         fds = [os.open(base + ".dat", os.O_RDONLY) for base in bases]
@@ -1705,6 +1729,7 @@ def _stream_batch_chunk(
             # staging: the u32 lane view is free host-side; device_put
             # lays the batch out P('vol', None, 'stripe') over the mesh
             vols = codec.shard_volumes(buf3.view(np.uint32))
+            held = min(held, codec.devices_holding(vols))
             t1 = time.perf_counter()
             handle = (
                 codec.encode_batch_u32_crc(vols)
@@ -1759,7 +1784,9 @@ def _stream_batch_chunk(
                     stats["pipeline_depth"] = depth
                     stats["ring_slots"] = ring.slots
                     stats["batch_volumes"] = b
-                    stats["mesh"] = {"vol": vol_axis, "stripe": stripe}
+                    stats["mesh"] = {
+                        **codec.report(), "devices_per_round": held
+                    }
                     if (
                         want_crcs
                         and ok
@@ -2049,6 +2076,7 @@ def _rebuild_batch_chunk(
     idx_lock = threading.Lock()
     idx_iter = iter(range(rounds))
     out_fds: list[dict[int, int]] = []
+    held = vol_axis * stripe  # see _stream_batch_chunk
     read_local = EC_REPAIR_BYTES_READ.labels("local")
 
     def reader():
@@ -2175,6 +2203,7 @@ def _rebuild_batch_chunk(
             r, slot_id, buf3 = item
             t0 = time.perf_counter()
             vols = codec.shard_volumes(buf3.view(np.uint32))
+            held = min(held, codec.devices_holding(vols))
             t1 = time.perf_counter()
             handle = codec.reconstruct_batch_u32(survivors, targets, vols)
             t2 = time.perf_counter()
@@ -2225,7 +2254,9 @@ def _rebuild_batch_chunk(
                     stats["pipeline_depth"] = depth
                     stats["ring_slots"] = ring.slots
                     stats["batch_volumes"] = b
-                    stats["mesh"] = {"vol": vol_axis, "stripe": stripe}
+                    stats["mesh"] = {
+                        **codec.report(), "devices_per_round": held
+                    }
                     if (
                         want_crcs
                         and ok

@@ -26,19 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.4x jax: experimental home + old kwarg name
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, **kwargs):
-        # the modern API spells the replication-check flag check_vma;
-        # the experimental one calls it check_rep — translate so call
-        # sites can stay on the current spelling
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _exp_shard_map(f, **kwargs)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from seaweedfs_tpu.ec.codec_tpu import (
@@ -109,6 +97,24 @@ class MeshCodec:
         """[B, C, N] host → device array sharded P(vol, None, stripe).
         B must divide by the vol axis, N by the stripe axis."""
         return jax.device_put(host_volumes, self.block_sharding)
+
+    def devices_holding(self, sharded: jnp.ndarray) -> int:
+        """How many devices hold a non-empty part of `sharded`."""
+        return len(
+            {s.device for s in sharded.addressable_shards if s.data.size}
+        )
+
+    def report(self) -> dict:
+        """The mesh's shape, its devices and the u32 kernel arm they
+        select (see _swar_tier), for the batch drivers' stats."""
+        first = np.asarray(self.mesh.devices).flat[0]
+        return {
+            "vol": self.mesh.shape[VOL_AXIS],
+            "stripe": self.mesh.shape[STRIPE_AXIS],
+            "platform": first.platform,
+            "device_kind": first.device_kind,
+            "arm": "swar" if self._swar_tier()[0] else "bit-matmul",
+        }
 
     # --- batched encode ---
     @functools.cached_property
@@ -494,10 +500,10 @@ class MeshCodec:
     def verify_batch_u32(
         self, volumes_u32: jnp.ndarray, parity_u32: jnp.ndarray
     ) -> jnp.ndarray:
-        """u32-lane verify at the SWAR encode rate (measured: 93 GB/s
-        vs 89-104 encode on one v5e chip, BENCH_r05 / docs/EC_KERNEL.md
-        round-5 section): the fused pallas kernel recomputes each
-        parity tile in VMEM, compares in register, and accumulates the
+        """u32-lane verify at the SWAR encode rate (pre-growth
+        kernel-only record r05, ROADMAP's table: 98.7 GB/s against
+        93.9 encode on one v5e chip): the fused pallas kernel recomputes
+        each parity tile in VMEM, compares in register, and accumulates the
         mismatched-lane count; the psum over the stripe axis reduces
         the per-device counts. [B] int32, 0 = verified. This is the
         TPU production tier — the u32 packing is the native device

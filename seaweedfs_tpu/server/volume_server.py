@@ -351,6 +351,11 @@ class VolumeServer:
         # back to a volume, and degraded-read reconstruction
         # (store_ec.go:364 enc.ReconstructData).
         self.ec_codec = ec_codec or None
+        self._ec_resolved = ""  # what "auto" resolved to, once it has
+        if self.ec_codec == "tpu":
+            # the flag names the device codec: say at start-up which
+            # platform and kernel arm that means in this process
+            self._new_rs()
         if storage_backends:
             # remote-tier backends (storage.backend config tree; the
             # reference ships this from master config in heartbeats,
@@ -542,7 +547,7 @@ class VolumeServer:
             "SeaweedFS-TPU Volume",
             f"Volume Server {self.host}:{self.port}",
             f"master: {_html.escape(self.master or '(none)')} &middot; "
-            f"ec codec: {self.ec_codec or 'auto'}",
+            f"ec codec: {_html.escape(json.dumps(self._ec_codec_status()))}",
             ["Id", "Collection", "Size", "Files", "Deleted", "Mode"],
             "".join(rows),
             ["/status", "/metrics"],
@@ -1061,7 +1066,53 @@ class VolumeServer:
     def _new_rs(self):
         from seaweedfs_tpu.ec.codec import new_encoder
 
-        return new_encoder(backend=self.ec_codec)
+        rs = new_encoder(backend=self.ec_codec)
+        self._ec_resolved = rs._backend_name
+        return rs
+
+    def _ec_codec_status(self) -> dict:
+        """The `ec.codec` this node runs: the flag (or what auto
+        resolved to) and, once the device codec is loaded, its device
+        report and host-interop calls per kernel arm."""
+        out: dict = {"codec": self.ec_codec or self._ec_resolved or "auto"}
+        if out["codec"] == "tpu":
+            # loaded (and reported) when the first tpu codec was built
+            from seaweedfs_tpu.ec import codec_tpu
+
+            out.update(codec_tpu.device_report())
+            out["apply_calls"] = dict(codec_tpu.APPLY_CALLS)
+        return out
+
+    def _batch_codec(self, batch: int):
+        """Mesh codec for the batch verbs on a node whose codec is tpu,
+        provisioned HERE so the drivers' codec=None host fallbacks
+        (legitimate on a CPU host) can never run in its place: what
+        provisioning raises fails the verb. None elsewhere — the
+        driver then self-provisions as before."""
+        from seaweedfs_tpu.ec import ec_stream
+        from seaweedfs_tpu.ec.codec import default_backend
+
+        if (self.ec_codec or default_backend()) != "tpu":
+            return None
+        return ec_stream._default_mesh_codec(batch)
+
+    @staticmethod
+    def _log_ec_verb(verb: str, vids, st: dict) -> None:
+        """One line per EC verb: the driver and kernel arm that ran and
+        the stage seconds the driver booked — the server's own account
+        of whether the device arm ran (SWAR not bit-matmul, stream
+        driver not the classic loop, device_s > 0)."""
+        keys = (
+            "driver", "arms", "mesh", "fallback", "codec_arm",
+            "batch_volumes", "read_s", "stage_s", "device_s",
+            "writeback_s", "compute_s", "write_s", "encode_s", "wall_s",
+        )
+        wlog.info(
+            "ec.%s vid=%s report=%s",
+            verb,
+            vids,
+            json.dumps({k: st[k] for k in keys if k in st}, sort_keys=True),
+        )
 
     def VolumeEcShardsGenerate(self, req, context):
         self._ensure_owned(req.volume_id)
@@ -1080,6 +1131,7 @@ class VolumeServer:
         ec_files.write_ec_files(
             base, rs=self._new_rs(), durable=True, stats=st, want_crcs=True
         )
+        self._log_ec_verb("generate", req.volume_id, st)
         crcs = st.get("shard_crcs")
         if crcs:
             wlog.info(
@@ -1112,8 +1164,13 @@ class VolumeServer:
         if bases:
             st: dict = {}
             ec_files.write_ec_files_batch(
-                bases, durable=True, stats=st, want_crcs=True
+                bases,
+                codec=self._batch_codec(len(bases)),
+                durable=True,
+                stats=st,
+                want_crcs=True,
             )
+            self._log_ec_verb("batch_generate", list(req.volume_ids), st)
             for vid, base, crcs in zip(
                 req.volume_ids, bases, st.get("shard_crcs") or []
             ):
@@ -1154,6 +1211,7 @@ class VolumeServer:
                 base, rs=self._new_rs(), durable=True, stats=st,
                 want_crcs=True,
             )
+            self._log_ec_verb("rebuild", req.volume_id, st)
             self._log_rebuild_crcs(req.volume_id, base, st)
             return pb.VolumeEcShardsRebuildResponse(rebuilt_shard_ids=rebuilt)
         # with a master, always learn which "missing" shards are in
@@ -1171,6 +1229,7 @@ class VolumeServer:
                     base, rs=self._new_rs(), durable=True, stats=st,
                     want_crcs=True,
                 )
+                self._log_ec_verb("rebuild", req.volume_id, st)
                 self._log_rebuild_crcs(req.volume_id, base, st)
             else:
                 from seaweedfs_tpu.ec import ec_stream, repair_session
@@ -1206,6 +1265,7 @@ class VolumeServer:
                         stats=st,
                         want_crcs=True,
                     )
+                    self._log_ec_verb("rebuild", req.volume_id, st)
                     self._log_rebuild_crcs(req.volume_id, base, st)
                 except ValueError as e:
                     context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
@@ -1263,6 +1323,7 @@ class VolumeServer:
                 try:
                     ec_files.rebuild_ec_files_batch(
                         [base for _, base in batch],
+                        codec=self._batch_codec(len(batch)),
                         durable=True,
                         stats=st,
                         want_crcs=True,
@@ -1271,6 +1332,9 @@ class VolumeServer:
                     context.abort(
                         grpc.StatusCode.FAILED_PRECONDITION, str(e)
                     )
+                self._log_ec_verb(
+                    "batch_rebuild", [vid for vid, _ in batch], st
+                )
                 for (vid, base), crcs in zip(
                     batch, st.get("shard_crcs") or []
                 ):
@@ -1987,6 +2051,7 @@ class VolumeServer:
                             # weedload scrapes these for its fast-path
                             # hit / 304 / plan-cache ratios
                             "ServeStats": _native_serve.serve_stats(),
+                            "EcCodec": server._ec_codec_status(),
                         }
                     )
                 if url_path == "/scrub/status":

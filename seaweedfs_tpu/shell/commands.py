@@ -886,10 +886,11 @@ def do_ec_verify(
     as_json: bool = False,
 ) -> list[int]:
     """Scrub one EC volume: stream all 14 shards from their holders,
-    recompute the parity from the data shards with the local codec
-    backend (auto: the TPU kernels on a TPU host, the native SIMD shim
-    otherwise — same selection as the serving path), and compare.
-    Returns the per-parity-row mismatched-byte counts [4].
+    recompute the parity from the data shards with a HOST codec (the
+    native SIMD shim, else numpy — never auto-detect: on a TPU host the
+    volume server owns the chip, and a shell that initialised it too
+    would fail or block), and compare. Returns the per-parity-row
+    mismatched-byte counts [4].
 
     Runs through the scrub engine's verify core
     (scrub/verify.verify_parity_stream — the same code path the
@@ -901,6 +902,7 @@ def do_ec_verify(
     parity rows; a corrupt PARITY shard only in its own row."""
     import json as _json
 
+    from seaweedfs_tpu.ec.codec import host_backend, new_encoder
     from seaweedfs_tpu.scrub.ratelimit import TokenBucket
     from seaweedfs_tpu.scrub.verify import verify_parity_stream
 
@@ -951,6 +953,7 @@ def do_ec_verify(
     try:
         res = verify_parity_stream(
             [make_reader(sid) for sid in range(ec_common.TOTAL_SHARDS_COUNT)],
+            rs=new_encoder(backend=host_backend()),
             tile_bytes=tile_bytes,
             limiter=limiter,
         )

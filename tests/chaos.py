@@ -35,8 +35,8 @@ def wait_for(cond, timeout=45.0, interval=0.05) -> bool:
 
 
 def spawn_cli(*args, env_extra: dict | None = None):
-    """A real `python -m seaweedfs_tpu ...` subprocess (cpu-forced
-    jax) — the SIGSTOP/SIGKILL scenarios need a separate PROCESS, and
+    """A real `python -m seaweedfs_tpu ...` subprocess — the
+    SIGSTOP/SIGKILL scenarios need a separate PROCESS, and
     `env_extra` selects the serving path (WEED_NATIVE_SERVE) per arm."""
     env = dict(
         os.environ, JAX_PLATFORMS="cpu", WEED_EC_CODEC="cpu",
@@ -45,9 +45,8 @@ def spawn_cli(*args, env_extra: dict | None = None):
     return subprocess.Popen(
         [
             sys.executable,
-            "-c",
-            "import jax; jax.config.update('jax_platforms', 'cpu');"
-            "from seaweedfs_tpu.__main__ import main; main()",
+            "-m",
+            "seaweedfs_tpu",
             *args,
         ],
         env=env,

@@ -401,3 +401,74 @@ class TestNativeBackend:
         for t in threads:
             t.join()
         assert not errors, errors[:1]
+
+
+class TestNoHiddenDeviceFallback:
+    """A chip that cannot be initialised (another process holds it,
+    say) must fail loudly — not read as "no chip" and demote the node
+    to a host codec or the bit-matmul arm in silence."""
+
+    @staticmethod
+    def _chip_busy():
+        raise RuntimeError("TPU is already in use by another process")
+
+    def test_default_backend_propagates_device_errors(self, monkeypatch):
+        import jax
+
+        from seaweedfs_tpu.ec import codec
+
+        monkeypatch.delenv("WEED_EC_CODEC", raising=False)
+        monkeypatch.setattr(codec, "_default_backend", "")
+        monkeypatch.setattr(jax, "devices", self._chip_busy)
+        with pytest.raises(RuntimeError, match="already in use"):
+            codec.default_backend()
+        assert codec._default_backend == ""  # nothing cached either
+
+    def test_on_tpu_propagates_device_errors(self, monkeypatch):
+        import jax
+
+        from seaweedfs_tpu.ec import codec_tpu
+
+        codec_tpu.device_report.cache_clear()
+        monkeypatch.setattr(jax, "devices", self._chip_busy)
+        try:
+            with pytest.raises(RuntimeError, match="already in use"):
+                codec_tpu._on_tpu()
+        finally:
+            codec_tpu.device_report.cache_clear()
+
+    def test_device_report_names_platform_and_arm(self):
+        from seaweedfs_tpu.ec import codec_tpu
+
+        report = codec_tpu.device_report()
+        assert report["platform"] == "cpu" and report["arm"] == "bit-matmul"
+        assert report["device_count"] >= 1 and report["device_kind"]
+        assert codec_tpu._on_tpu() is False
+
+    def test_compile_cache_placed_from_outside_or_fixed(self, monkeypatch):
+        import jax
+
+        from seaweedfs_tpu.ec import compile_cache
+
+        # unset: one fixed path inside the checkout
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        keys = (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+        )
+        was = [getattr(jax.config, k) for k in keys]
+        try:
+            assert compile_cache.place_compile_cache() == compile_cache.DEFAULT_DIR
+            assert (
+                jax.config.jax_compilation_cache_dir == compile_cache.DEFAULT_DIR
+            )
+        finally:
+            for k, v in zip(keys, was):
+                jax.config.update(k, v)
+        assert compile_cache.DEFAULT_DIR.endswith("/.jax_cache")
+        # set: JAX's own handling of it stands, the code sets nothing
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        monkeypatch.setattr(
+            jax.config, "update", lambda *a: pytest.fail(f"set {a}")
+        )
+        assert compile_cache.place_compile_cache() == "/somewhere/else"

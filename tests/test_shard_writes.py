@@ -405,9 +405,8 @@ class TestShardWritesCli:
             return subprocess.Popen(
                 [
                     sys.executable,
-                    "-c",
-                    "import jax; jax.config.update('jax_platforms', 'cpu');"
-                    "from seaweedfs_tpu.__main__ import main; main()",
+                    "-m",
+                    "seaweedfs_tpu",
                     *args,
                 ],
                 env=env,

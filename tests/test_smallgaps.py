@@ -401,8 +401,6 @@ class TestFiveByteOffsets:
         code = f"""
 import os
 os.environ["WEED_VOLUME_OFFSET_SIZE"] = "5"
-import jax
-jax.config.update("jax_platforms", "cpu")
 from seaweedfs_tpu.storage import types as t, idx
 assert t.OFFSET_SIZE == 5 and idx.ENTRY_SIZE == 17
 e = idx.pack_entry(7, 0xFFFFFFFFF, 123)

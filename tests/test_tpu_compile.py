@@ -1,0 +1,214 @@
+"""The main path's device programs compile for the real chip.
+
+Interpret mode and the CPU bit-matmul arm cannot see what the TPU's
+compiler refuses (tiling, VMEM, partitioning), and a chip run costs
+budget. The compiler is installed here and compiles for a chip that is
+described, not attached — so each program the stream and mesh drivers
+dispatch is lowered and compiled for "TPU v5 lite" at the width it
+really runs at (DEFAULT_TILE_BYTES = 1 MiB per shard row = 262144 u32
+lanes). Nothing executes: a compile that passes is not a chip run
+(chip_smoke.py is).
+
+The topology is described inside a module-scoped fixture — never at
+import, in a skipif or in a parametrize argument — so every xdist
+worker collects the same tests and only the worker handed this file
+loads the TPU library; everything compiles in this process.
+"""
+
+import numpy as np
+import pytest
+
+TILE_LANES = 262144  # ec_stream.DEFAULT_TILE_BYTES // 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # noqa: BLE001 — whatever the plugin raises
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer code that asks _on_tpu() — it sees this process's CPU
+    backend — onto the branch it takes on the chip."""
+    from seaweedfs_tpu.ec import codec_tpu
+
+    monkeypatch.setattr(codec_tpu, "_on_tpu", lambda: True)
+
+
+def _compiled_text(fn, *shapes) -> str:
+    import jax
+
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _u32(shape, sharding):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
+
+
+def _parity_rows():
+    from seaweedfs_tpu.ec import gf256
+
+    return gf256.build_code_matrix(10, 14)[10:]
+
+
+# --- the single-chip programs ------------------------------------------------
+
+
+@pytest.mark.parametrize("lanes", [TILE_LANES, 16384])
+def test_swar_apply(one_chip, lanes):
+    """[10, 16384] is the 64 KiB floor where tpu_apply_matrix switches
+    from the bit-matmul to the SWAR kernel (_SWAR_MIN_BYTES)."""
+    from seaweedfs_tpu.ec import codec_tpu
+
+    rows = _parity_rows()
+    text = _compiled_text(
+        lambda x: codec_tpu.swar_apply_matrix_u32(rows, x),
+        _u32((10, lanes), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_encode_u32_crc(one_chip, on_tpu):
+    """The stream encode driver's fused stage (ec_stream._tpu_encode_fns)."""
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+
+    kern = TpuCodecKernels()
+    text = _compiled_text(kern.encode_u32_crc, _u32((10, TILE_LANES), one_chip))
+    assert "tpu_custom_call" in text
+
+
+def test_reconstruct_u32_one_target(one_chip):
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+
+    kern = TpuCodecKernels()
+    survivors = tuple(range(1, 11))
+    text = _compiled_text(
+        lambda x: kern.reconstruct_u32(survivors, (0,), x),
+        _u32((10, TILE_LANES), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_reconstruct_u32_crc_four_data_targets(one_chip, on_tpu):
+    """The stream rebuild driver's fused stage at its worst case: all
+    four losses are data shards (the inverted-matrix decode)."""
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+
+    kern = TpuCodecKernels()
+    survivors = tuple(range(4, 14))
+    text = _compiled_text(
+        lambda x: kern.reconstruct_u32_crc(survivors, (0, 1, 2, 3), x),
+        _u32((10, TILE_LANES), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_swar_apply_batch(one_chip):
+    from seaweedfs_tpu.ec import codec_tpu
+
+    rows = _parity_rows()
+    text = _compiled_text(
+        lambda x: codec_tpu.swar_apply_matrix_u32_batch(rows, x),
+        _u32((4, 10, TILE_LANES), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_swar_verify_batch(one_chip):
+    from seaweedfs_tpu.ec import codec_tpu
+
+    rows = _parity_rows()
+    text = _compiled_text(
+        lambda x, p: codec_tpu.swar_verify_matrix_u32_batch(rows, x, p),
+        _u32((4, 10, TILE_LANES), one_chip),
+        _u32((4, 4, TILE_LANES), one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_apply_matrix_bits(one_chip):
+    """The bit-matmul arm small degraded-read intervals take: plain
+    XLA, no Pallas kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from seaweedfs_tpu.ec import codec_tpu
+
+    bits = codec_tpu.gf_matrix_to_bits(_parity_rows())
+    text = _compiled_text(
+        lambda x: codec_tpu.apply_matrix_bits(bits, x),
+        jax.ShapeDtypeStruct((10, 1 << 20), jnp.uint8, sharding=one_chip),
+    )
+    assert "tpu_custom_call" not in text
+
+
+# --- the mesh programs of the batch drivers ----------------------------------
+
+
+def _mesh_codec(topo, vol: int, stripe: int):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from seaweedfs_tpu.parallel import MeshCodec
+    from seaweedfs_tpu.parallel.mesh_codec import STRIPE_AXIS, VOL_AXIS
+
+    mesh = Mesh(
+        np.array(topo.devices).reshape(vol, stripe), (VOL_AXIS, STRIPE_AXIS)
+    )
+    codec = MeshCodec(mesh)
+    assert codec.report()["arm"] == "swar"  # described devices are TPUs
+    return codec, NamedSharding(mesh, P(VOL_AXIS, None, STRIPE_AXIS))
+
+
+@pytest.mark.parametrize("vol,stripe", [(2, 2), (4, 1)])
+def test_mesh_encode_batch_u32_crc(topo, vol, stripe):
+    """One program per tile round of ec.batch; with a stripe axis the
+    per-device CRCs fold through an all_gather."""
+    codec, sharding = _mesh_codec(topo, vol, stripe)
+    text = _compiled_text(
+        codec.encode_batch_u32_crc, _u32((4, 10, TILE_LANES), sharding)
+    )
+    assert "tpu_custom_call" in text
+    assert ("all-gather" in text) == (stripe > 1)
+
+
+@pytest.mark.parametrize("vol,stripe", [(2, 2), (4, 1)])
+def test_mesh_verify_batch_u32(topo, vol, stripe):
+    """The fused verify kernel; the stripe-axis psum is an all-reduce."""
+    codec, sharding = _mesh_codec(topo, vol, stripe)
+    text = _compiled_text(
+        codec.verify_batch_u32,
+        _u32((4, 10, TILE_LANES), sharding),
+        _u32((4, 4, TILE_LANES), sharding),
+    )
+    assert "tpu_custom_call" in text
+    assert ("all-reduce" in text) == (stripe > 1)

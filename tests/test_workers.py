@@ -243,6 +243,14 @@ class TestVolumeReadWorker:
         assert not errors, errors[:3]
 
 
+def _ppid(pid: int) -> int:
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return int(f.read().rsplit(")", 1)[1].split()[1])
+    except (OSError, ValueError, IndexError):
+        return -1  # exited between listdir and open
+
+
 class TestWorkersCli:
     """The real `volume -workers N` spawn path: a CLI lead brings up
     SO_REUSEPORT worker subprocesses sharing its port; fresh-connection
@@ -262,9 +270,8 @@ class TestWorkersCli:
             return subprocess.Popen(
                 [
                     sys.executable,
-                    "-c",
-                    "import jax; jax.config.update('jax_platforms', 'cpu');"
-                    "from seaweedfs_tpu.__main__ import main; main()",
+                    "-m",
+                    "seaweedfs_tpu",
                     *args,
                 ],
                 env=env,
@@ -275,10 +282,9 @@ class TestWorkersCli:
 
         procs = [spawn("master", "-port", str(mport))]
         try:
-            # generous spawn deadlines: each subprocess pays a fresh
-            # interpreter + jax import, which stretches from ~3 s to
-            # tens of seconds when the host throttles mid-suite (this
-            # test failed a full-suite run on exactly that)
+            # generous spawn deadlines: a fresh interpreter stretches
+            # to tens of seconds when the host throttles mid-suite
+            # (this test failed a full-suite run on exactly that)
             deadline = time.time() + 60
             while time.time() < deadline:
                 try:
@@ -298,8 +304,7 @@ class TestWorkersCli:
                     "-workers", "3",
                 )
             )
-            # lead + 2 worker subprocesses all listening (workers take a
-            # few seconds each: fresh interpreter + jax import)
+            # lead + 2 worker subprocesses all listening
             def assigned():
                 with urllib.request.urlopen(
                     f"http://127.0.0.1:{mport}/dir/assign", timeout=2
@@ -342,6 +347,19 @@ class TestWorkersCli:
             for _ in range(12):
                 with urllib.request.urlopen(url, timeout=10) as r:
                     assert r.read() == b"cli worker payload"
+            # one process per chip: the lead and its workers have served
+            # by now and none of them may have loaded JAX (a process
+            # with jax in sys.modules has jaxlib's extension mapped)
+            lead = procs[-1].pid
+            family = [lead] + [
+                int(p)
+                for p in os.listdir("/proc")
+                if p.isdigit() and _ppid(int(p)) == lead
+            ]
+            assert len(family) == 3, family
+            for pid in family:
+                with open(f"/proc/{pid}/maps") as f:
+                    assert "jaxlib" not in f.read(), f"pid {pid} loaded jax"
             # delete propagates through whichever process accepts
             urllib.request.urlopen(
                 urllib.request.Request(url, method="DELETE"), timeout=10
