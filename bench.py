@@ -60,10 +60,10 @@ Other configs (BASELINE.json):
                      single-core framework floor). The pipelined
                      driver reports overlapped stages (read/stage/
                      device/writeback/compute/write + pipeline_depth,
-                     docs/CODEC.md) whose sum can exceed wall —
-                     overlap_s is the excess, the per-run proof the
-                     stages actually ran concurrently; loop_s is
-                     wall − flush − max stage. The line also carries
+                     docs/CODEC.md) whose sum can exceed wall, and
+                     the serial phases head/dispatch_span/drain/
+                     write_tail/flush, which sum to it
+                     (docs/TRACING.md). The line also carries
                      serial_gb_s / vs_serial: the same encode through
                      the WEED_EC_PIPELINE=0 serial classic driver
                      (BENCH_r12 is the standing record).

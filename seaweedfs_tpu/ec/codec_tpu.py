@@ -46,6 +46,15 @@ from seaweedfs_tpu.util import wlog
 
 place_compile_cache()
 
+# Scopes of the fused encode/rebuild programs, here and in
+# parallel/mesh_codec.py: the names a trace reader finds the three parts
+# under (op_name metadata `jit(...)/ec.crc_fold/...`) whatever the
+# compiler calls the fusions. The Pallas kernels carry their own `name=`.
+SCOPE_SWAR = "ec.swar"
+SCOPE_LAYOUT = "ec.layout"
+SCOPE_CRC_FOLD = "ec.crc_fold"
+SCOPE_CRC_GATHER = "ec.crc_gather"
+
 
 def gf_matrix_to_bits(matrix: np.ndarray) -> np.ndarray:
     """Expand a GF(2^8) coefficient matrix [R,C] to its GF(2) bit-matrix
@@ -230,6 +239,7 @@ def swar_apply_u32(
         out_specs=pl.BlockSpec((r_out, tn), lambda i: (0, i), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((r_out, n), jnp.uint32),
         interpret=interpret,
+        name="swar_apply_u32",
     )(data_u32)
 
 
@@ -262,6 +272,7 @@ def swar_apply_u32_batch(
         ),
         out_shape=jax.ShapeDtypeStruct((b, r_out, n), jnp.uint32),
         interpret=interpret,
+        name="swar_apply_u32_batch",
     )(data_u32)
 
 
@@ -353,6 +364,7 @@ def swar_verify_u32_batch(
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
         scratch_shapes=[pltpu.VMEM((tn,), jnp.int32)],
         interpret=interpret,
+        name="swar_verify_u32_batch",
     )(data_u32, parity_u32)
     return counts[:, 0]
 
@@ -604,15 +616,17 @@ class TpuCodecKernels:
         way."""
         from seaweedfs_tpu.ec import crc_kernel
 
-        if interpret or _on_tpu():
-            parity = swar_apply_matrix_u32(
-                self.matrix[self.data_shards :], data_u32, interpret
-            )
-        else:
-            parity = apply_matrix_bits_u32(self.encode_bits, data_u32)
-        crcs = crc_kernel.crc32c_rows(
-            jnp.concatenate([data_u32, parity], axis=0)
-        )
+        with jax.named_scope(SCOPE_SWAR):
+            if interpret or _on_tpu():
+                parity = swar_apply_matrix_u32(
+                    self.matrix[self.data_shards :], data_u32, interpret
+                )
+            else:
+                parity = apply_matrix_bits_u32(self.encode_bits, data_u32)
+        with jax.named_scope(SCOPE_LAYOUT):
+            full = jnp.concatenate([data_u32, parity], axis=0)
+        with jax.named_scope(SCOPE_CRC_FOLD):
+            crcs = crc_kernel.crc32c_rows(full)
         return parity, crcs
 
     def reconstruct_u32_crc(
@@ -628,14 +642,17 @@ class TpuCodecKernels:
         from seaweedfs_tpu.ec import crc_kernel
 
         rows = self.decode_rows_for(survivors, targets)
-        if interpret or _on_tpu():
-            rebuilt = swar_apply_matrix_u32(rows, shard_data_u32, interpret)
-        else:
-            rebuilt = apply_matrix_bits_u32(
-                jnp.asarray(self.decode_bits_for(survivors, targets)),
-                shard_data_u32,
-            )
-        return rebuilt, crc_kernel.crc32c_rows(rebuilt)
+        with jax.named_scope(SCOPE_SWAR):
+            if interpret or _on_tpu():
+                rebuilt = swar_apply_matrix_u32(rows, shard_data_u32, interpret)
+            else:
+                rebuilt = apply_matrix_bits_u32(
+                    jnp.asarray(self.decode_bits_for(survivors, targets)),
+                    shard_data_u32,
+                )
+        with jax.named_scope(SCOPE_CRC_FOLD):
+            crcs = crc_kernel.crc32c_rows(rebuilt)
+        return rebuilt, crcs
 
     def decode_rows_for(
         self, survivors: tuple[int, ...], targets: tuple[int, ...]

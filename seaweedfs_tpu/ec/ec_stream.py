@@ -50,6 +50,7 @@ weed/storage/erasure_coding/ec_encoder.go:188-225 (encodeDatFile) and
 from __future__ import annotations
 
 import errno
+import functools
 import os
 import sys
 import queue
@@ -125,8 +126,8 @@ class _StagingRing:
     writer → free. Replaces a fresh np.empty per tile: the pipeline's
     host memory is bounded at slots x slot_bytes for the whole run and
     the allocator drops out of the hot loop (page-faulting a new 40 MiB
-    arena per tile showed up as unattributed wall in the loop_s
-    residue). Slot count = dispatch depth + one in-hand buffer per pool
+    arena per tile showed up as wall no stage accounted for). Slot
+    count = dispatch depth + one in-hand buffer per pool
     thread, so no stage ever stalls waiting for memory another stage
     is legitimately using."""
 
@@ -286,6 +287,39 @@ def _charge(busy: dict, lock: threading.Lock, key: str, dt: float) -> None:
         busy[key] += dt
 
 
+# The serial phases of one encode operation, all on the handler's thread
+# (it is the dispatcher): span and annotation name -> report field. They
+# partition wall_s (trace.Phases): head (open + preallocate the shard
+# files, spawn the pools, first read) | dispatch (first dispatch -> last
+# dispatch returned) | drain (-> the last writer's fetch returned:
+# nothing left to send, the host waits for the device and the D2H) |
+# write_tail (-> the pools joined) | flush (the fsync + close loop).
+_OP_PHASES = {
+    "ec.op.head": "head_s",
+    "ec.op.dispatch": "dispatch_span_s",
+    "ec.op.drain": "drain_s",
+    "ec.op.write_tail": "write_tail_s",
+    "ec.op.flush": "flush_s",
+}
+
+# What only a device stage books, beside the pool stages and in
+# thread-seconds like them: the dispatcher's time in the transfer call
+# and in the jitted call. In the single-volume driver h2d_s + launch_s
+# is device_s; the batch driver's transfer is part of its stage_s and
+# launch_s == device_s. Host stage pairs book neither.
+_DEVICE_BUSY = {"h2d_s": 0.0, "launch_s": 0.0}
+
+
+def _close_phases(phases, busy: dict) -> float:
+    """End the operation's last phase and book all five into `busy`
+    (0.0 for one an aborted operation never entered); returns the
+    closing clock sample, which is the end of wall_s."""
+    end = phases.close()
+    for name, field in _OP_PHASES.items():
+        busy[field] = phases.seconds.get(name, 0.0)
+    return end
+
+
 # --- codec stage factories --------------------------------------------------
 
 
@@ -401,8 +435,26 @@ def stream_write_ec_files(
     if (parity_fn is None) != (fetch_fn is None):
         raise ValueError("parity_fn and fetch_fn must be injected together")
     device_stage = parity_fn is None
+    # per-stage busy thread-seconds (queue waits excluded): read |
+    # stage (host staging prep) | device (async dispatch) | writeback
+    # (device drain / D2H) or compute (host codec) | write — how e2e
+    # numbers stay attributable and reader/device/writer overlap is
+    # provable per run
+    busy = {
+        "read_s": 0.0,
+        "stage_s": 0.0,
+        "device_s": 0.0,
+        "writeback_s": 0.0,
+        "compute_s": 0.0,
+        "write_s": 0.0,
+    }
+    busy_lock = threading.Lock()
     if device_stage:
-        parity_fn, fetch_fn = _tpu_encode_fns(want_crcs=want_crcs)
+        busy.update(_DEVICE_BUSY)
+        parity_fn, fetch_fn = _tpu_encode_fns(
+            want_crcs=want_crcs,
+            book=functools.partial(_charge, busy, busy_lock),
+        )
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
     writer_threads = writer_threads or DEFAULT_WRITER_THREADS
     reader_threads = reader_threads or DEFAULT_READER_THREADS
@@ -449,30 +501,21 @@ def stream_write_ec_files(
     ring = _StagingRing(
         depth + writer_threads + 1, DATA_SHARDS * tile_bytes
     )
-    # per-stage busy thread-seconds (queue waits excluded): read |
-    # stage (host staging prep) | device (async dispatch) | writeback
-    # (device drain / D2H) or compute (host codec) | write — how e2e
-    # numbers stay attributable and reader/device/writer overlap is
-    # provable per run
-    busy = {
-        "read_s": 0.0,
-        "stage_s": 0.0,
-        "device_s": 0.0,
-        "writeback_s": 0.0,
-        "compute_s": 0.0,
-        "write_s": 0.0,
-    }
-    busy_lock = threading.Lock()
     fetch_bucket = getattr(fetch_fn, "charges", "writeback_s")
     # per-tile shard CRCs, filled by the writer pool (index writes are
     # GIL-atomic), folded into whole-file CRCs after the join
     tile_crcs: list = [None] * len(tiles)
+    # the latest clock sample at which a writer's fetch returned (under
+    # busy_lock): where ec.op.drain ends, read once the pools are joined
+    last_fetch = [0.0]
     wall0 = time.perf_counter()
     # tracing plane: the encode is one span whose stages are the pool
-    # busy totals; entered manually because the body below already owns
-    # the try/finally structure
+    # busy totals and whose children are the serial phases; entered
+    # manually because the body below already owns the try/finally
+    # structure
     _sp = trace.span("ec_stream.encode", nbytes=dat_size)
     _sp.__enter__()
+    phases = trace.Phases("ec.op.head", wall0)
 
     idx_lock = threading.Lock()
     idx_iter = iter(range(len(tiles)))
@@ -502,29 +545,30 @@ def stream_write_ec_files(
                 # are copied exactly once between disk reads and
                 # writes.
                 flat = buf[: rows * DATA_SHARDS * step]
-                if batch_off == 0 and step == block:
-                    # full rows are CONTIGUOUS in the .dat: one read
-                    # covers the whole super-tile
-                    n = max(0, min(len(flat), dat_size - row_off))
-                    if n < len(flat):
-                        flat[n:] = 0
-                    if n:
-                        got = _pread_into(fd, flat[:n], row_off)
-                        if got < n:  # truncated .dat: pad like classic
-                            flat[got:n] = 0
-                else:
-                    # sub-block tile of the large tier: rows == 1,
-                    # shard blocks are strided through the .dat
-                    for i in range(DATA_SHARDS):
-                        row = flat[i * step : (i + 1) * step]
-                        off = row_off + i * block + batch_off
-                        n = max(0, min(step, dat_size - off))
-                        if n < step:
-                            row[n:] = 0
+                with trace.annotation("ec.read"):
+                    if batch_off == 0 and step == block:
+                        # full rows are CONTIGUOUS in the .dat: one read
+                        # covers the whole super-tile
+                        n = max(0, min(len(flat), dat_size - row_off))
+                        if n < len(flat):
+                            flat[n:] = 0
                         if n:
-                            got = _pread_into(fd, row[:n], off)
-                            if got < n:
-                                row[got:n] = 0
+                            got = _pread_into(fd, flat[:n], row_off)
+                            if got < n:  # truncated .dat: pad like classic
+                                flat[got:n] = 0
+                    else:
+                        # sub-block tile of the large tier: rows == 1,
+                        # shard blocks are strided through the .dat
+                        for i in range(DATA_SHARDS):
+                            row = flat[i * step : (i + 1) * step]
+                            off = row_off + i * block + batch_off
+                            n = max(0, min(step, dat_size - off))
+                            if n < step:
+                                row[n:] = 0
+                            if n:
+                                got = _pread_into(fd, row[:n], off)
+                                if got < n:
+                                    row[got:n] = 0
                 _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
                 if not _q_put(read_q, (k, slot_id, flat), pipe.stop):
                     ring.release(slot_id)
@@ -551,6 +595,8 @@ def stream_write_ec_files(
                     parities.append(got)
                     crc_rows.append(None)
             t1 = time.perf_counter()
+            with busy_lock:
+                last_fetch[0] = max(last_fetch[0], t1)
             if want_crcs and any(c is None for c in crc_rows):
                 # the stage declined the fused CRC for this tile
                 # (injected pair / unsupported shape): table-CRC the
@@ -572,24 +618,29 @@ def stream_write_ec_files(
                         for p in range(PARITY_SHARDS)
                     ]
             t2 = time.perf_counter()
-            for i in range(DATA_SHARDS):
-                _pwritev_full(
-                    out_fds[i],
-                    [
-                        flat[
-                            (r * DATA_SHARDS + i) * step : (r * DATA_SHARDS + i + 1)
-                            * step
-                        ]
-                        for r in range(rows)
-                    ],
-                    off,
-                )
-            for p in range(PARITY_SHARDS):
-                _pwritev_full(
-                    out_fds[DATA_SHARDS + p],
-                    [np.ascontiguousarray(parities[r][p]) for r in range(rows)],
-                    off,
-                )
+            with trace.annotation("ec.write"):
+                for i in range(DATA_SHARDS):
+                    _pwritev_full(
+                        out_fds[i],
+                        [
+                            flat[
+                                (r * DATA_SHARDS + i)
+                                * step : (r * DATA_SHARDS + i + 1)
+                                * step
+                            ]
+                            for r in range(rows)
+                        ],
+                        off,
+                    )
+                for p in range(PARITY_SHARDS):
+                    _pwritev_full(
+                        out_fds[DATA_SHARDS + p],
+                        [
+                            np.ascontiguousarray(parities[r][p])
+                            for r in range(rows)
+                        ],
+                        off,
+                    )
             t3 = time.perf_counter()
             if want_crcs:
                 tile_crcs[k] = crc_rows
@@ -614,13 +665,15 @@ def stream_write_ec_files(
             pipe.spawn(reader)
         for _ in range(writer_threads):
             pipe.spawn(writer)
-        for _ in range(len(tiles)):
+        for n in range(len(tiles)):
             item = _q_get(read_q, pipe.stop)
             if item is _STOPPED:
                 break
             k, slot_id, flat = item
             _, _, _, step, rows = tiles[k]
             t0 = time.perf_counter()
+            if n == 0:
+                phases.to("ec.op.dispatch", t0)
             # staging: each [10, step] view is contiguous in the ring
             # slot, so the injected stage contract (and the TPU H2D)
             # sees an ordinary tile
@@ -634,6 +687,8 @@ def stream_write_ec_files(
             # one async parity dispatch per row
             handles = [parity_fn(v) for v in views]
             t2 = time.perf_counter()
+            if n == len(tiles) - 1:
+                phases.to("ec.op.drain", t2)
             _charge(busy, busy_lock, "stage_s", t1 - t0)
             _charge(busy, busy_lock, "device_s", t2 - t1)
             if not _q_put(write_q, (k, slot_id, flat, handles), pipe.stop):
@@ -646,7 +701,8 @@ def stream_write_ec_files(
         try:
             pipe.finish(caller_error=not ok)  # may re-raise a stage error
         finally:
-            tc0 = time.perf_counter()
+            phases.to("ec.op.write_tail", (ok and last_fetch[0]) or None)
+            phases.to("ec.op.flush")
             fsync_err: OSError | None = None
             try:
                 for fd in out_fds:
@@ -679,12 +735,13 @@ def stream_write_ec_files(
                     raise fsync_err
             finally:
                 # raw preallocated fds: nothing buffered remains, so
-                # this measures only the close syscalls (the previous
-                # driver lost 47% of wall right here)
-                busy["flush_s"] = time.perf_counter() - tc0
+                # flush_s measures only the fsync + close syscalls (the
+                # previous driver lost 47% of wall right here)
+                end = _close_phases(phases, busy)
                 if stats is not None:
                     _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads
+                        stats, busy, wall0, reader_threads, writer_threads,
+                        end,
                     )
                     stats["pipeline_depth"] = depth
                     stats["ring_slots"] = ring.slots
@@ -1146,22 +1203,11 @@ def _fold_rebuild_crcs(
 
 
 def _trace_stages(sp, busy: dict) -> None:
-    """Fold the driver's per-stage busy thread-seconds onto its span as
-    the three pipeline stages an operator reasons about: reader-pool
-    (disk/remote reads), compute (staging + device dispatch/drain +
-    host codec), writer-pool (shard pwritev)."""
-    sp.add_stages(
-        {
-            "reader-pool": busy.get("read_s", 0.0),
-            "compute": (
-                busy.get("stage_s", 0.0)
-                + busy.get("device_s", 0.0)
-                + busy.get("writeback_s", 0.0)
-                + busy.get("compute_s", 0.0)
-            ),
-            "writer-pool": busy.get("write_s", 0.0),
-        }
-    )
+    """The driver's booked seconds on its span, under the report line's
+    own field names (one vocabulary: what an operator reads at
+    /debug/traces is what the node's `ec.<verb> report=` line says).
+    Pool stages are thread-seconds; the phases are the span's children."""
+    sp.add_stages({k: v for k, v in busy.items() if k.endswith("_s")})
 
 
 def _finish_stats(
@@ -1170,48 +1216,18 @@ def _finish_stats(
     wall0: float,
     reader_threads: int = 1,
     writer_threads: int = 1,
+    end: float | None = None,
 ) -> None:
-    """Per-stage busy thread-seconds + wall and the unattributed
-    remainder. The PIPELINE stages (read/dispatch/fetch/write) run in
-    thread POOLS, so a stage's Σ can exceed wall (overlap across
-    threads) — the wall a stage explains is its total divided by its
-    pool width. flush_s is different: it is the SERIAL post-pipeline
-    close of the raw fds appended to the wall (≈0 now that nothing is
-    buffered), so it subtracts separately. loop_s = wall − flush − max
-    per-thread stage share: the honest "pipeline was idle / Python
-    glue" residue for a bench line to carry (clamped at 0 — pool
-    accounting is approximate)."""
-    wall = time.perf_counter() - wall0
-    flush = busy.get("flush_s", 0.0)
-    widths = {
-        "read_s": reader_threads,
-        "writeback_s": writer_threads,
-        "compute_s": writer_threads,
-        "write_s": writer_threads,
-    }
-    pipeline_max = max(
-        (
-            v / widths.get(k, 1)
-            for k, v in busy.items()
-            if k != "flush_s"
-        ),
-        default=0.0,
-    )
+    """Per-stage busy thread-seconds + wall. The PIPELINE stages
+    (read/dispatch/fetch/write) run in thread POOLS, so a stage's Σ can
+    exceed wall (overlap across threads) — the wall a stage explains is
+    its total divided by its pool width. The serial phases of the
+    encode drivers (_OP_PHASES, flush_s among them) are different: they
+    partition the wall, given the clock sample `end` that closed the
+    last of them."""
+    wall = (time.perf_counter() if end is None else end) - wall0
     stats.update({k: round(v, 4) for k, v in busy.items()})
     stats["wall_s"] = round(wall, 4)
-    stats["loop_s"] = round(max(0.0, wall - flush - pipeline_max), 4)
-    # busy thread-seconds in excess of wall = stage time that ran
-    # CONCURRENTLY with another stage: the mechanical proof that
-    # reader / device / writer work actually overlapped this run
-    # (0 would mean the pipeline degenerated to a serial chain)
-    stats["overlap_s"] = round(
-        max(
-            0.0,
-            sum(v for k, v in busy.items() if k != "flush_s")
-            - (wall - flush),
-        ),
-        4,
-    )
     stats["reader_threads"] = reader_threads
     stats["writer_threads"] = writer_threads
 
@@ -1228,15 +1244,21 @@ def _swar_ok(step: int) -> bool:
 def _fetch(handle) -> np.ndarray:
     """Block a dispatched kernel handle into a host uint8 array — or
     (uint8 array, crc uint32 array) when the dispatch fused the CRC
-    pass (the driver splits on the tuple)."""
+    pass (the driver splits on the tuple). One annotation, the wait for
+    the program and the copy together, as writeback_s books them: a
+    host-side sample between the two costs the batch cell 4 to 8 % of
+    its throughput (PERF.md section 6, PR 26); a device trace tells them
+    apart, where the program's last operation ends inside the
+    annotation."""
     import jax
 
     out, swar, fused_crc = handle
-    if fused_crc:
-        dev, crcs = out
-        host = np.asarray(jax.device_get(dev))
-        return host.view(np.uint8), np.asarray(jax.device_get(crcs))
-    host = np.asarray(jax.device_get(out))
+    with trace.annotation("ec.writeback"):
+        if fused_crc:
+            dev, crcs = out
+            host = np.asarray(jax.device_get(dev))
+            return host.view(np.uint8), np.asarray(jax.device_get(crcs))
+        host = np.asarray(jax.device_get(out))
     return host.view(np.uint8) if swar else host
 
 
@@ -1261,7 +1283,9 @@ def _new_arms() -> dict:
     return {"swar+crc": 0, "swar": 0, "bit-matmul": 0}
 
 
-def _tpu_encode_fns(want_crcs: bool = False):
+def _tpu_encode_fns(want_crcs: bool, book: Callable[[str, float], None]):
+    """(parity_fn, fetch_fn) on the attached device. `book(field, dt)`
+    takes the device fields (_DEVICE_BUSY) of the driver's `busy`."""
     import jax
     import jax.numpy as jnp
 
@@ -1282,20 +1306,25 @@ def _tpu_encode_fns(want_crcs: bool = False):
     arms = _new_arms()
 
     def parity_fn(tile: np.ndarray):
+        t0 = time.perf_counter()
         swar = _swar_ok(tile.shape[1])
-        fused_crc = _crc_ok(tile.shape[1], want_crcs)
-        if swar and fused_crc:
-            u32 = jnp.asarray(tile.view(np.uint32))  # async H2D
-            out = encode_u32_crc(u32)
-            arms["swar+crc"] += 1
+        fused_crc = swar and _crc_ok(tile.shape[1], want_crcs)
+        if fused_crc:
+            arm, program = "swar+crc", encode_u32_crc
         elif swar:
-            u32 = jnp.asarray(tile.view(np.uint32))  # async H2D
-            out = encode_u32(u32)  # async dispatch
-            arms["swar"] += 1
+            arm, program = "swar", encode_u32
         else:
-            out = kern.encode(jnp.asarray(tile))
-            fused_crc = False
-            arms["bit-matmul"] += 1
+            arm, program = "bit-matmul", kern.encode
+        with trace.annotation("ec.h2d"):
+            # async H2D; the SWAR arms take the byte stream 4 per lane
+            dev = jnp.asarray(tile.view(np.uint32) if swar else tile)
+        t1 = time.perf_counter()
+        with trace.annotation("ec.launch"):
+            out = program(dev)  # async dispatch
+        t2 = time.perf_counter()
+        arms[arm] += 1
+        book("h2d_s", t1 - t0)
+        book("launch_s", t2 - t1)
         return out, swar, fused_crc
 
     parity_fn.arms = arms
@@ -1586,12 +1615,15 @@ def _stream_batch_chunk(
         "writeback_s": 0.0,
         "compute_s": 0.0,
         "write_s": 0.0,
+        **_DEVICE_BUSY,
     }
     busy_lock = threading.Lock()
     round_crcs: list = [None] * rounds
+    last_fetch = [0.0]  # as in stream_write_ec_files: where ec.op.drain ends
     wall0 = time.perf_counter()
     _sp = trace.span("ec_stream.encode_batch", nbytes=sum(sizes))
     _sp.__enter__()
+    phases = trace.Phases("ec.op.head", wall0)
 
     idx_lock = threading.Lock()
     idx_iter = iter(range(rounds))
@@ -1616,14 +1648,15 @@ def _stream_batch_chunk(
                 buf3 = buf[: b * DATA_SHARDS * width].reshape(
                     b, DATA_SHARDS, width
                 )
-                for v in range(b):
-                    if r >= len(tiles[v]):
-                        continue  # volume done: zero-step, output discarded
-                    row_off, block, batch_off, step = tiles[v][r]
-                    _read_tile_into(
-                        fds[v], sizes[v], row_off, block, batch_off, step,
-                        buf3[v, :, :step],
-                    )
+                with trace.annotation("ec.read"):
+                    for v in range(b):
+                        if r >= len(tiles[v]):
+                            continue  # volume done: zero-step, output discarded
+                        row_off, block, batch_off, step = tiles[v][r]
+                        _read_tile_into(
+                            fds[v], sizes[v], row_off, block, batch_off, step,
+                            buf3[v, :, :step],
+                        )
                 _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
                 if not _q_put(read_q, (r, slot_id, buf3), pipe.stop):
                     ring.release(slot_id)
@@ -1641,17 +1674,20 @@ def _stream_batch_chunk(
                 return
             r, slot_id, buf3, handle = item
             t0 = time.perf_counter()
-            if fused_crc:
-                parity_dev, crcs_dev = handle
-                crcs = np.asarray(jax.device_get(crcs_dev))
-            else:
-                parity_dev, crcs = handle, None
-            parity = (
-                np.asarray(jax.device_get(parity_dev))
-                .view(np.uint8)
-                .reshape(b, PARITY_SHARDS, width)
-            )
+            with trace.annotation("ec.writeback"):
+                if fused_crc:
+                    parity_dev, crcs_dev = handle
+                    crcs = np.asarray(jax.device_get(crcs_dev))
+                else:
+                    parity_dev, crcs = handle, None
+                parity = (
+                    np.asarray(jax.device_get(parity_dev))
+                    .view(np.uint8)
+                    .reshape(b, PARITY_SHARDS, width)
+                )
             t1 = time.perf_counter()
+            with busy_lock:
+                last_fetch[0] = max(last_fetch[0], t1)
             vol_crcs: list = [None] * b
             if want_crcs:
                 from seaweedfs_tpu.util.crc import crc32c
@@ -1677,19 +1713,20 @@ def _stream_batch_chunk(
                             for p in range(PARITY_SHARDS)
                         ]
             t2 = time.perf_counter()
-            for v in range(b):
-                step = step_of[r][v]
-                if not step:
-                    continue
-                off = out_offs[r][v]
-                for i in range(DATA_SHARDS):
-                    _pwrite_full(out_fds[v][i], buf3[v, i, :step], off)
-                for p in range(PARITY_SHARDS):
-                    _pwrite_full(
-                        out_fds[v][DATA_SHARDS + p],
-                        np.ascontiguousarray(parity[v, p, :step]),
-                        off,
-                    )
+            with trace.annotation("ec.write"):
+                for v in range(b):
+                    step = step_of[r][v]
+                    if not step:
+                        continue
+                    off = out_offs[r][v]
+                    for i in range(DATA_SHARDS):
+                        _pwrite_full(out_fds[v][i], buf3[v, i, :step], off)
+                    for p in range(PARITY_SHARDS):
+                        _pwrite_full(
+                            out_fds[v][DATA_SHARDS + p],
+                            np.ascontiguousarray(parity[v, p, :step]),
+                            off,
+                        )
             t3 = time.perf_counter()
             if want_crcs:
                 round_crcs[r] = vol_crcs
@@ -1720,25 +1757,35 @@ def _stream_batch_chunk(
             pipe.spawn(reader)
         for _ in range(writer_threads):
             pipe.spawn(writer)
-        for _ in range(rounds):
+        for n in range(rounds):
             item = _q_get(read_q, pipe.stop)
             if item is _STOPPED:
                 break
             r, slot_id, buf3 = item
             t0 = time.perf_counter()
+            if n == 0:
+                phases.to("ec.op.dispatch", t0)
             # staging: the u32 lane view is free host-side; device_put
             # lays the batch out P('vol', None, 'stripe') over the mesh
-            vols = codec.shard_volumes(buf3.view(np.uint32))
+            with trace.annotation("ec.h2d"):
+                vols = codec.shard_volumes(buf3.view(np.uint32))
+            th = time.perf_counter()
             held = min(held, codec.devices_holding(vols))
             t1 = time.perf_counter()
-            handle = (
-                codec.encode_batch_u32_crc(vols)
-                if fused_crc
-                else codec.encode_batch_u32(vols)
-            )
+            with trace.annotation("ec.launch"):
+                handle = (
+                    codec.encode_batch_u32_crc(vols)
+                    if fused_crc
+                    else codec.encode_batch_u32(vols)
+                )
             t2 = time.perf_counter()
+            if n == rounds - 1:
+                phases.to("ec.op.drain", t2)
             _charge(busy, busy_lock, "stage_s", t1 - t0)
             _charge(busy, busy_lock, "device_s", t2 - t1)
+            # the transfer is part of the stage; the launch is all of device_s
+            _charge(busy, busy_lock, "h2d_s", th - t0)
+            _charge(busy, busy_lock, "launch_s", t2 - t1)
             if not _q_put(write_q, (r, slot_id, buf3, handle), pipe.stop):
                 break
         for _ in range(writer_threads):
@@ -1749,7 +1796,8 @@ def _stream_batch_chunk(
         try:
             pipe.finish(caller_error=not ok)
         finally:
-            tc0 = time.perf_counter()
+            phases.to("ec.op.write_tail", (ok and last_fetch[0]) or None)
+            phases.to("ec.op.flush")
             fsync_err: OSError | None = None
             try:
                 for fds in out_fds:
@@ -1776,10 +1824,11 @@ def _stream_batch_chunk(
                 if fsync_err is not None:
                     raise fsync_err
             finally:
-                busy["flush_s"] = time.perf_counter() - tc0
+                end = _close_phases(phases, busy)
                 if stats is not None:
                     _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads
+                        stats, busy, wall0, reader_threads, writer_threads,
+                        end,
                     )
                     stats["pipeline_depth"] = depth
                     stats["ring_slots"] = ring.slots

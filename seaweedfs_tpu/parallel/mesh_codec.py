@@ -30,6 +30,10 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from seaweedfs_tpu.ec.codec_tpu import (
+    SCOPE_CRC_FOLD,
+    SCOPE_CRC_GATHER,
+    SCOPE_LAYOUT,
+    SCOPE_SWAR,
     TpuCodecKernels,
     apply_matrix_bits_batch,
     apply_matrix_bits_u32_batch,
@@ -227,13 +231,17 @@ class MeshCodec:
         if use_swar:
 
             def per_device(vols_u32):
-                return swar_apply_matrix_u32_batch(rows, vols_u32, interpret)
+                with jax.named_scope(SCOPE_SWAR):
+                    return swar_apply_matrix_u32_batch(rows, vols_u32, interpret)
 
         else:
             bits = gf_matrix_to_bits(rows)
 
             def per_device(vols_u32):
-                return apply_matrix_bits_u32_batch(jnp.asarray(bits), vols_u32)
+                with jax.named_scope(SCOPE_SWAR):
+                    return apply_matrix_bits_u32_batch(
+                        jnp.asarray(bits), vols_u32
+                    )
 
         return per_device
 
@@ -318,18 +326,23 @@ class MeshCodec:
         stripe = self.mesh.shape[STRIPE_AXIS]
 
         def per_device(vols_u32):  # [Bb, k, Nb]
-            parity = per_device_apply(vols_u32)
-            full = jnp.concatenate([vols_u32, parity], axis=1)
-            lin = crc_kernel.crc_lin_rows(full)  # [Bb, k+p] raw CRCs
+            parity = per_device_apply(vols_u32)  # under SCOPE_SWAR
+            with jax.named_scope(SCOPE_LAYOUT):
+                full = jnp.concatenate([vols_u32, parity], axis=1)
+            with jax.named_scope(SCOPE_CRC_FOLD):
+                lin = crc_kernel.crc_lin_rows(full)  # [Bb, k+p] raw CRCs
             seg_bytes = full.shape[-1] * 4
             if stripe > 1:
-                segs = jax.lax.all_gather(lin, STRIPE_AXIS)  # [S, Bb, R]
-                zbits = jnp.asarray(crc_kernel._shift_bitmat(seg_bytes))
-                acc = segs[0]
-                for s in range(1, stripe):
-                    acc = crc_kernel._apply_bits(acc, zbits) ^ segs[s]
-                lin = acc
-            crcs = crc_kernel.finalize_rows(lin, seg_bytes * stripe)
+                with jax.named_scope(SCOPE_CRC_GATHER):
+                    segs = jax.lax.all_gather(lin, STRIPE_AXIS)  # [S, Bb, R]
+                with jax.named_scope(SCOPE_CRC_FOLD):
+                    zbits = jnp.asarray(crc_kernel._shift_bitmat(seg_bytes))
+                    acc = segs[0]
+                    for s in range(1, stripe):
+                        acc = crc_kernel._apply_bits(acc, zbits) ^ segs[s]
+                    lin = acc
+            with jax.named_scope(SCOPE_CRC_FOLD):
+                crcs = crc_kernel.finalize_rows(lin, seg_bytes * stripe)
             return parity, crcs
 
         return jax.jit(

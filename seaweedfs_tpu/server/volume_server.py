@@ -24,6 +24,7 @@ VolumeEcShardRead stream the reference uses (store_ec.go:279).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import os
@@ -1098,14 +1099,22 @@ class VolumeServer:
 
     @staticmethod
     def _log_ec_verb(verb: str, vids, st: dict) -> None:
-        """One line per EC verb: the driver and kernel arm that ran and
-        the stage seconds the driver booked — the server's own account
-        of whether the device arm ran (SWAR not bit-matmul, stream
-        driver not the classic loop, device_s > 0)."""
+        """One line per EC verb, written once the verb's work is done
+        and before the RPC returns: the driver and kernel arm that ran
+        and the seconds the driver booked — the server's own account of
+        whether the device arm ran (SWAR not bit-matmul, stream driver
+        not the classic loop, device_s > 0) and of where the operation's
+        time went. Pool stages are thread-seconds; the phases (head_s
+        ... flush_s) partition wall_s, and publish_s follows it."""
         keys = (
             "driver", "arms", "mesh", "fallback", "codec_arm",
             "batch_volumes", "read_s", "stage_s", "device_s",
             "writeback_s", "compute_s", "write_s", "encode_s", "wall_s",
+            # serial phases of the operation on the handler's thread
+            "head_s", "dispatch_span_s", "drain_s", "write_tail_s",
+            "flush_s", "publish_s",
+            # the dispatcher's share of device_s / stage_s in a device stage
+            "h2d_s", "launch_s",
         )
         wlog.info(
             "ec.%s vid=%s report=%s",
@@ -1115,6 +1124,16 @@ class VolumeServer:
         )
 
     def VolumeEcShardsGenerate(self, req, context):
+        with trace.span(
+            "volume.ec_generate",
+            header=trace.header_from_grpc_context(context),
+            node=f"{self.host}:{self.port}",
+        ) as sp:
+            if sp:
+                sp.annotate("vid", req.volume_id)
+            return self._ec_shards_generate(req, context)
+
+    def _ec_shards_generate(self, req, context):
         self._ensure_owned(req.volume_id)
         v = self.store.find_volume(req.volume_id)
         if v is None:
@@ -1131,17 +1150,33 @@ class VolumeServer:
         ec_files.write_ec_files(
             base, rs=self._new_rs(), durable=True, stats=st, want_crcs=True
         )
-        self._log_ec_verb("generate", req.volume_id, st)
-        crcs = st.get("shard_crcs")
-        if crcs:
-            wlog.info(
-                "ec.generate vid=%s shard_crc32c=%s",
-                req.volume_id,
-                ",".join(f"{c:08x}" for c in crcs),
-            )
-            self._publish_ecc(base, crcs)
-        ec_files.write_sorted_file_from_idx(base, durable=True)
+        with self._ec_publish("generate", req.volume_id, st):
+            crcs = st.get("shard_crcs")
+            if crcs:
+                wlog.info(
+                    "ec.generate vid=%s shard_crc32c=%s",
+                    req.volume_id,
+                    ",".join(f"{c:08x}" for c in crcs),
+                )
+                self._publish_ecc(base, crcs)
+            ec_files.write_sorted_file_from_idx(base, durable=True)
         return pb.VolumeEcShardsGenerateResponse()
+
+    @contextlib.contextmanager
+    def _ec_publish(self, verb: str, vids, st: dict):
+        """The generate verbs' last phase, from the driver's return to
+        the `.ecc` published and the `.ecx` sorted and fsynced: the
+        `ec.publish` span and annotation, `publish_s`, and then the
+        verb's ONE report line — after the publish, so that the line
+        accounts for all of the operation, and in a `finally`, so that
+        a failed publish still reports."""
+        phase = trace.Phases("ec.publish")
+        try:
+            yield
+        finally:
+            phase.close()
+            st["publish_s"] = round(phase.seconds["ec.publish"], 4)
+            self._log_ec_verb(verb, vids, st)
 
     def VolumeEcShardsBatchGenerate(self, req, context):
         """N local sealed volumes → shard files through ONE mesh
@@ -1153,6 +1188,16 @@ class VolumeServer:
         returning on both arms, so the .ecx publish below can imply
         shard bytes are on disk (the single-volume verb's weedcrash
         ordering)."""
+        with trace.span(
+            "volume.ec_batch_generate",
+            header=trace.header_from_grpc_context(context),
+            node=f"{self.host}:{self.port}",
+        ) as sp:
+            if sp:
+                sp.annotate("vids", list(req.volume_ids))
+            return self._ec_shards_batch_generate(req, context)
+
+    def _ec_shards_batch_generate(self, req, context):
         bases = []
         for vid in req.volume_ids:
             v = self.store.find_volume(vid)
@@ -1170,18 +1215,18 @@ class VolumeServer:
                 stats=st,
                 want_crcs=True,
             )
-            self._log_ec_verb("batch_generate", list(req.volume_ids), st)
-            for vid, base, crcs in zip(
-                req.volume_ids, bases, st.get("shard_crcs") or []
-            ):
-                wlog.info(
-                    "ec.batch_generate vid=%s shard_crc32c=%s",
-                    vid,
-                    ",".join(f"{c:08x}" for c in crcs),
-                )
-                self._publish_ecc(base, crcs)
-            for base in bases:
-                ec_files.write_sorted_file_from_idx(base, durable=True)
+            with self._ec_publish("batch_generate", list(req.volume_ids), st):
+                for vid, base, crcs in zip(
+                    req.volume_ids, bases, st.get("shard_crcs") or []
+                ):
+                    wlog.info(
+                        "ec.batch_generate vid=%s shard_crc32c=%s",
+                        vid,
+                        ",".join(f"{c:08x}" for c in crcs),
+                    )
+                    self._publish_ecc(base, crcs)
+                for base in bases:
+                    ec_files.write_sorted_file_from_idx(base, durable=True)
         return pb.VolumeEcShardsBatchGenerateResponse()
 
     def VolumeEcShardsRebuild(self, req, context):

@@ -5,8 +5,11 @@ hop records a span into a lock-cheap per-process ring buffer, and the
 `X-Weed-Trace` header carries `trace_id:parent_span_id:plane` across
 every internal HTTP and gRPC hop — replica fan-out, `x-shard-hop`
 worker forwarding, EC remote shard reads, scrub/repair rebuild traffic.
+`profiler.py` puts an operation's phases and pool stages on the JAX
+profiler's clock as well.
 """
 
+from seaweedfs_tpu.trace.profiler import Phases, annotation
 from seaweedfs_tpu.trace.tracer import (
     TRACE_HEADER,
     Span,
@@ -37,9 +40,11 @@ from seaweedfs_tpu.trace.tracer import (
 
 __all__ = [
     "TRACE_HEADER",
+    "Phases",
     "Span",
     "add_stages",
     "annotate",
+    "annotation",
     "connection_tracer",
     "current",
     "current_trace_id",

@@ -1,0 +1,518 @@
+"""Where one encode operation's time goes (ISSUE 26, docs/TRACING.md):
+the serial phases of the two encode drivers partition the wall, the
+device stage's H2D / launch split reconciles with the pool stages it
+refines, the spans and profiler annotations carry the same
+names, and the node's one report line per operation carries all of it.
+
+Everything runs on the CPU backend: what is asserted is bookkeeping
+(sums, parents, names, counts), never a device time."""
+
+import glob
+import importlib.util
+import json
+import logging
+import os
+import time
+
+import grpc
+import numpy as np
+import pytest
+
+from seaweedfs_tpu import trace
+from seaweedfs_tpu.ec import ec_stream
+from seaweedfs_tpu.ec.codec import new_encoder
+from seaweedfs_tpu.pb import rpc, volume_pb2
+from seaweedfs_tpu.server.master_server import MasterServer
+from seaweedfs_tpu.server.volume_server import VolumeServer
+from seaweedfs_tpu.util.availability import free_port
+
+LARGE = 64 * 1024
+SMALL = 16 * 1024
+PHASE_FIELDS = ("head_s", "dispatch_span_s", "drain_s", "write_tail_s", "flush_s")
+PHASE_SPANS = tuple(ec_stream._OP_PHASES)
+DEVICE_FIELDS = tuple(ec_stream._DEVICE_BUSY)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _make_dat(base: str, nbytes: int, seed: int = 0) -> None:
+    rng = np.random.default_rng(seed)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, nbytes, dtype=np.uint8).tobytes())
+
+
+def _encode_single(tmp_path, host_pair: bool, rows: int = 6) -> dict:
+    base = str(tmp_path / "v")
+    _make_dat(base, 10 * SMALL * rows + 77)
+    fns = {}
+    if host_pair:
+        fns["parity_fn"], fns["fetch_fn"] = ec_stream.local_encode_fns(
+            new_encoder(backend="cpu"), want_crcs=True
+        )
+    stats: dict = {}
+    ec_stream.stream_write_ec_files(
+        base, tile_bytes=SMALL, large_block_size=LARGE, small_block_size=SMALL,
+        stats=stats, want_crcs=True, **fns,
+    )
+    return stats
+
+
+def _encode_batch(tmp_path, volumes: int = 4, rows: int = 3) -> dict:
+    bases = []
+    for i in range(volumes):
+        bases.append(str(tmp_path / f"b{i}"))
+        _make_dat(bases[-1], 10 * SMALL * rows + i, seed=i)
+    stats: dict = {}
+    ec_stream.stream_write_ec_files_batch(
+        bases, tile_bytes=SMALL, large_block_size=LARGE, small_block_size=SMALL,
+        stats=stats, want_crcs=True,
+    )
+    return stats
+
+
+DRIVERS = {
+    "single-host-pair": lambda p: _encode_single(p, host_pair=True),
+    "single-device": lambda p: _encode_single(p, host_pair=False),
+    "batch": _encode_batch,
+}
+
+
+@pytest.fixture
+def ring():
+    trace.reset()
+    yield
+    trace.reset()
+
+
+def _recent() -> list[dict]:
+    return trace.debug_payload(n=256)["recent"]
+
+
+# --- the phases ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("driver", sorted(DRIVERS))
+def test_phases_partition_the_wall(driver, tmp_path):
+    stats = DRIVERS[driver](tmp_path)
+    for field in PHASE_FIELDS:
+        assert stats[field] >= 0, field
+    # each of the six numbers is rounded to 1e-4 on its own
+    assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
+        stats["wall_s"], abs=3.5e-4
+    )
+    assert stats["dispatch_span_s"] > 0
+    assert "loop_s" not in stats and "overlap_s" not in stats
+
+
+def test_phases_partition_an_aborted_operation(tmp_path):
+    """A stage error skips phases; what was entered still sums to the
+    wall, and the fields are all there."""
+    base = str(tmp_path / "v")
+    _make_dat(base, 10 * SMALL * 4)
+
+    def boom(handle):
+        raise RuntimeError("fetch failed")
+
+    stats: dict = {}
+    with pytest.raises(RuntimeError, match="fetch failed"):
+        ec_stream.stream_write_ec_files(
+            base, tile_bytes=SMALL, large_block_size=LARGE,
+            small_block_size=SMALL, parity_fn=lambda t: t, fetch_fn=boom,
+            stats=stats,
+        )
+    assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
+        stats["wall_s"], abs=3.5e-4
+    )
+
+
+def test_phases_helper_shares_its_samples(ring):
+    with trace.span("root") as root:
+        phases = trace.Phases("p.a", 100.0)
+        phases.to("p.b", 101.5)
+        phases.to("p.a", 102.0)  # a phase entered twice adds up
+        end = phases.close(104.0)
+    assert end == 104.0
+    assert phases.seconds == {"p.a": 3.5, "p.b": 0.5}
+    spans = [s for s in _recent() if s["name"].startswith("p.")]
+    assert sorted(s["dur_ms"] for s in spans) == [500.0, 1500.0, 2000.0]
+    assert {s["parent"] for s in spans} == {root.span_id}
+
+
+def test_phases_helper_takes_no_sample_older_than_the_open_phase():
+    phases = trace.Phases("p.a", 100.0)
+    phases.to("p.b", 101.0)
+    phases.to("p.c", 99.0)  # older than p.b's start: counts as that start
+    phases.close(103.0)
+    assert phases.seconds == {"p.a": 1.0, "p.b": 0.0, "p.c": 2.0}
+
+
+def test_drain_ends_at_the_last_fetch_not_at_the_join(tmp_path, monkeypatch):
+    """The writers leave the latest fetch-return sample in one slot and
+    the handler's thread reads it after the join: slow shard writes lie
+    in write_tail_s, a slow fetch in drain_s."""
+    real = ec_stream._pwritev_full
+
+    def slow_write(fd, views, offset):
+        time.sleep(0.02)
+        real(fd, views, offset)
+
+    monkeypatch.setattr(ec_stream, "_pwritev_full", slow_write)
+    stats = _encode_single(tmp_path, host_pair=True, rows=1)
+    assert stats["write_tail_s"] >= 0.2  # 14 writes of the last tile
+    assert stats["drain_s"] < stats["write_tail_s"]
+    assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
+        stats["wall_s"], abs=3.5e-4
+    )
+
+
+# --- the device stage's split -------------------------------------------------
+
+
+def test_single_driver_device_split_reconciles(tmp_path):
+    stats = _encode_single(tmp_path, host_pair=False, rows=6)
+    tiles = sum(stats["arms"].values())
+    assert tiles == 7  # six rows and the tail
+    # per dispatch the driver's own samples bracket parity_fn's by a
+    # call and a few dict stores
+    slack = 1e-3 * tiles + 5e-4
+    assert stats["h2d_s"] + stats["launch_s"] == pytest.approx(
+        stats["device_s"], abs=slack
+    )
+    assert stats["h2d_s"] + stats["launch_s"] <= stats["device_s"] + 5e-4
+    # off the TPU the stage takes the bit-matmul arm
+    assert stats["arms"]["bit-matmul"] == tiles
+
+
+def test_batch_driver_device_split_reconciles(tmp_path):
+    stats = _encode_batch(tmp_path, volumes=4, rows=3)
+    assert stats["launch_s"] == pytest.approx(stats["device_s"], abs=2e-4)
+    assert 0 < stats["h2d_s"] <= stats["stage_s"] + 1e-4
+
+
+def test_chunked_batch_adds_the_seconds_up(tmp_path, monkeypatch):
+    """WEED_EC_PIPELINE_BATCH splits the verb's batch into chunks whose
+    seconds add up: over all chunks the phases still partition the wall
+    and the device stage's split still reconciles."""
+    monkeypatch.setenv("WEED_EC_PIPELINE_BATCH", "2")
+    chunked = _encode_batch(tmp_path, volumes=4, rows=2)
+    assert chunked["batch_volumes"] == 4
+    assert sum(chunked[f] for f in PHASE_FIELDS) == pytest.approx(
+        chunked["wall_s"], abs=7e-4
+    )
+    assert chunked["launch_s"] == pytest.approx(chunked["device_s"], abs=4e-4)
+
+
+def test_host_stage_pairs_book_no_device_field(tmp_path):
+    stats = _encode_single(tmp_path, host_pair=True)
+    assert not set(DEVICE_FIELDS) & set(stats)
+    assert stats["driver"] == "stream-host" and stats["compute_s"] > 0
+
+
+# --- spans --------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("driver", ["single-device", "batch"])
+def test_phase_spans_hang_off_the_drivers_root(driver, tmp_path, ring):
+    stats = DRIVERS[driver](tmp_path)
+    spans = _recent()
+    root = [s for s in spans if s["name"].startswith("ec_stream.encode")]
+    assert len(root) == 1
+    phases = {s["name"]: s for s in spans if s["name"] in PHASE_SPANS}
+    assert set(phases) == set(PHASE_SPANS)
+    for name, field in ec_stream._OP_PHASES.items():
+        assert phases[name]["parent"] == root[0]["span"], name
+        assert phases[name]["dur_ms"] == pytest.approx(stats[field] * 1e3, abs=0.11)
+    # one vocabulary: the root's stages are the report line's fields
+    assert set(root[0]["stages_ms"]) == {k for k in stats if k.endswith("_s")} - {"wall_s"}
+    # and never a span per tile: the root and its five phases
+    assert len(spans) == 6
+
+
+@pytest.fixture(scope="module")
+def node(tmp_path_factory):
+    master = MasterServer(port=free_port(), volume_size_limit_mb=64)
+    master.start()
+    vs = VolumeServer(
+        [str(tmp_path_factory.mktemp("opvs"))],
+        port=free_port(),
+        master=f"127.0.0.1:{master.port}",
+        heartbeat_interval=0.2,
+        max_volume_counts=[100],
+        ec_codec="tpu",
+    )
+    vs.start()
+    deadline = time.time() + 10
+    while time.time() < deadline and not master.topology.data_nodes():
+        time.sleep(0.05)
+    yield master, vs
+    vs.stop()
+    master.stop()
+
+
+def _sealed_volume(master, vs, collection: str) -> int:
+    import urllib.request
+
+    with urllib.request.urlopen(
+        f"http://127.0.0.1:{master.port}/dir/assign?collection={collection}",
+        timeout=10,
+    ) as r:
+        assign = json.loads(r.read())
+    urllib.request.urlopen(
+        urllib.request.Request(
+            f"http://{assign['url']}/{assign['fid']}",
+            data=bytes(range(256)) * 1200, method="POST",
+        ),
+        timeout=10,
+    ).close()
+    return int(assign["fid"].split(",")[0])
+
+
+class _Lines(logging.Handler):
+    """The node's log as the benchmark reads it: wlog's own format."""
+
+    def __init__(self):
+        super().__init__()
+        self.setFormatter(logging.Formatter(
+            "%(levelname).1s%(asctime)s %(module)s:%(lineno)d] %(message)s",
+            datefmt="%m%d %H:%M:%S",
+        ))
+        self.lines: list[str] = []
+
+    def emit(self, record):
+        self.lines.append(self.format(record))
+
+
+@pytest.fixture
+def node_log():
+    handler = _Lines()
+    logger = logging.getLogger("seaweedfs_tpu")
+    logger.addHandler(handler)
+    yield handler.lines
+    logger.removeHandler(handler)
+
+
+def _verb_reports(text: str, verb: str) -> list[dict]:
+    """benchmark/harness/node.py's own parser of the report lines."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_harness_node", os.path.join(REPO, "benchmark", "harness", "node.py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.verb_reports(text, verb)
+
+
+CALLS = {
+    "generate": lambda stub, vids, md: stub.VolumeEcShardsGenerate(
+        volume_pb2.VolumeEcShardsGenerateRequest(volume_id=vids[0], collection="op1"),
+        metadata=md,
+    ),
+    "batch_generate": lambda stub, vids, md: stub.VolumeEcShardsBatchGenerate(
+        volume_pb2.VolumeEcShardsBatchGenerateRequest(volume_ids=vids), metadata=md
+    ),
+}
+
+
+@pytest.fixture
+def stream_device_driver(monkeypatch):
+    """On the chip a node whose codec is tpu encodes one volume through
+    the stream driver's device stage; here the chip is a CPU and
+    ec_files routes the verb to the classic loop. Steer it the chip's
+    way (the stage then takes its bit-matmul arm)."""
+    from seaweedfs_tpu.ec import ec_files
+
+    monkeypatch.setattr(ec_files, "_use_stream_driver", lambda rs: True)
+
+
+@pytest.mark.parametrize("verb,volumes", [("generate", 1), ("batch_generate", 2)])
+def test_handler_span_report_line_and_publish(
+    verb, volumes, node, node_log, ring, stream_device_driver
+):
+    """Over gRPC with a caller's trace header: handler span <- wire,
+    driver root and ec.publish <- handler span, ONE report line, written
+    after the publish, that the benchmark's regex parses and that holds
+    every new field."""
+    master, vs = node
+    vids = [_sealed_volume(master, vs, f"op{volumes}") for _ in range(volumes)]
+    with grpc.insecure_channel(f"127.0.0.1:{vs.grpc_port}") as ch:
+        stub = rpc.volume_stub(ch)
+        for vid in vids:
+            stub.VolumeMarkReadonly(volume_pb2.VolumeMarkReadonlyRequest(volume_id=vid))
+        trace.reset()
+        del node_log[:]
+        CALLS[verb](stub, vids, ((trace.TRACE_HEADER, "00000000000000ab:000000cd:serve"),))
+
+    spans = _recent()
+    handler = [s for s in spans if s["name"] == f"volume.ec_{verb}"]
+    assert len(handler) == 1
+    assert handler[0]["trace"] == "00000000000000ab"
+    assert handler[0]["parent"] == "000000cd"
+    roots = [s for s in spans if s["name"].startswith("ec_stream.encode")]
+    publish = [s for s in spans if s["name"] == "ec.publish"]
+    assert len(roots) == 1 and len(publish) == 1
+    assert roots[0]["parent"] == publish[0]["parent"] == handler[0]["span"]
+    assert {s["trace"] for s in spans} == {"00000000000000ab"}
+    # handler, driver root, five phases, the publish: no span per tile
+    assert len(spans) == 8
+
+    text = "\n".join(node_log)
+    reports = _verb_reports(text, verb)
+    assert len(reports) == 1, text
+    report = reports[0]
+    for field in PHASE_FIELDS + DEVICE_FIELDS + ("publish_s", "wall_s"):
+        assert field in report, field
+    assert report["publish_s"] > 0
+    assert sum(report[f] for f in PHASE_FIELDS) == pytest.approx(
+        report["wall_s"], abs=3.5e-4
+    )
+    # after the publish: the CRC breadcrumbs, which the publish writes,
+    # come before the report line in the log
+    lines = text.splitlines()
+    report_at = next(i for i, ln in enumerate(lines) if " report={" in ln)
+    crc_at = [i for i, ln in enumerate(lines) if "shard_crc32c=" in ln]
+    assert crc_at and max(crc_at) < report_at
+    for vid in vids:
+        base = vs.store.find_volume(vid).base_name
+        assert os.path.exists(base + ".ecx") and os.path.exists(base + ".ecc")
+
+
+def test_failed_publish_still_reports(node, node_log, monkeypatch, stream_device_driver):
+    from seaweedfs_tpu.ec import ec_files
+
+    master, vs = node
+    vid = _sealed_volume(master, vs, "op1")
+
+    def no_index(base, durable=False):
+        raise OSError("no space for the index")
+
+    monkeypatch.setattr(ec_files, "write_sorted_file_from_idx", no_index)
+    with grpc.insecure_channel(f"127.0.0.1:{vs.grpc_port}") as ch:
+        stub = rpc.volume_stub(ch)
+        stub.VolumeMarkReadonly(volume_pb2.VolumeMarkReadonlyRequest(volume_id=vid))
+        del node_log[:]
+        with pytest.raises(grpc.RpcError):
+            CALLS["generate"](stub, [vid], None)
+    reports = _verb_reports("\n".join(node_log), "generate")
+    assert len(reports) == 1 and "publish_s" in reports[0]
+
+
+# --- the profiler's clock -----------------------------------------------------
+
+
+def test_annotations_reach_a_profiler_trace(tmp_path):
+    """One CPU profiler session around a small encode: the phases and
+    the pool stages are TraceMe events on the host plane's thread lines
+    (read as benchmark/selftest reads its recorded trace)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # as the benchmark's node launcher sets it
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=options)
+    try:
+        _encode_single(tmp_path, host_pair=False)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert found
+    on_host: dict[str, int] = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("ec."):
+                    on_host[ev.name] = on_host.get(ev.name, 0) + 1
+    for name in PHASE_SPANS:
+        assert on_host.get(name) == 1, (name, on_host)
+    for name in ("ec.read", "ec.h2d", "ec.launch", "ec.writeback", "ec.write"):
+        assert on_host.get(name) == 7, (name, on_host)  # one per tile
+
+
+def test_tracing_off_books_the_fields_and_annotates_nothing(tmp_path, monkeypatch, ring):
+    import jax
+
+    opened: list[str] = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    trace.set_enabled(False)
+    try:
+        stats = _encode_single(tmp_path, host_pair=False)
+    finally:
+        trace.set_enabled(True)
+    assert opened == []
+    assert _recent() == []
+    for field in PHASE_FIELDS + DEVICE_FIELDS:
+        assert field in stats, field
+    assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
+        stats["wall_s"], abs=3.5e-4
+    )
+    # and with it on, the same helper does open them
+    _encode_single(tmp_path, host_pair=False)
+    assert "ec.op.drain" in opened and "ec.writeback" in opened
+
+
+def test_annotation_leaves_jax_alone_where_it_is_not_loaded():
+    """Daemons that need no JAX never import it (PR 21): the helper asks
+    sys.modules, it does not import."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from seaweedfs_tpu import trace\n"
+        "with trace.annotation('ec.read'):\n"
+        "    phases = trace.Phases('ec.op.head')\n"
+        "    phases.close()\n"
+        "assert 'jax' not in sys.modules, 'the helper imported jax'\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True, text=True, timeout=60
+    )
+    assert proc.returncode == 0, proc.stderr
+
+
+# --- the device scopes --------------------------------------------------------
+
+
+def test_fused_programs_carry_the_scopes():
+    """The three parts of the fused encode program, and the mesh
+    program's gather, lower under stable scope names (the compiled-for-
+    TPU view of the same is tests/test_tpu_compile.py's)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from seaweedfs_tpu.ec import codec_tpu
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+    from seaweedfs_tpu.parallel import MeshCodec, make_mesh
+
+    def scoped(text: str, scope: str) -> bool:
+        # a component of an operation's name stack: "jit(f)/ec.swar/..."
+        # at the top level, "ec.swar/..." inside a shard_map
+        return re.search(rf'["/]{re.escape(scope)}/', text) is not None
+
+    kern = TpuCodecKernels()
+    x = jax.ShapeDtypeStruct((10, 1024), jnp.uint32)
+    text = jax.jit(kern.encode_u32_crc).lower(x).as_text(debug_info=True)
+    for scope in (codec_tpu.SCOPE_SWAR, codec_tpu.SCOPE_LAYOUT, codec_tpu.SCOPE_CRC_FOLD):
+        assert scoped(text, scope), scope
+    rebuilt = jax.jit(
+        lambda t: kern.reconstruct_u32_crc(tuple(range(1, 11)), (0,), t)
+    ).lower(x).as_text(debug_info=True)
+    assert scoped(rebuilt, "ec.swar") and scoped(rebuilt, "ec.crc_fold")
+
+    codec = MeshCodec(make_mesh(jax.devices()[:4], stripe=2))
+    vols = jax.ShapeDtypeStruct((2, 10, 2048), jnp.uint32, sharding=codec.block_sharding)
+    mesh_text = codec._encode_crc_sharded.lower(vols).as_text(debug_info=True)
+    for scope in ("ec.swar", "ec.layout", "ec.crc_fold", "ec.crc_gather"):
+        assert scoped(mesh_text, scope), scope

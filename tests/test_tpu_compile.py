@@ -212,3 +212,47 @@ def test_mesh_verify_batch_u32(topo, vol, stripe):
     )
     assert "tpu_custom_call" in text
     assert ("all-reduce" in text) == (stripe > 1)
+
+
+# --- the names a trace reader finds them under --------------------------------
+
+# benchmark/metrics/swar_kernel_roofline.json's own pattern: the Pallas
+# kernel's event in a device trace is named by this instruction's text
+SWAR_EVENT = r"%swar_apply[\w.]* = .*custom-call"
+
+
+def _scoped(text: str, scope: str) -> bool:
+    import re
+
+    return re.search(rf'op_name="[^"]*/{re.escape(scope)}/', text) is not None
+
+
+def test_encode_u32_crc_names(one_chip, on_tpu):
+    """The compiled single-volume program: the kernel keeps the name the
+    accepted roofline metric matches, and its three parts carry the
+    scopes in their op_name metadata (the fusions' own names,
+    `%fusion.1`, are the compiler's and move with any refactor)."""
+    import re
+
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+
+    text = _compiled_text(
+        TpuCodecKernels().encode_u32_crc, _u32((10, TILE_LANES), one_chip)
+    )
+    assert re.search(SWAR_EVENT, text)
+    for scope in ("ec.swar", "ec.layout", "ec.crc_fold"):
+        assert _scoped(text, scope), scope
+
+
+def test_mesh_encode_batch_u32_crc_names(topo):
+    import re
+
+    codec, sharding = _mesh_codec(topo, 2, 2)
+    text = _compiled_text(
+        codec.encode_batch_u32_crc, _u32((4, 10, TILE_LANES), sharding)
+    )
+    assert re.search(SWAR_EVENT, text)
+    for scope in ("ec.swar", "ec.layout", "ec.crc_fold", "ec.crc_gather"):
+        assert _scoped(text, scope), scope
+    gather = next(ln for ln in text.splitlines() if " all-gather(" in ln)
+    assert _scoped(gather, "ec.crc_gather")
