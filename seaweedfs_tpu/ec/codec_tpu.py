@@ -32,6 +32,7 @@ over a Mesh for the batched multi-volume paths
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -42,9 +43,43 @@ from jax.experimental.pallas import tpu as pltpu
 from seaweedfs_tpu.ec import gf256
 from seaweedfs_tpu.ec.codec import register_backend
 from seaweedfs_tpu.ec.compile_cache import place_compile_cache
+from seaweedfs_tpu.stats.metrics import EC_PROGRAM_TRACES
 from seaweedfs_tpu.util import wlog
 
 place_compile_cache()
+
+# --- the kept programs' trace count ------------------------------------------
+# The EC drivers' device programs are built once per process (ec_stream's
+# program holder, parallel/mesh_codec.MeshCodec) and every operation
+# reuses them; what proves it is a count of how often JAX ran a program's
+# Python body, which it does only while tracing.
+
+_thread_traces = threading.local()
+
+
+def program_traces() -> int:
+    """How often the CALLING thread has traced a counted_jit program so
+    far. JAX traces on the thread that made the call, and an EC
+    operation has one dispatcher thread, so the difference of two
+    readings on that thread is the operation's own count whatever other
+    operations run beside it."""
+    return getattr(_thread_traces, "n", 0)
+
+
+def counted_jit(fn, **jit_kwargs):
+    """jax.jit(fn) whose body counts its traces: per thread
+    (program_traces) and on /metrics (weed_ec_program_traces_total).
+    The name and signature stay fn's (static_argnums, the program's name
+    in a device trace)."""
+
+    @functools.wraps(fn)
+    def body(*args):
+        _thread_traces.n = program_traces() + 1
+        EC_PROGRAM_TRACES.inc()
+        return fn(*args)
+
+    return jax.jit(body, **jit_kwargs)
+
 
 # Scopes of the fused encode/rebuild programs, here and in
 # parallel/mesh_codec.py: the names a trace reader finds the three parts

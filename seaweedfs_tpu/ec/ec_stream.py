@@ -450,6 +450,7 @@ def stream_write_ec_files(
     }
     busy_lock = threading.Lock()
     if device_stage:
+        traces0 = _program_traces()
         busy.update(_DEVICE_BUSY)
         parity_fn, fetch_fn = _tpu_encode_fns(
             want_crcs=want_crcs,
@@ -758,6 +759,8 @@ def stream_write_ec_files(
                             tiles, tile_crcs
                         )
                 _trace_stages(_sp, busy)
+                if device_stage:
+                    _report_traces(stats, _sp, traces0)
                 # a stage error re-raised by pipe.finish() is live in
                 # this finally; hand it to the span so a failed drive
                 # is distinguishable from a clean one in /debug/traces
@@ -836,6 +839,7 @@ def stream_rebuild_ec_files(
         raise ValueError("rebuild_fn and fetch_fn must be injected together")
     device_stage = rebuild_fn is None
     if device_stage:
+        traces0 = _program_traces()
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs)
     # rebuild tiles read one span from each of 10 FILES. Re-swept with
     # the staging ring (BENCH_r12): LOCAL rebuilds want fine tiles —
@@ -1177,6 +1181,8 @@ def stream_rebuild_ec_files(
                         )
                         stats["serve_yields"] = session.yields
                 _trace_stages(_sp, busy)
+                if device_stage:
+                    _report_traces(stats, _sp, traces0)
                 if session is not None and _sp:
                     _sp.annotate("donated_bytes", session.used_donated_bytes)
                     _sp.annotate("serve_yields", session.yields)
@@ -1283,26 +1289,108 @@ def _new_arms() -> dict:
     return {"swar+crc": 0, "swar": 0, "bit-matmul": 0}
 
 
+_KEPT: dict[tuple, object] = {}
+_KEPT_LOCK = threading.Lock()
+
+
+def _kept(key: tuple, build: Callable[[], "object"]):
+    """The process's one object for `key`, built under a lock the first
+    time it is asked for (gRPC handler threads run EC verbs
+    concurrently) and handed out without one from then on."""
+    obj = _KEPT.get(key)
+    if obj is None:
+        with _KEPT_LOCK:
+            obj = _KEPT.get(key)
+            if obj is None:
+                obj = _KEPT[key] = build()
+    return obj
+
+
+class _DevicePrograms:
+    """The single-chip stage pairs' device programs: ONE TpuCodecKernels
+    and one jitted program per kernel arm of encode and of rebuild, kept
+    for the life of the process. A jax.jit object carries its own cache
+    of traced and loaded programs, so an operation that made new ones
+    traced, lowered and re-loaded a program the process ran a second
+    before (0.5 s of a 1.2 s encode of 1 GiB on a v5e, PERF.md section
+    6, PR 27); these are traced once per tile shape (and per survivor
+    set, the rebuild programs' static arguments) and then only launched.
+    Each body counts its traces (codec_tpu.counted_jit).
+
+    Use after construction takes no lock: jax.jit objects are
+    thread-safe, and two threads that miss one of the kernels' decode
+    row dicts at once both fill it with the same matrix. What now lives
+    as long as the process is bounded by the distinct (survivors,
+    targets) sets it has rebuilt, at most 15,015 for RS(10,4) (1,001
+    survivor sets by the 15 subsets of the other four): per set a 4x10
+    coefficient matrix, on the bit-matmul arm its 32x80 bit matrix
+    (2.6 KB, on the host), and one compiled program, which JAX's own
+    bounded caches hold."""
+
+    def __init__(self):
+        from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels, counted_jit
+
+        self.kern = kern = TpuCodecKernels(DATA_SHARDS, PARITY_SHARDS)
+        # no donate_argnums: no output has the [10, n32] tile's shape, so
+        # XLA cannot reuse its buffer ("Some donated buffers were not
+        # usable" on the chip, PR 21); the tile is freed once the kernel
+        # has read it either way, because nothing keeps a reference
+        self.encode_u32 = counted_jit(kern.encode_u32)
+        # fused encode+CRC program (ec/crc_kernel.py rides the same
+        # dispatch): parity AND all 14 per-row CRCs come back from one
+        # device pass, so the host never re-reads parity bytes to
+        # checksum them
+        self.encode_u32_crc = counted_jit(kern.encode_u32_crc)
+        self.encode = counted_jit(kern.encode)
+        self.reconstruct_u32 = counted_jit(
+            kern.reconstruct_u32, static_argnums=(0, 1)
+        )
+        self.reconstruct_u32_crc = counted_jit(
+            kern.reconstruct_u32_crc, static_argnums=(0, 1)
+        )
+        self.reconstruct = counted_jit(kern.reconstruct, static_argnums=(0, 1))
+
+
+def _device_programs() -> _DevicePrograms:
+    """The process's _DevicePrograms for everything a trace of them
+    reads that is not an argument: the code's shape, the kernel arm
+    (_on_tpu) and the XOR schedule flag (swar_apply_matrix_u32 reads it
+    while tracing). The key only keeps apart programs that differ."""
+    from seaweedfs_tpu.ec.codec_tpu import _on_tpu
+    from seaweedfs_tpu.ec.schedule import schedule_enabled
+
+    return _kept(
+        ("chip", DATA_SHARDS, PARITY_SHARDS, _on_tpu(), schedule_enabled()),
+        _DevicePrograms,
+    )
+
+
+def _program_traces() -> int:
+    """codec_tpu.program_traces() of the calling thread (imported here:
+    only a device stage may pull JAX in)."""
+    from seaweedfs_tpu.ec.codec_tpu import program_traces
+
+    return program_traces()
+
+
+def _report_traces(stats: dict | None, sp, traces0: int) -> None:
+    """program_traces of the operation that read `traces0` at its start
+    on this, its dispatcher's, thread: into its stats (the node's report
+    line) and onto its root span. 0 in steady state."""
+    n = _program_traces() - traces0
+    if stats is not None:
+        stats["program_traces"] = n
+    sp.annotate("program_traces", n)
+
+
 def _tpu_encode_fns(want_crcs: bool, book: Callable[[str, float], None]):
     """(parity_fn, fetch_fn) on the attached device. `book(field, dt)`
-    takes the device fields (_DEVICE_BUSY) of the driver's `busy`."""
-    import jax
+    takes the device fields (_DEVICE_BUSY) of the driver's `busy`. The
+    programs are the process's (_device_programs); the closures, the
+    arm counts and the booking are this operation's."""
     import jax.numpy as jnp
 
-    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
-
-    kern = TpuCodecKernels(DATA_SHARDS, PARITY_SHARDS)
-    # no donate_argnums: no output has the [10, n32] tile's shape, so
-    # XLA cannot reuse its buffer ("Some donated buffers were not
-    # usable" on the chip, PR 21); the tile is freed once the kernel
-    # has read it either way, because nothing keeps a reference
-    encode_u32 = jax.jit(kern.encode_u32)
-    # fused encode+CRC program (ec/crc_kernel.py rides the same
-    # dispatch): parity AND all 14 per-row CRCs come back from one
-    # device pass, so the host never re-reads parity bytes to
-    # checksum them
-    encode_u32_crc = jax.jit(kern.encode_u32_crc)
-
+    progs = _device_programs()
     arms = _new_arms()
 
     def parity_fn(tile: np.ndarray):
@@ -1310,11 +1398,11 @@ def _tpu_encode_fns(want_crcs: bool, book: Callable[[str, float], None]):
         swar = _swar_ok(tile.shape[1])
         fused_crc = swar and _crc_ok(tile.shape[1], want_crcs)
         if fused_crc:
-            arm, program = "swar+crc", encode_u32_crc
+            arm, program = "swar+crc", progs.encode_u32_crc
         elif swar:
-            arm, program = "swar", encode_u32
+            arm, program = "swar", progs.encode_u32
         else:
-            arm, program = "bit-matmul", kern.encode
+            arm, program = "bit-matmul", progs.encode
         with trace.annotation("ec.h2d"):
             # async H2D; the SWAR arms take the byte stream 4 per lane
             dev = jnp.asarray(tile.view(np.uint32) if swar else tile)
@@ -1332,32 +1420,25 @@ def _tpu_encode_fns(want_crcs: bool, book: Callable[[str, float], None]):
 
 
 def _tpu_rebuild_fns(want_crcs: bool = False):
-    import jax
+    """(rebuild_fn, fetch_fn) on the attached device, over the same kept
+    programs as _tpu_encode_fns."""
     import jax.numpy as jnp
 
-    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
-
-    kern = TpuCodecKernels(DATA_SHARDS, PARITY_SHARDS)
-    recon = jax.jit(kern.reconstruct_u32, static_argnums=(0, 1))
-    recon_crc = jax.jit(kern.reconstruct_u32_crc, static_argnums=(0, 1))
-
+    progs = _device_programs()
     arms = _new_arms()
 
     def rebuild_fn(survivors, targets, tile: np.ndarray):
         swar = _swar_ok(tile.shape[1])
-        fused_crc = _crc_ok(tile.shape[1], want_crcs)
-        if swar and fused_crc:
-            u32 = jnp.asarray(tile.view(np.uint32))
-            out = recon_crc(tuple(survivors), tuple(targets), u32)
-            arms["swar+crc"] += 1
+        fused_crc = swar and _crc_ok(tile.shape[1], want_crcs)
+        if fused_crc:
+            arm, program = "swar+crc", progs.reconstruct_u32_crc
         elif swar:
-            u32 = jnp.asarray(tile.view(np.uint32))
-            out = recon(tuple(survivors), tuple(targets), u32)
-            arms["swar"] += 1
+            arm, program = "swar", progs.reconstruct_u32
         else:
-            out = kern.reconstruct(survivors, targets, jnp.asarray(tile))
-            fused_crc = False
-            arms["bit-matmul"] += 1
+            arm, program = "bit-matmul", progs.reconstruct
+        dev = jnp.asarray(tile.view(np.uint32) if swar else tile)
+        out = program(tuple(survivors), tuple(targets), dev)
+        arms[arm] += 1
         return out, swar, fused_crc
 
     rebuild_fn.arms = arms
@@ -1453,6 +1534,8 @@ def stream_write_ec_files_batch(
                     if isinstance(v, float):
                         # stage seconds accumulate across chunks
                         stats[k] = round(stats.get(k, 0.0) + v, 4)
+                    elif k == "program_traces":
+                        stats[k] = stats.get(k, 0) + v
                     elif k != "shard_crcs":
                         # structural fields (pipeline_depth, mesh,
                         # ring_slots, thread counts): last chunk's
@@ -1503,18 +1586,30 @@ def stream_write_ec_files_batch(
 
 
 def _default_mesh_codec(batch: int):
-    """MeshCodec over all devices with the 'vol' axis sized to
-    gcd(batch, devices) so any batch shards cleanly (the
-    BatchGenerate verb's mesh recipe, now owned by the driver)."""
+    """The process's MeshCodec over all devices with the 'vol' axis
+    sized to gcd(batch, devices) so any batch shards cleanly (the
+    BatchGenerate verb's mesh recipe, owned by the driver). The SAME
+    object for the same mesh, because its jitted programs live on it
+    (see _DevicePrograms): one on one chip, at most three on four
+    (the gcd is 1, 2 or 4). The schedule flag is in the key because the
+    mesh programs' SWAR kernels read it while tracing."""
     import math
 
     import jax
 
+    from seaweedfs_tpu.ec.schedule import schedule_enabled
     from seaweedfs_tpu.parallel import MeshCodec, make_mesh
 
     devices = jax.devices()
     vol_axis = math.gcd(batch, len(devices))
-    return MeshCodec(make_mesh(devices, stripe=len(devices) // vol_axis))
+    stripe = len(devices) // vol_axis
+    return _kept(
+        (
+            "mesh", vol_axis, stripe, tuple(d.id for d in devices),
+            schedule_enabled(),
+        ),
+        lambda: MeshCodec(make_mesh(devices, stripe=stripe)),
+    )
 
 
 class _HostBatchCodec:
@@ -1620,6 +1715,7 @@ def _stream_batch_chunk(
     busy_lock = threading.Lock()
     round_crcs: list = [None] * rounds
     last_fetch = [0.0]  # as in stream_write_ec_files: where ec.op.drain ends
+    traces0 = _program_traces()
     wall0 = time.perf_counter()
     _sp = trace.span("ec_stream.encode_batch", nbytes=sum(sizes))
     _sp.__enter__()
@@ -1846,6 +1942,7 @@ def _stream_batch_chunk(
                             b, step_of, round_crcs
                         )
                 _trace_stages(_sp, busy)
+                _report_traces(stats, _sp, traces0)
                 _sp.__exit__(*sys.exc_info())
 
 
@@ -1987,7 +2084,7 @@ def stream_rebuild_ec_files_batch(
 
     limit = pipeline_batch_limit()
     crcs_by_vol: dict[int, dict] = {}
-    float_acc: dict[str, float] = {}
+    summed: dict[str, float] = {}  # stage seconds, program_traces
     last_struct: dict = {}
     for (survivors, targets), idxs in groups.items():
         chunks = (
@@ -2017,11 +2114,13 @@ def stream_rebuild_ec_files_batch(
                     crcs_by_vol[i] = crcs
             for k, v in chunk_stats.items():
                 if isinstance(v, float):
-                    float_acc[k] = round(float_acc.get(k, 0.0) + v, 4)
+                    summed[k] = round(summed.get(k, 0.0) + v, 4)
+                elif k == "program_traces":
+                    summed[k] = summed.get(k, 0) + v
                 elif k != "shard_crcs":
                     last_struct[k] = v
     if stats is not None:
-        stats.update(float_acc)
+        stats.update(summed)
         stats.update(last_struct)
         stats["batch_volumes"] = len(base_file_names)
         stats["batch_groups"] = len(groups)
@@ -2115,6 +2214,7 @@ def _rebuild_batch_chunk(
     }
     busy_lock = threading.Lock()
     round_crcs: list = [None] * rounds
+    traces0 = _program_traces()
     wall0 = time.perf_counter()
     _sp = trace.span(
         "ec_stream.rebuild_batch",
@@ -2316,6 +2416,7 @@ def _rebuild_batch_chunk(
                             b, targets, step_of, round_crcs
                         )
                 _trace_stages(_sp, busy)
+                _report_traces(stats, _sp, traces0)
                 _sp.__exit__(*sys.exc_info())
 
 

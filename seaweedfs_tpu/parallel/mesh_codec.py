@@ -37,6 +37,7 @@ from seaweedfs_tpu.ec.codec_tpu import (
     TpuCodecKernels,
     apply_matrix_bits_batch,
     apply_matrix_bits_u32_batch,
+    counted_jit,
     gf_matrix_to_bits,
     swar_apply_matrix_u32_batch,
     swar_verify_matrix_u32_batch,
@@ -66,7 +67,19 @@ def make_mesh(
 
 
 class MeshCodec:
-    """RS(k,p) batched encode / rebuild / verify over a Mesh."""
+    """RS(k,p) batched encode / rebuild / verify over a Mesh.
+
+    The jitted programs live ON the instance (cached properties, and
+    _sharded_u32_cache per coefficient matrix), each counting its traces
+    (codec_tpu.counted_jit): a caller that wants a program traced once
+    keeps its MeshCodec. The batch drivers' own
+    (ec_stream._default_mesh_codec) lives as long as the process, and
+    with it _sharded_u32_cache and _decode_bits_dev: one entry per
+    distinct (survivors, targets) set batch-rebuilt on this mesh, at
+    most 1,470 for RS(10,4) with every survivor local (the missing set
+    fixes the survivors), each one compiled program or a 2.6 KB
+    bit-matrix on the device. Use after construction takes no lock: two
+    threads that miss a cache at once both fill it with the same thing."""
 
     def __init__(self, mesh: Mesh, data_shards: int = 10, parity_shards: int = 4):
         self.mesh = mesh
@@ -132,7 +145,7 @@ class MeshCodec:
             in_specs=(P(), P(VOL_AXIS, None, STRIPE_AXIS)),
             out_specs=P(VOL_AXIS, None, STRIPE_AXIS),
         )
-        return jax.jit(fn)
+        return counted_jit(fn)
 
     def _swar_ok(self, n_bytes: int) -> bool:
         """True when the byte-layout APIs route through the SWAR u32
@@ -186,7 +199,7 @@ class MeshCodec:
         if fn is not None:
             return fn
         per_device = self._swar_bytes_per_device(rows)
-        fn = jax.jit(
+        fn = counted_jit(
             shard_map(
                 per_device,
                 mesh=self.mesh,
@@ -256,7 +269,7 @@ class MeshCodec:
         if fn is not None:
             return fn
         per_device = self._per_device_u32_apply(rows)
-        fn = jax.jit(
+        fn = counted_jit(
             shard_map(
                 per_device,
                 mesh=self.mesh,
@@ -345,7 +358,7 @@ class MeshCodec:
                 crcs = crc_kernel.finalize_rows(lin, seg_bytes * stripe)
             return parity, crcs
 
-        return jax.jit(
+        return counted_jit(
             shard_map(
                 per_device,
                 mesh=self.mesh,
@@ -441,7 +454,7 @@ class MeshCodec:
             ),
             out_specs=P(VOL_AXIS),
         )
-        return jax.jit(fn)
+        return counted_jit(fn)
 
     @functools.cached_property
     def _verify_sharded_swar(self):
@@ -456,7 +469,7 @@ class MeshCodec:
             )  # [Bb] — mismatched-byte count, identical to the matmul tier
             return jax.lax.psum(local, STRIPE_AXIS)
 
-        return jax.jit(
+        return counted_jit(
             shard_map(
                 per_device,
                 mesh=self.mesh,
@@ -497,7 +510,7 @@ class MeshCodec:
                 )  # [Bb]
                 return jax.lax.psum(local, STRIPE_AXIS)
 
-        return jax.jit(
+        return counted_jit(
             shard_map(
                 per_device,
                 mesh=self.mesh,

@@ -1115,6 +1115,9 @@ class VolumeServer:
             "flush_s", "publish_s",
             # the dispatcher's share of device_s / stage_s in a device stage
             "h2d_s", "launch_s",
+            # device programs traced during the operation: 0 after a
+            # node's first verb per tile shape and survivor set
+            "program_traces",
         )
         wlog.info(
             "ec.%s vid=%s report=%s",

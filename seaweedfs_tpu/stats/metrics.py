@@ -436,6 +436,12 @@ EC_TILE_CACHE = DEFAULT_REGISTRY.counter(
     "reconstructed-tile cache probes on the degraded read path",
     ("result",),  # result: hit | miss
 )
+EC_PROGRAM_TRACES = DEFAULT_REGISTRY.counter(
+    "weed_ec_program_traces_total",
+    "times JAX traced the body of a kept EC device program "
+    "(codec_tpu.counted_jit): grows at a node's first verb per tile "
+    "shape and survivor set, then stands still",
+)
 EC_REPAIR_BYTES_READ = DEFAULT_REGISTRY.counter(
     "weed_ec_repair_bytes_read_total",
     "survivor bytes gathered by EC rebuild, by where they came from",

@@ -374,6 +374,51 @@ def test_handler_span_report_line_and_publish(
         assert os.path.exists(base + ".ecx") and os.path.exists(base + ".ecc")
 
 
+@pytest.mark.parametrize("verb,volumes", [("generate", 1), ("batch_generate", 2)])
+def test_report_line_and_metrics_carry_program_traces(
+    verb, volumes, node, node_log, stream_device_driver
+):
+    """The same verb twice on one node (ISSUE 27): every report line
+    carries `program_traces`, the repeat's is 0, and the node's
+    /metrics has the process-wide counter, which the repeat leaves
+    where it was."""
+    import urllib.request
+
+    def counter() -> float:
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{vs.port}/metrics", timeout=10
+        ) as r:
+            text = r.read().decode()
+        line = next(
+            ln for ln in text.splitlines()
+            if ln.startswith("weed_ec_program_traces_total")
+        )
+        return float(line.split()[-1])
+
+    master, vs = node
+    before = []
+    with grpc.insecure_channel(f"127.0.0.1:{vs.grpc_port}") as ch:
+        stub = rpc.volume_stub(ch)
+        del node_log[:]
+        for _ in range(2):
+            vids = [
+                _sealed_volume(master, vs, f"tr{volumes}") for _ in range(volumes)
+            ]
+            for vid in vids:
+                stub.VolumeMarkReadonly(
+                    volume_pb2.VolumeMarkReadonlyRequest(volume_id=vid)
+                )
+            before.append(counter())
+            CALLS[verb](stub, vids, None)
+    reports = _verb_reports("\n".join(node_log), verb)
+    assert len(reports) == 2
+    # the worker's other tests may have traced these shapes already
+    assert reports[0]["program_traces"] in (0, 1)
+    assert reports[1]["program_traces"] == 0
+    assert before[1] - before[0] == reports[0]["program_traces"]
+    assert counter() == before[1]
+
+
 def test_failed_publish_still_reports(node, node_log, monkeypatch, stream_device_driver):
     from seaweedfs_tpu.ec import ec_files
 

@@ -256,3 +256,30 @@ def test_mesh_encode_batch_u32_crc_names(topo):
         assert _scoped(text, scope), scope
     gather = next(ln for ln in text.splitlines() if " all-gather(" in ln)
     assert _scoped(gather, "ec.crc_gather")
+
+
+# --- the kept programs themselves ----------------------------------------------
+
+
+def test_kept_encode_program_compiles_and_keeps_its_names(
+    one_chip, on_tpu, monkeypatch
+):
+    """The very jit object the stream encode driver launches
+    (ec_stream._device_programs, taken under the chip's key): it lowers
+    for the chip with the kernel and the scopes under the names the
+    trace readers match, as a module named after the method — the
+    counting wrapper (codec_tpu.counted_jit) leaves no name of its own."""
+    import re
+
+    from seaweedfs_tpu.ec import codec_tpu, ec_stream
+
+    monkeypatch.setattr(ec_stream, "_KEPT", {})
+    traces0 = codec_tpu.program_traces()
+    program = ec_stream._device_programs().encode_u32_crc
+    shape = _u32((10, TILE_LANES), one_chip)
+    text = program.lower(shape).compile().as_text()
+    assert codec_tpu.program_traces() - traces0 == 1
+    assert text.startswith("HloModule jit_encode_u32_crc")
+    assert re.search(SWAR_EVENT, text)
+    for scope in ("ec.swar", "ec.layout", "ec.crc_fold"):
+        assert _scoped(text, scope), scope
