@@ -1932,6 +1932,9 @@ def _stream_batch_chunk(
                     stats["mesh"] = {
                         **codec.report(), "devices_per_round": held
                     }
+                    # the same count as a number of its own: a metric
+                    # term reads a top-level field, not a nested dict
+                    stats["mesh_devices"] = held
                     if (
                         want_crcs
                         and ok
@@ -1943,6 +1946,9 @@ def _stream_batch_chunk(
                         )
                 _trace_stages(_sp, busy)
                 _report_traces(stats, _sp, traces0)
+                _sp.annotate("mesh", f"{vol_axis}x{stripe}")
+                _sp.annotate("mesh_devices", held)
+                _sp.annotate("batch_volumes", b)
                 _sp.__exit__(*sys.exc_info())
 
 

@@ -1107,8 +1107,8 @@ class VolumeServer:
         time went. Pool stages are thread-seconds; the phases (head_s
         ... flush_s) partition wall_s, and publish_s follows it."""
         keys = (
-            "driver", "arms", "mesh", "fallback", "codec_arm",
-            "batch_volumes", "read_s", "stage_s", "device_s",
+            "driver", "arms", "mesh", "mesh_devices", "fallback",
+            "codec_arm", "batch_volumes", "read_s", "stage_s", "device_s",
             "writeback_s", "compute_s", "write_s", "encode_s", "wall_s",
             # serial phases of the operation on the handler's thread
             "head_s", "dispatch_span_s", "drain_s", "write_tail_s",

@@ -363,6 +363,18 @@ def test_handler_span_report_line_and_publish(
     assert sum(report[f] for f in PHASE_FIELDS) == pytest.approx(
         report["wall_s"], abs=3.5e-4
     )
+    if verb == "batch_generate":
+        # the mesh as a number on the line and as attributes of the root
+        # span (ISSUE 28): two volumes on the worker's 8 virtual devices
+        # are vol = gcd(2, 8) = 2 by stripe = 4
+        mesh = report["mesh"]
+        assert type(report["mesh_devices"]) is int
+        assert report["mesh_devices"] == mesh["devices_per_round"] == 8
+        assert roots[0]["annot"]["mesh"] == f"{mesh['vol']}x{mesh['stripe']}" == "2x4"
+        assert roots[0]["annot"]["mesh_devices"] == "8"
+        assert roots[0]["annot"]["batch_volumes"] == "2"
+    else:
+        assert "mesh_devices" not in report
     # after the publish: the CRC breadcrumbs, which the publish writes,
     # come before the report line in the log
     lines = text.splitlines()
