@@ -19,7 +19,11 @@ version removes both bottlenecks:
     final size (posix_fallocate, ftruncate fallback), and written with
     positioned os.pwritev at each tile's precomputed output offset —
     no userspace buffering accumulates, so close() is free and
-    `flush_s` measures only the os.close loop;
+    `flush_s` measures only the os.close loop. In the encode drivers
+    the WRITER POOL does the preallocating, beside the first reads and
+    dispatches, and a latch keeps every shard write behind the last
+    reservation (_Reservation); the rebuild drivers, with one to four
+    files to reserve, still do it on the caller's thread;
   * a READER POOL claims tiles from a shared index and fills a bounded
     queue (each thread owns its fds: positioned preadv, no seek
     state), so the ten survivor reads of a rebuild tile — or tiles of
@@ -221,12 +225,63 @@ class _Pipeline:
             raise self.errors[0]
 
 
+class _Reservation:
+    """One encode operation's shard files, reserved by its writer pool
+    behind a count-down latch. Every writer thread starts by claiming
+    (fd, size) pairs from ONE shared iterator and preallocating each
+    (`reserve`), then enters its ordinary loop; `wait` stands directly
+    before a writer's pwritevs, so no shard byte of the operation is
+    written until every file of the operation has its final extent —
+    what the handler's serial loop guaranteed, without the readers, the
+    dispatcher and the device waiting for it. ENOSPC from a reservation
+    is a stage error like any other (_Pipeline.spawn), and because of
+    the latch it still fails before the first write. `wait` obeys the
+    pipeline's stop flag the way _q_get does, so another stage's error
+    cannot leave a writer parked here."""
+
+    def __init__(self, files: list[tuple[int, int]], stop: threading.Event):
+        self._files = iter(files)
+        self._left = len(files)
+        self._stop = stop
+        self._lock = threading.Lock()
+        self._open = threading.Event()
+        # the clock sample at which the last file was reserved (0.0: the
+        # latch never opened)
+        self.opened_at = 0.0
+
+    def reserve(self, book: Callable[[str, float], None]) -> None:
+        while not self._stop.is_set():
+            with self._lock:
+                claim = next(self._files, None)
+            if claim is None:
+                return
+            t0 = time.perf_counter()
+            with trace.annotation("ec.reserve"):
+                _preallocate(*claim)
+            t1 = time.perf_counter()
+            book("reserve_s", t1 - t0)
+            with self._lock:
+                self._left -= 1
+                if not self._left:
+                    self.opened_at = t1
+                    self._open.set()
+
+    def wait(self) -> bool:
+        """True once every file is reserved; False when the pipeline
+        aborted first."""
+        while not self._open.is_set():
+            if self._stop.is_set():
+                return False
+            self._open.wait(_Q_TICK)
+        return True
+
+
 # --- raw-fd IO primitives ---------------------------------------------------
 
 
 def _preallocate(fd: int, size: int) -> None:
     """Reserve the file's exact final extent up front so ENOSPC fails
-    before the pipeline spins up and close() has no deferred work.
+    before any shard byte is written and close() has no deferred work.
     posix_fallocate allocates real blocks where the filesystem supports
     it; anything it can't do degrades to ftruncate (sparse extent —
     every byte is positioned-written exactly once anyway)."""
@@ -289,8 +344,9 @@ def _charge(busy: dict, lock: threading.Lock, key: str, dt: float) -> None:
 
 # The serial phases of one encode operation, all on the handler's thread
 # (it is the dispatcher): span and annotation name -> report field. They
-# partition wall_s (trace.Phases): head (open + preallocate the shard
-# files, spawn the pools, first read) | dispatch (first dispatch -> last
+# partition wall_s (trace.Phases): head (open the shard files, spawn
+# the pools, first read; the writers reserve the files meanwhile, see
+# _Reservation) | dispatch (first dispatch -> last
 # dispatch returned) | drain (-> the last writer's fetch returned:
 # nothing left to send, the host waits for the device and the D2H) |
 # write_tail (-> the pools joined) | flush (the fsync + close loop).
@@ -308,6 +364,19 @@ _OP_PHASES = {
 # is device_s; the batch driver's transfer is part of its stage_s and
 # launch_s == device_s. Host stage pairs book neither.
 _DEVICE_BUSY = {"h2d_s": 0.0, "launch_s": 0.0}
+
+# What the encode drivers book for the shard files' reservation
+# (_Reservation): reserve_s is a pool stage like read_s, the writer
+# pool's thread-seconds inside _preallocate; reserve_done_s is one
+# clock sample, wall seconds from the driver's clock start to the
+# moment the last file was reserved and the first shard write could go
+# (0.0 on an operation that aborted before that).
+_RESERVE_BUSY = {"reserve_s": 0.0, "reserve_done_s": 0.0}
+
+
+def _book_reserve_done(busy: dict, reservation, wall0: float) -> None:
+    if reservation is not None and reservation.opened_at:
+        busy["reserve_done_s"] = reservation.opened_at - wall0
 
 
 def _close_phases(phases, busy: dict) -> float:
@@ -447,15 +516,14 @@ def stream_write_ec_files(
         "writeback_s": 0.0,
         "compute_s": 0.0,
         "write_s": 0.0,
+        **_RESERVE_BUSY,
     }
     busy_lock = threading.Lock()
+    book = functools.partial(_charge, busy, busy_lock)
     if device_stage:
         traces0 = _program_traces()
         busy.update(_DEVICE_BUSY)
-        parity_fn, fetch_fn = _tpu_encode_fns(
-            want_crcs=want_crcs,
-            book=functools.partial(_charge, busy, busy_lock),
-        )
+        parity_fn, fetch_fn = _tpu_encode_fns(want_crcs=want_crcs, book=book)
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
     writer_threads = writer_threads or DEFAULT_WRITER_THREADS
     reader_threads = reader_threads or DEFAULT_READER_THREADS
@@ -494,6 +562,7 @@ def stream_write_ec_files(
         shard_bytes += step * rows
 
     out_fds: list[int] = []  # opened inside the try: no leak on ENOSPC
+    reservation: _Reservation | None = None
     pipe = _Pipeline()
     read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
     write_q: queue.Queue = queue.Queue(maxsize=depth)
@@ -578,6 +647,7 @@ def stream_write_ec_files(
             os.close(fd)
 
     def writer():
+        reservation.reserve(book)
         while True:
             item = _q_get(write_q, pipe.stop)
             if item is _EOF or item is _STOPPED:
@@ -619,6 +689,9 @@ def stream_write_ec_files(
                         for p in range(PARITY_SHARDS)
                     ]
             t2 = time.perf_counter()
+            if not reservation.wait():
+                return
+            tw = time.perf_counter()
             with trace.annotation("ec.write"):
                 for i in range(DATA_SHARDS):
                     _pwritev_full(
@@ -648,7 +721,7 @@ def stream_write_ec_files(
             ring.release(slot_id)
             _charge(busy, busy_lock, fetch_bucket, t1 - t0)
             _charge(busy, busy_lock, "compute_s", t2 - t1)
-            _charge(busy, busy_lock, "write_s", t3 - t2)
+            _charge(busy, busy_lock, "write_s", t3 - tw)
 
     ok = False
     try:
@@ -660,12 +733,14 @@ def stream_write_ec_files(
                     0o644,
                 )
             )
-        for fd in out_fds:
-            _preallocate(fd, shard_bytes)
-        for _ in range(reader_threads):
-            pipe.spawn(reader)
+        reservation = _Reservation(
+            [(fd, shard_bytes) for fd in out_fds], pipe.stop
+        )
+        # writers first: they reserve the shard files beside the first reads
         for _ in range(writer_threads):
             pipe.spawn(writer)
+        for _ in range(reader_threads):
+            pipe.spawn(reader)
         for n in range(len(tiles)):
             item = _q_get(read_q, pipe.stop)
             if item is _STOPPED:
@@ -739,6 +814,7 @@ def stream_write_ec_files(
                 # flush_s measures only the fsync + close syscalls (the
                 # previous driver lost 47% of wall right here)
                 end = _close_phases(phases, busy)
+                _book_reserve_done(busy, reservation, wall0)
                 if stats is not None:
                     _finish_stats(
                         stats, busy, wall0, reader_threads, writer_threads,
@@ -1711,6 +1787,7 @@ def _stream_batch_chunk(
         "compute_s": 0.0,
         "write_s": 0.0,
         **_DEVICE_BUSY,
+        **_RESERVE_BUSY,
     }
     busy_lock = threading.Lock()
     round_crcs: list = [None] * rounds
@@ -1724,6 +1801,7 @@ def _stream_batch_chunk(
     idx_lock = threading.Lock()
     idx_iter = iter(range(rounds))
     out_fds: list[list[int]] = []
+    reservation: _Reservation | None = None
     # fewest mesh devices that held part of a round's batch: the
     # sharding can silently land everything on device 0
     held = vol_axis * stripe
@@ -1764,6 +1842,7 @@ def _stream_batch_chunk(
     def writer():
         import jax
 
+        reservation.reserve(functools.partial(_charge, busy, busy_lock))
         while True:
             item = _q_get(write_q, pipe.stop)
             if item is _EOF or item is _STOPPED:
@@ -1809,6 +1888,9 @@ def _stream_batch_chunk(
                             for p in range(PARITY_SHARDS)
                         ]
             t2 = time.perf_counter()
+            if not reservation.wait():
+                return
+            tw = time.perf_counter()
             with trace.annotation("ec.write"):
                 for v in range(b):
                     step = step_of[r][v]
@@ -1829,11 +1911,11 @@ def _stream_batch_chunk(
             ring.release(slot_id)
             _charge(busy, busy_lock, "writeback_s", t1 - t0)
             _charge(busy, busy_lock, "compute_s", t2 - t1)
-            _charge(busy, busy_lock, "write_s", t3 - t2)
+            _charge(busy, busy_lock, "write_s", t3 - tw)
 
     ok = False
     try:
-        for v, base in enumerate(bases):
+        for base in bases:
             fds = []
             out_fds.append(fds)
             for i in range(TOTAL_SHARDS):
@@ -1844,15 +1926,19 @@ def _stream_batch_chunk(
                         0o644,
                     )
                 )
-            size = shard_file_size(
-                sizes[v], large_block_size, small_block_size
-            )
-            for fd in fds:
-                _preallocate(fd, size)
-        for _ in range(min(reader_threads, rounds)):
-            pipe.spawn(reader)
+        reservation = _Reservation(
+            [
+                (fd, shard_file_size(size, large_block_size, small_block_size))
+                for fds, size in zip(out_fds, sizes)
+                for fd in fds
+            ],
+            pipe.stop,
+        )
+        # writers first, as in stream_write_ec_files
         for _ in range(writer_threads):
             pipe.spawn(writer)
+        for _ in range(min(reader_threads, rounds)):
+            pipe.spawn(reader)
         for n in range(rounds):
             item = _q_get(read_q, pipe.stop)
             if item is _STOPPED:
@@ -1921,6 +2007,7 @@ def _stream_batch_chunk(
                     raise fsync_err
             finally:
                 end = _close_phases(phases, busy)
+                _book_reserve_done(busy, reservation, wall0)
                 if stats is not None:
                     _finish_stats(
                         stats, busy, wall0, reader_threads, writer_threads,

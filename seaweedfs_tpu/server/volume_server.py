@@ -1115,6 +1115,9 @@ class VolumeServer:
             "flush_s", "publish_s",
             # the dispatcher's share of device_s / stage_s in a device stage
             "h2d_s", "launch_s",
+            # the writer pool's thread-seconds reserving the shard files,
+            # and the wall second at which the last of them was reserved
+            "reserve_s", "reserve_done_s",
             # device programs traced during the operation: 0 after a
             # node's first verb per tile shape and survivor set
             "program_traces",

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import tempfile
 import textwrap
 
@@ -496,6 +497,40 @@ class TestCrashMatrix:
         rep = crash.run_ec_encode(budget=96)
         assert rep.states_tested >= 24
         assert rep.violations == []
+
+    def test_ec_encode_reserves_every_shard_before_the_first_write(
+        self, monkeypatch
+    ):
+        """The writer pool reserves the shard files (ISSUE 29): in the
+        recorded trace every shard inode's trunc events (create, then
+        the posix_fallocate to its final size) precede the operation's
+        first shard write, whichever pool thread made them."""
+        seen = {}
+        real_sweep = crash.sweep
+
+        def keep_trace(trace, *args, **kwargs):
+            seen["trace"] = trace
+            return real_sweep(trace, *args, **kwargs)
+
+        monkeypatch.setattr(crash, "sweep", keep_trace)
+        crash.run_ec_encode(budget=8)
+        events = seen["trace"].events
+        shards = {
+            ev.ino for ev in events
+            if ev.kind == "link" and re.search(r"\.ec\d\d$", ev.path)
+        }
+        assert len(shards) == 14
+        first_write = min(
+            i for i, ev in enumerate(events)
+            if ev.kind == "write" and ev.ino in shards
+        )
+        for ino in shards:
+            truncs = [
+                (i, ev.size) for i, ev in enumerate(events)
+                if ev.kind == "trunc" and ev.ino == ino
+            ]
+            assert [size > 0 for _, size in truncs] == [False, True], truncs
+            assert truncs[-1][0] < first_write, (ino, truncs, first_write)
 
     def test_ec_encode_pre_fix_ordering_detected(self):
         """Regression proof the durable flag is load-bearing: replaying
