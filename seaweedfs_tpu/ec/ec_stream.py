@@ -1,50 +1,40 @@
-"""Flush-free, pool-parallel host↔HBM streaming drivers for EC
-encode/rebuild.
+"""Pipelined host<->HBM streaming drivers for EC encode and rebuild.
 
-The classic drivers in ec_files.py are synchronous: read a batch,
-round-trip it through the codec, write, repeat — every stage waits for
-every other. These drivers pipeline the stages the TPU-first way
-(SURVEY §7 step 2 "streaming driver double-buffers tiles host↔HBM"),
-matching the *output bytes* of ec_files.py exactly while overlapping:
+Encode, rebuild and their batch forms are all `out = M * in`, streamed
+through ONE pipeline shell (_Op): a reader pool fills slots of a
+preallocated staging ring, ONE dispatcher (the caller's thread) hands
+each slot to the codec stage, a writer pool fetches the result and
+lands it with positioned writes at precomputed offsets. Every output
+byte is written exactly once wherever its tile finishes, so completion
+order never shows in the bytes: they match the synchronous drivers of
+ec_files.py exactly.
 
-  disk reads (tiles t+1..)  ‖  H2D + SWAR kernel (tile t)  ‖  parity
-  D2H + shard writes (tiles t-1..)
+The shell owns, once: the pools, their two bounded queues and the ring;
+the stop flag's reach into every wait; the output files (opened on the
+caller's thread, reserved by the writer pool behind a latch:
+_Reservation); the five serial phases that partition wall_s
+(_OP_PHASES) and the pool stages' thread-seconds; the abort and
+durability contract (_settle_outputs); the report line and the span.
+A driver is a plan and its stage bodies, handed to _Op.run:
 
-Round 5 measured the previous single-reader/single-writer version
-losing 47% of encode wall to a SERIAL buffered-file flush at close and
-the rebuild reader serializing ten preadv calls on one thread. This
-version removes both bottlenecks:
+  plan      items (claimed in order by the readers), slot_bytes,
+            outputs [(path, final size)], the span's name and bytes
+  opened    () -> context manager: what ONE reader thread opens
+  fill      (src, item, slot) -> staged       reader pool      read_s
+  dispatch  (item, staged) -> handle          the dispatcher; books its
+            own stage_s / device_s (h2d_s / launch_s) through op.book
+  fetch     (item, staged, handle) -> result  writer pool, blocking
+  checksum  (item, staged, result)            host CRC where the stage
+            declined the fused one                             compute_s
+  write     (fds, item, staged, result)       behind the latch write_s
+  report    (stats, span, whole)              CRC fold, its own fields
 
-  * shard files are opened as RAW fds, preallocated to their exact
-    final size (posix_fallocate, ftruncate fallback), and written with
-    positioned os.pwritev at each tile's precomputed output offset —
-    no userspace buffering accumulates, so close() is free and
-    `flush_s` measures only the os.close loop. In the encode drivers
-    the WRITER POOL does the preallocating, beside the first reads and
-    dispatches, and a latch keeps every shard write behind the last
-    reservation (_Reservation); the rebuild drivers, with one to four
-    files to reserve, still do it on the caller's thread;
-  * a READER POOL claims tiles from a shared index and fills a bounded
-    queue (each thread owns its fds: positioned preadv, no seek
-    state), so the ten survivor reads of a rebuild tile — or tiles of
-    the encode .dat — land in parallel instead of one serial loop;
-  * a WRITER POOL drains dispatched tiles: each worker blocks on its
-    tile's parity fetch and lands all rows with pwritev. Positioned
-    writes make tile COMPLETION ORDER irrelevant to the bytes — every
-    byte offset is written exactly once — so the pool needs no
-    re-sequencing to stay byte-identical to the synchronous drivers;
-  * the in-flight window is 3 dispatched-but-unfetched tiles deep, so
-    H2D, kernel, and D2H genuinely triple-overlap.
-
-Only the [4, N] parity ever crosses device→host on encode — the ten
-data-shard files are byte copies of the blocks read from the .dat,
-written straight from the host tile.
-
-The rebuild driver additionally accepts REMOTE survivor readers
-(`remote_readers`: shard id → fetch(offset, size) callables), which is
-how the volume server's VolumeEcShardsRebuild verb overlaps rack-wide
-shard gathering with reconstruction instead of copying every survivor
-to the rebuilder before decoding byte one.
+Only the [4, N] parity crosses device->host on encode: the ten data
+shard files are byte copies of the blocks read from the .dat, written
+straight from the ring slot. The rebuild driver also takes REMOTE
+survivor readers (`remote_readers`: shard id -> fetch(offset, size)),
+which is how VolumeEcShardsRebuild overlaps a rack-wide gather with
+reconstruction.
 
 Role match: the 256 KB-batch loops at reference
 weed/storage/erasure_coding/ec_encoder.go:188-225 (encodeDatFile) and
@@ -53,6 +43,7 @@ weed/storage/erasure_coding/ec_encoder.go:188-225 (encodeDatFile) and
 
 from __future__ import annotations
 
+import contextlib
 import errno
 import functools
 import os
@@ -88,10 +79,10 @@ SMALL_BLOCK_SIZE = locate.SMALL_BLOCK_SIZE
 # 8 MiB lost everywhere). TPU dispatch amortization keeps the floor at
 # 1 MiB — a [10, 1 MiB] tile is still 16x the SWAR minimum stream.
 DEFAULT_TILE_BYTES = 1024 * 1024
-# Dispatched-but-unfetched tiles queued toward the writer pool. Live
-# host-tile bound: _INFLIGHT queued + one per writer thread (being
-# fetched/written) + reader_threads + 2 (read queue + the
-# dispatcher's hands) — 10 tiles at the defaults.
+# Dispatched-but-unfetched tiles queued toward the writer pool (the
+# report line's pipeline_depth). Live host-tile bound: _INFLIGHT queued
+# + one per writer thread (being fetched/written) + reader_threads + 2
+# (read queue + the dispatcher's hands) — 10 tiles at the defaults.
 _INFLIGHT = 3
 
 
@@ -102,17 +93,6 @@ def pipeline_enabled() -> bool:
     regression-tested) — the operator lever when a pipeline bug is
     suspected in production."""
     return os.environ.get("WEED_EC_PIPELINE", "1") != "0"
-
-
-def pipeline_depth() -> int:
-    """Dispatched-but-unfetched window (staging-ring dispatch depth):
-    WEED_EC_PIPELINE_DEPTH, minimum 2 (double buffering — one tile on
-    the device while the next stages), default 3."""
-    try:
-        d = int(os.environ.get("WEED_EC_PIPELINE_DEPTH", "0"))
-    except ValueError:
-        d = 0
-    return max(2, d) if d > 0 else _INFLIGHT
 
 
 def pipeline_batch_limit() -> int:
@@ -203,7 +183,7 @@ class _Pipeline:
         self.errors: list[BaseException] = []
         self._threads: list[threading.Thread] = []
 
-    def spawn(self, fn) -> None:
+    def spawn(self, fn, role: str) -> None:
         def run():
             try:
                 fn()
@@ -211,7 +191,11 @@ class _Pipeline:
                 self.errors.append(e)
                 self.stop.set()
 
-        t = threading.Thread(target=run, daemon=True)
+        t = threading.Thread(
+            target=run,
+            daemon=True,
+            name=f"ec-stream-{role}-{len(self._threads)}",
+        )
         t.start()
         self._threads.append(t)
 
@@ -226,8 +210,8 @@ class _Pipeline:
 
 
 class _Reservation:
-    """One encode operation's shard files, reserved by its writer pool
-    behind a count-down latch. Every writer thread starts by claiming
+    """One operation's output files, reserved by its writer pool behind
+    a count-down latch. Every writer thread starts by claiming
     (fd, size) pairs from ONE shared iterator and preallocating each
     (`reserve`), then enters its ordinary loop; `wait` stands directly
     before a writer's pwritevs, so no shard byte of the operation is
@@ -335,6 +319,111 @@ def _pread_into(fd: int, view, offset: int) -> int:
     return got
 
 
+@contextlib.contextmanager
+def _opened(paths: list[str]):
+    """Read-only fds of `paths` for ONE reader thread (each thread owns
+    its fds: positioned reads, no seek state shared across the pool)."""
+    fds: list[int] = []
+    try:
+        for path in paths:
+            fds.append(os.open(path, os.O_RDONLY))
+        yield fds
+    finally:
+        for fd in fds:
+            os.close(fd)
+
+
+class _Survivors:
+    """One thread's read-only fds of each volume's ten survivor shard
+    files, opened when the thread first meets the volume and closed
+    together: the batch rebuild arms' only way to survivor bytes, so the
+    truncation check and the repair accounting live here."""
+
+    def __init__(self, bases: list[str], survivors: tuple[int, ...]):
+        from seaweedfs_tpu.ec.ec_files import to_ext
+
+        # [volume][j]: the file of the volume's j-th survivor
+        self._paths = [[base + to_ext(s) for s in survivors] for base in bases]
+        self._survivors = survivors
+        self._fds: dict[int, list[int]] = {}
+        self._read = EC_REPAIR_BYTES_READ.labels("local")
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        for fds in self._fds.values():
+            for fd in fds:
+                try:
+                    os.close(fd)
+                except OSError:
+                    pass
+
+    def read(self, v: int, off: int, tile: np.ndarray) -> None:
+        """Fill tile [10, step] with volume v's survivor bytes at off."""
+        fds = self._fds.get(v)
+        if fds is None:
+            fds = self._fds[v] = []
+            for path in self._paths[v]:
+                fds.append(os.open(path, os.O_RDONLY))
+        step = tile.shape[1]
+        for j in range(DATA_SHARDS):
+            got = _pread_into(fds[j], tile[j], off)
+            self._read.inc(got)
+            if got != step:
+                raise ValueError(
+                    f"ec shard {self._survivors[j]} truncated: expected "
+                    f"{step} at {off} ({self._paths[v][j]})"
+                )
+
+
+def _create_empty(paths: list[str], durable: bool) -> None:
+    """The outputs of an operation with nothing to stream: empty files,
+    fsynced when durable, so the caller's publish can never outlive
+    files a crash could drop."""
+    from seaweedfs_tpu.util import durable as _durable
+
+    for path in paths:
+        open(path, "wb").close()
+        if durable:
+            _durable.fsync_path(path)
+
+
+def _settle_outputs(
+    fds: list[int], paths: list[str], durable: bool, whole: bool
+) -> OSError | None:
+    """The abort and durability contract of every operation's output
+    files, for the shell and for the inline host arm. fsync each fd only
+    if the operation is whole (crash contract, weedcrash, docs/ANALYSIS.md
+    v3: an operation acked to its caller must survive power loss, so the
+    bytes are pinned before the fds close and the ack leaves). A FAILED
+    fsync must fail the operation (swallowing it would ack bytes that
+    never reached disk), but only after every fd is closed: the first
+    one is kept and RETURNED for the caller to raise. On any failure
+    EVERY output file is removed: shard_presence treats any existing
+    .ecNN as a valid shard, so full-size garbage would read as a
+    complete volume to a later rebuild, or be skipped by a retry."""
+    fsync_err: OSError | None = None
+    for fd in fds:
+        try:
+            if durable and whole:
+                try:
+                    os.fsync(fd)
+                except OSError as e:
+                    if fsync_err is None:
+                        fsync_err = e
+            os.close(fd)
+        except OSError:
+            pass
+    if not whole or fsync_err is not None:
+        for path in paths:
+            try:
+                os.remove(path)
+            except OSError:
+                pass
+    return fsync_err
+
+
 def _charge(busy: dict, lock: threading.Lock, key: str, dt: float) -> None:
     """Accumulate per-stage busy seconds across pool threads (a stage
     total can legitimately exceed wall — it is thread-seconds)."""
@@ -342,9 +431,9 @@ def _charge(busy: dict, lock: threading.Lock, key: str, dt: float) -> None:
         busy[key] += dt
 
 
-# The serial phases of one encode operation, all on the handler's thread
-# (it is the dispatcher): span and annotation name -> report field. They
-# partition wall_s (trace.Phases): head (open the shard files, spawn
+# The serial phases of one operation, all on the handler's thread (it is
+# the dispatcher): span and annotation name -> report field. They
+# partition wall_s (trace.Phases): head (open the output files, spawn
 # the pools, first read; the writers reserve the files meanwhile, see
 # _Reservation) | dispatch (first dispatch -> last
 # dispatch returned) | drain (-> the last writer's fetch returned:
@@ -358,17 +447,17 @@ _OP_PHASES = {
     "ec.op.flush": "flush_s",
 }
 
-# What only a device stage books, beside the pool stages and in
-# thread-seconds like them: the dispatcher's time in the transfer call
-# and in the jitted call. In the single-volume driver h2d_s + launch_s
-# is device_s; the batch driver's transfer is part of its stage_s and
-# launch_s == device_s. Host stage pairs book neither.
+# What only the encode drivers' device stages book, beside the pool
+# stages and in thread-seconds like them: the dispatcher's time in the
+# transfer call and in the jitted call. In the single-volume driver
+# h2d_s + launch_s is device_s; the batch driver's transfer is part of
+# its stage_s and launch_s == device_s. Host stage pairs book neither.
 _DEVICE_BUSY = {"h2d_s": 0.0, "launch_s": 0.0}
 
-# What the encode drivers book for the shard files' reservation
+# What every operation books for its output files' reservation
 # (_Reservation): reserve_s is a pool stage like read_s, the writer
 # pool's thread-seconds inside _preallocate; reserve_done_s is one
-# clock sample, wall seconds from the driver's clock start to the
+# clock sample, wall seconds from the operation's clock start to the
 # moment the last file was reserved and the first shard write could go
 # (0.0 on an operation that aborted before that).
 _RESERVE_BUSY = {"reserve_s": 0.0, "reserve_done_s": 0.0}
@@ -387,6 +476,240 @@ def _close_phases(phases, busy: dict) -> float:
     for name, field in _OP_PHASES.items():
         busy[field] = phases.seconds.get(name, 0.0)
     return end
+
+
+class _Op:
+    """The one pipeline shell (module docstring): one operation's pools,
+    queues, ring, output files, phases, books and close-out. Made
+    before the stage bodies, which book through `book`; `run` then
+    drives them and returns when the operation is settled."""
+
+    def __init__(
+        self, span: str, device_stage: bool = False,
+        extra_busy: dict | None = None,
+    ):
+        self.span = span
+        # the stage traces and launches this process's device programs
+        # (program_traces is reported, 0 in steady state)
+        self.device_stage = device_stage
+        # per-stage busy thread-seconds (queue waits excluded): read |
+        # stage (host staging prep) | device (async dispatch) | writeback
+        # (device drain / D2H) or compute (host codec, host CRC) | write
+        # — how e2e numbers stay attributable and reader/device/writer
+        # overlap is provable per run
+        self.busy = {
+            "read_s": 0.0,
+            "stage_s": 0.0,
+            "device_s": 0.0,
+            "writeback_s": 0.0,
+            "compute_s": 0.0,
+            "write_s": 0.0,
+            **(extra_busy or {}),
+            **_RESERVE_BUSY,
+        }
+        self._lock = threading.Lock()
+        self.book = functools.partial(_charge, self.busy, self._lock)
+        self._traces0 = _program_traces() if device_stage else 0
+
+    def run(
+        self,
+        *,
+        nbytes: int,
+        items: list,
+        slot_bytes: int,
+        outputs: list[tuple[str, int]],
+        opened: Callable[[], "contextlib.AbstractContextManager"],
+        fill: Callable,
+        dispatch: Callable,
+        fetch: Callable,
+        write: Callable,
+        report: Callable[[dict, "object", bool], None],
+        checksum: Callable | None = None,
+        prepare: Callable | None = None,
+        fetch_charges: str = "writeback_s",
+        stats: dict | None = None,
+        durable: bool = False,
+        reader_threads: int | None = None,
+        writer_threads: int | None = None,
+    ) -> None:
+        """`prepare(item) -> item` runs on the reader's thread before it
+        takes a slot, outside every stage's clock. `checksum` is skipped
+        when None. `report(stats, span, whole)` adds the driver's own
+        fields; `whole` says every byte was written and, if asked,
+        fsynced."""
+        busy, book = self.busy, self.book
+        writer_threads = writer_threads or DEFAULT_WRITER_THREADS
+        reader_threads = reader_threads or DEFAULT_READER_THREADS
+        pipe = _Pipeline()
+        read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
+        write_q: queue.Queue = queue.Queue(maxsize=_INFLIGHT)
+        # every in-flight tile lives in one of these preallocated slots:
+        # the window plus the buffers pool threads legitimately hold
+        ring = _StagingRing(_INFLIGHT + writer_threads + 1, slot_bytes)
+        claims, claim_lock = iter(items), threading.Lock()
+        fds: list[int] = []  # opened inside the try: no leak on ENOSPC
+        reservation: _Reservation | None = None
+        # the latest clock sample at which a writer's fetch returned
+        # (under the books' lock): where ec.op.drain ends, read once the
+        # pools are joined
+        last_fetch = [0.0]
+        wall0 = time.perf_counter()
+        # tracing plane: the operation is one span whose stages are the
+        # pool busy totals and whose children are the serial phases
+        # (inherits the scrub/repair plane tag when the caller's context
+        # carries one); entered manually because the body below already
+        # owns the try/finally structure
+        sp = trace.span(self.span, nbytes=nbytes)
+        sp.__enter__()
+        phases = trace.Phases("ec.op.head", wall0)
+
+        def reader():
+            with opened() as src:
+                while True:
+                    with claim_lock:
+                        item = next(claims, None)
+                    if item is None:
+                        return
+                    if prepare is not None:
+                        item = prepare(item)
+                    got = ring.acquire(pipe.stop)
+                    if got is None:
+                        return
+                    slot_id, buf = got
+                    t0 = time.perf_counter()
+                    with trace.annotation("ec.read"):
+                        staged = fill(src, item, buf)
+                    book("read_s", time.perf_counter() - t0)
+                    if not _q_put(read_q, (item, slot_id, staged), pipe.stop):
+                        ring.release(slot_id)
+                        return
+
+        def writer():
+            reservation.reserve(book)
+            while True:
+                got = _q_get(write_q, pipe.stop)
+                if got is _EOF or got is _STOPPED:
+                    return
+                item, slot_id, staged, handle = got
+                t0 = time.perf_counter()
+                result = fetch(item, staged, handle)
+                t1 = time.perf_counter()
+                with self._lock:
+                    last_fetch[0] = max(last_fetch[0], t1)
+                if checksum is not None:
+                    checksum(item, staged, result)
+                t2 = time.perf_counter()
+                if not reservation.wait():
+                    return
+                tw = time.perf_counter()
+                with trace.annotation("ec.write"):
+                    write(fds, item, staged, result)
+                t3 = time.perf_counter()
+                ring.release(slot_id)
+                book(fetch_charges, t1 - t0)
+                book("compute_s", t2 - t1)
+                book("write_s", t3 - tw)
+
+        ok = False
+        try:
+            for path, _ in outputs:
+                fds.append(
+                    os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
+                )
+            reservation = _Reservation(
+                [(fd, size) for fd, (_, size) in zip(fds, outputs)], pipe.stop
+            )
+            # writers first: they reserve the files beside the first reads
+            for _ in range(writer_threads):
+                pipe.spawn(writer, "writer")
+            for _ in range(min(reader_threads, len(items))):
+                pipe.spawn(reader, "reader")
+            for n in range(len(items)):
+                got = _q_get(read_q, pipe.stop)
+                if got is _STOPPED:
+                    break
+                item, slot_id, staged = got
+                t0 = time.perf_counter()
+                if n == 0:
+                    phases.to("ec.op.dispatch", t0)
+                handle = dispatch(item, staged)
+                if n == len(items) - 1:
+                    phases.to("ec.op.drain")
+                if not _q_put(
+                    write_q, (item, slot_id, staged, handle), pipe.stop
+                ):
+                    break
+            for _ in range(writer_threads):
+                if not _q_put(write_q, _EOF, pipe.stop):
+                    break
+            ok = True
+        finally:
+            try:
+                pipe.finish(caller_error=not ok)  # may re-raise a stage error
+            finally:
+                phases.to("ec.op.write_tail", (ok and last_fetch[0]) or None)
+                phases.to("ec.op.flush")
+                whole = ok and not pipe.errors
+                fsync_err = _settle_outputs(
+                    fds, [path for path, _ in outputs], durable, whole
+                )
+                try:
+                    if fsync_err is not None:
+                        raise fsync_err
+                finally:
+                    # an error surfacing mid-stream must not skip the
+                    # stats nor leak any fd (each reader closes its own
+                    # in its thread). Raw preallocated fds: nothing
+                    # buffered remains, so flush_s measures only the
+                    # fsync + close syscalls
+                    end = _close_phases(phases, busy)
+                    _book_reserve_done(busy, reservation, wall0)
+                    out: dict = {}
+                    _finish_stats(
+                        out, busy, wall0, reader_threads, writer_threads, end
+                    )
+                    out["pipeline_depth"] = _INFLIGHT
+                    out["ring_slots"] = ring.slots
+                    report(out, sp, whole and fsync_err is None)
+                    _trace_stages(sp, busy)
+                    if self.device_stage:
+                        _report_traces(out, sp, self._traces0)
+                    if stats is not None:
+                        stats.update(out)
+                    # a stage error re-raised by pipe.finish() is live
+                    # in this finally; hand it to the span so a failed
+                    # drive is distinguishable from a clean one in
+                    # /debug/traces
+                    sp.__exit__(*sys.exc_info())
+
+
+def _trace_stages(sp, busy: dict) -> None:
+    """The operation's booked seconds on its span, under the report
+    line's own field names (one vocabulary: what an operator reads at
+    /debug/traces is what the node's `ec.<verb> report=` line says).
+    Pool stages are thread-seconds; the phases are the span's children."""
+    sp.add_stages({k: v for k, v in busy.items() if k.endswith("_s")})
+
+
+def _finish_stats(
+    stats: dict,
+    busy: dict,
+    wall0: float,
+    reader_threads: int = 1,
+    writer_threads: int = 1,
+    end: float | None = None,
+) -> None:
+    """Per-stage busy thread-seconds + wall. The PIPELINE stages
+    (read/dispatch/fetch/write) run in thread POOLS, so a stage's Σ can
+    exceed wall (overlap across threads) — the wall a stage explains is
+    its total divided by its pool width. The serial phases of the shell
+    (_OP_PHASES, flush_s among them) are different: they partition the
+    wall, given the clock sample `end` that closed the last of them."""
+    wall = (time.perf_counter() if end is None else end) - wall0
+    stats.update({k: round(v, 4) for k, v in busy.items()})
+    stats["wall_s"] = round(wall, 4)
+    stats["reader_threads"] = reader_threads
+    stats["writer_threads"] = writer_threads
 
 
 # --- codec stage factories --------------------------------------------------
@@ -497,37 +820,19 @@ def stream_write_ec_files(
     pool and charged to compute_s — the contract holds either way.
 
     Host staging buffers live in a _StagingRing of
-    pipeline_depth() + writer_threads + 1 slots (WEED_EC_PIPELINE_DEPTH
-    bounds the dispatched-but-unfetched window; the extras are the
-    buffers pool threads legitimately hold while working), so pipeline
-    memory is bounded and allocator churn stays out of the hot loop."""
+    _INFLIGHT + writer_threads + 1 slots (the dispatched-but-unfetched
+    window plus the buffers pool threads legitimately hold while
+    working), so pipeline memory is bounded and allocator churn stays
+    out of the hot loop."""
     if (parity_fn is None) != (fetch_fn is None):
         raise ValueError("parity_fn and fetch_fn must be injected together")
     device_stage = parity_fn is None
-    # per-stage busy thread-seconds (queue waits excluded): read |
-    # stage (host staging prep) | device (async dispatch) | writeback
-    # (device drain / D2H) or compute (host codec) | write — how e2e
-    # numbers stay attributable and reader/device/writer overlap is
-    # provable per run
-    busy = {
-        "read_s": 0.0,
-        "stage_s": 0.0,
-        "device_s": 0.0,
-        "writeback_s": 0.0,
-        "compute_s": 0.0,
-        "write_s": 0.0,
-        **_RESERVE_BUSY,
-    }
-    busy_lock = threading.Lock()
-    book = functools.partial(_charge, busy, busy_lock)
+    op = _Op(
+        "ec_stream.encode", device_stage, _DEVICE_BUSY if device_stage else None
+    )
     if device_stage:
-        traces0 = _program_traces()
-        busy.update(_DEVICE_BUSY)
-        parity_fn, fetch_fn = _tpu_encode_fns(want_crcs=want_crcs, book=book)
+        parity_fn, fetch_fn = _tpu_encode_fns(want_crcs=want_crcs, book=op.book)
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
-    writer_threads = writer_threads or DEFAULT_WRITER_THREADS
-    reader_threads = reader_threads or DEFAULT_READER_THREADS
-    depth = pipeline_depth()
 
     dat_path = base_file_name + ".dat"
     dat_size = os.path.getsize(dat_path)
@@ -560,287 +865,144 @@ def stream_write_ec_files(
     for _, _, _, step, rows in tiles:
         out_offs.append(shard_bytes)
         shard_bytes += step * rows
-
-    out_fds: list[int] = []  # opened inside the try: no leak on ENOSPC
-    reservation: _Reservation | None = None
-    pipe = _Pipeline()
-    read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
-    write_q: queue.Queue = queue.Queue(maxsize=depth)
-    # the staging ring: every in-flight tile lives in one of these
-    # preallocated slots (flat [rows*10*step] prefixes of slot buffers)
-    ring = _StagingRing(
-        depth + writer_threads + 1, DATA_SHARDS * tile_bytes
-    )
-    fetch_bucket = getattr(fetch_fn, "charges", "writeback_s")
     # per-tile shard CRCs, filled by the writer pool (index writes are
     # GIL-atomic), folded into whole-file CRCs after the join
     tile_crcs: list = [None] * len(tiles)
-    # the latest clock sample at which a writer's fetch returned (under
-    # busy_lock): where ec.op.drain ends, read once the pools are joined
-    last_fetch = [0.0]
-    wall0 = time.perf_counter()
-    # tracing plane: the encode is one span whose stages are the pool
-    # busy totals and whose children are the serial phases; entered
-    # manually because the body below already owns the try/finally
-    # structure
-    _sp = trace.span("ec_stream.encode", nbytes=dat_size)
-    _sp.__enter__()
-    phases = trace.Phases("ec.op.head", wall0)
 
-    idx_lock = threading.Lock()
-    idx_iter = iter(range(len(tiles)))
-
-    def reader():
-        fd = os.open(dat_path, os.O_RDONLY)
-        try:
-            while True:
-                with idx_lock:
-                    k = next(idx_iter, None)
-                if k is None:
-                    return
-                row_off, block, batch_off, step, rows = tiles[k]
-                got_slot = ring.acquire(pipe.stop)
-                if got_slot is None:
-                    return
-                slot_id, buf = got_slot
-                t0 = time.perf_counter()
-                # one flat [rows, 10, step] ring-slot prefix per tile,
-                # preadv straight into it (no bytes objects, no shared
-                # seek position across the pool), zero-padded past EOF
-                # like read_dat_tile — and only spans the .dat does not
-                # cover pay the memset. NO reshuffling into shard
-                # order: the codec consumes contiguous per-row [10,
-                # step] views and the writer gather-writes each shard's
-                # run of blocks with one iovec pwritev, so the bytes
-                # are copied exactly once between disk reads and
-                # writes.
-                flat = buf[: rows * DATA_SHARDS * step]
-                with trace.annotation("ec.read"):
-                    if batch_off == 0 and step == block:
-                        # full rows are CONTIGUOUS in the .dat: one read
-                        # covers the whole super-tile
-                        n = max(0, min(len(flat), dat_size - row_off))
-                        if n < len(flat):
-                            flat[n:] = 0
-                        if n:
-                            got = _pread_into(fd, flat[:n], row_off)
-                            if got < n:  # truncated .dat: pad like classic
-                                flat[got:n] = 0
-                    else:
-                        # sub-block tile of the large tier: rows == 1,
-                        # shard blocks are strided through the .dat
-                        for i in range(DATA_SHARDS):
-                            row = flat[i * step : (i + 1) * step]
-                            off = row_off + i * block + batch_off
-                            n = max(0, min(step, dat_size - off))
-                            if n < step:
-                                row[n:] = 0
-                            if n:
-                                got = _pread_into(fd, row[:n], off)
-                                if got < n:
-                                    row[got:n] = 0
-                _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
-                if not _q_put(read_q, (k, slot_id, flat), pipe.stop):
-                    ring.release(slot_id)
-                    return
-        finally:
-            os.close(fd)
-
-    def writer():
-        reservation.reserve(book)
-        while True:
-            item = _q_get(write_q, pipe.stop)
-            if item is _EOF or item is _STOPPED:
-                return
-            k, slot_id, flat, handles = item
-            _, _, _, step, rows = tiles[k]
-            off = out_offs[k]
-            t0 = time.perf_counter()
-            parities, crc_rows = [], []
-            for h in handles:
-                got = fetch_fn(h)
-                if isinstance(got, tuple):
-                    parities.append(got[0])
-                    crc_rows.append(got[1])
-                else:
-                    parities.append(got)
-                    crc_rows.append(None)
-            t1 = time.perf_counter()
-            with busy_lock:
-                last_fetch[0] = max(last_fetch[0], t1)
-            if want_crcs and any(c is None for c in crc_rows):
-                # the stage declined the fused CRC for this tile
-                # (injected pair / unsupported shape): table-CRC the
-                # written bytes here, charged as host compute
-                from seaweedfs_tpu.util.crc import crc32c
-
-                for r, c in enumerate(crc_rows):
-                    if c is not None:
-                        continue
-                    row0 = r * DATA_SHARDS * step
-                    crc_rows[r] = [
-                        crc32c(
-                            flat[row0 + i * step : row0 + (i + 1) * step]
-                            .tobytes()
-                        )
-                        for i in range(DATA_SHARDS)
-                    ] + [
-                        crc32c(np.ascontiguousarray(parities[r][p]).tobytes())
-                        for p in range(PARITY_SHARDS)
-                    ]
-            t2 = time.perf_counter()
-            if not reservation.wait():
-                return
-            tw = time.perf_counter()
-            with trace.annotation("ec.write"):
-                for i in range(DATA_SHARDS):
-                    _pwritev_full(
-                        out_fds[i],
-                        [
-                            flat[
-                                (r * DATA_SHARDS + i)
-                                * step : (r * DATA_SHARDS + i + 1)
-                                * step
-                            ]
-                            for r in range(rows)
-                        ],
-                        off,
-                    )
-                for p in range(PARITY_SHARDS):
-                    _pwritev_full(
-                        out_fds[DATA_SHARDS + p],
-                        [
-                            np.ascontiguousarray(parities[r][p])
-                            for r in range(rows)
-                        ],
-                        off,
-                    )
-            t3 = time.perf_counter()
-            if want_crcs:
-                tile_crcs[k] = crc_rows
-            ring.release(slot_id)
-            _charge(busy, busy_lock, fetch_bucket, t1 - t0)
-            _charge(busy, busy_lock, "compute_s", t2 - t1)
-            _charge(busy, busy_lock, "write_s", t3 - tw)
-
-    ok = False
-    try:
-        for i in range(TOTAL_SHARDS):
-            out_fds.append(
-                os.open(
-                    base_file_name + to_ext(i),
-                    os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                    0o644,
-                )
+    def fill(src, k, buf):
+        # one flat [rows, 10, step] ring-slot prefix per tile, preadv
+        # straight into it (no bytes objects, no shared seek position
+        # across the pool), zero-padded past EOF like read_dat_tile —
+        # and only spans the .dat does not cover pay the memset. NO
+        # reshuffling into shard order: the codec consumes contiguous
+        # per-row [10, step] views and the writer gather-writes each
+        # shard's run of blocks with one iovec pwritev, so the bytes are
+        # copied exactly once between disk reads and writes.
+        (fd,) = src
+        row_off, block, batch_off, step, rows = tiles[k]
+        flat = buf[: rows * DATA_SHARDS * step]
+        if batch_off == 0 and step == block:
+            # full rows are CONTIGUOUS in the .dat: one read covers the
+            # whole super-tile
+            n = max(0, min(len(flat), dat_size - row_off))
+            if n < len(flat):
+                flat[n:] = 0
+            if n:
+                got = _pread_into(fd, flat[:n], row_off)
+                if got < n:  # truncated .dat: pad like classic
+                    flat[got:n] = 0
+        else:
+            # sub-block tile of the large tier: rows == 1, shard blocks
+            # are strided through the .dat
+            _read_tile_into(
+                fd, dat_size, row_off, block, batch_off, step,
+                flat.reshape(DATA_SHARDS, step),
             )
-        reservation = _Reservation(
-            [(fd, shard_bytes) for fd in out_fds], pipe.stop
-        )
-        # writers first: they reserve the shard files beside the first reads
-        for _ in range(writer_threads):
-            pipe.spawn(writer)
-        for _ in range(reader_threads):
-            pipe.spawn(reader)
-        for n in range(len(tiles)):
-            item = _q_get(read_q, pipe.stop)
-            if item is _STOPPED:
-                break
-            k, slot_id, flat = item
-            _, _, _, step, rows = tiles[k]
-            t0 = time.perf_counter()
-            if n == 0:
-                phases.to("ec.op.dispatch", t0)
-            # staging: each [10, step] view is contiguous in the ring
-            # slot, so the injected stage contract (and the TPU H2D)
-            # sees an ordinary tile
-            views = [
-                flat[
-                    r * DATA_SHARDS * step : (r + 1) * DATA_SHARDS * step
-                ].reshape(DATA_SHARDS, step)
-                for r in range(rows)
+        return flat
+
+    def dispatch(k, flat):
+        _, _, _, step, rows = tiles[k]
+        t0 = time.perf_counter()
+        # staging: each [10, step] view is contiguous in the ring slot,
+        # so the injected stage contract (and the TPU H2D) sees an
+        # ordinary tile
+        views = [
+            flat[
+                r * DATA_SHARDS * step : (r + 1) * DATA_SHARDS * step
+            ].reshape(DATA_SHARDS, step)
+            for r in range(rows)
+        ]
+        t1 = time.perf_counter()
+        # one async parity dispatch per row
+        handles = [parity_fn(v) for v in views]
+        t2 = time.perf_counter()
+        op.book("stage_s", t1 - t0)
+        op.book("device_s", t2 - t1)
+        return handles
+
+    def fetch(k, flat, handles):
+        """([rows] parity [4, step], [rows] 14 CRCs or None)."""
+        parities, crc_rows = [], []
+        for h in handles:
+            got = fetch_fn(h)
+            if isinstance(got, tuple):
+                parities.append(got[0])
+                crc_rows.append(got[1])
+            else:
+                parities.append(got)
+                crc_rows.append(None)
+        return parities, crc_rows
+
+    def checksum(k, flat, result):
+        # rows for which the stage declined the fused CRC (injected
+        # pair / unsupported shape): table-CRC the written bytes here
+        from seaweedfs_tpu.util.crc import crc32c
+
+        step = tiles[k][3]
+        parities, crc_rows = result
+        for r, c in enumerate(crc_rows):
+            if c is not None:
+                continue
+            row0 = r * DATA_SHARDS * step
+            crc_rows[r] = [
+                crc32c(flat[row0 + i * step : row0 + (i + 1) * step].tobytes())
+                for i in range(DATA_SHARDS)
+            ] + [
+                crc32c(np.ascontiguousarray(parities[r][p]).tobytes())
+                for p in range(PARITY_SHARDS)
             ]
-            t1 = time.perf_counter()
-            # one async parity dispatch per row
-            handles = [parity_fn(v) for v in views]
-            t2 = time.perf_counter()
-            if n == len(tiles) - 1:
-                phases.to("ec.op.drain", t2)
-            _charge(busy, busy_lock, "stage_s", t1 - t0)
-            _charge(busy, busy_lock, "device_s", t2 - t1)
-            if not _q_put(write_q, (k, slot_id, flat, handles), pipe.stop):
-                break
-        for _ in range(writer_threads):
-            if not _q_put(write_q, _EOF, pipe.stop):
-                break
-        ok = True
-    finally:
-        try:
-            pipe.finish(caller_error=not ok)  # may re-raise a stage error
-        finally:
-            phases.to("ec.op.write_tail", (ok and last_fetch[0]) or None)
-            phases.to("ec.op.flush")
-            fsync_err: OSError | None = None
-            try:
-                for fd in out_fds:
-                    try:
-                        if durable and ok and not pipe.errors:
-                            # a failed durability fsync must FAIL the
-                            # encode (swallowing it would ack bytes that
-                            # never reached disk — the exact state the
-                            # weedcrash ec-encode workload forbids), but
-                            # only after every fd is closed
-                            try:
-                                os.fsync(fd)  # see the docstring contract
-                            except OSError as e:
-                                if fsync_err is None:
-                                    fsync_err = e
-                        os.close(fd)
-                    except OSError:
-                        pass
-                if not ok or pipe.errors or fsync_err is not None:
-                    # a partial shard set must not survive the abort:
-                    # shard_presence treats ANY existing .ecNN as a
-                    # valid shard, so full-size garbage files would
-                    # read as a complete volume to a later rebuild
-                    for i in range(TOTAL_SHARDS):
-                        try:
-                            os.remove(base_file_name + to_ext(i))
-                        except OSError:
-                            pass
-                if fsync_err is not None:
-                    raise fsync_err
-            finally:
-                # raw preallocated fds: nothing buffered remains, so
-                # flush_s measures only the fsync + close syscalls (the
-                # previous driver lost 47% of wall right here)
-                end = _close_phases(phases, busy)
-                _book_reserve_done(busy, reservation, wall0)
-                if stats is not None:
-                    _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads,
-                        end,
-                    )
-                    stats["pipeline_depth"] = depth
-                    stats["ring_slots"] = ring.slots
-                    stats["driver"] = _driver_name(device_stage)
-                    if hasattr(parity_fn, "arms"):
-                        stats["arms"] = dict(parity_fn.arms)
-                    if (
-                        want_crcs
-                        and ok
-                        and not pipe.errors
-                        and fsync_err is None
-                    ):
-                        stats["shard_crcs"] = _fold_encode_crcs(
-                            tiles, tile_crcs
-                        )
-                _trace_stages(_sp, busy)
-                if device_stage:
-                    _report_traces(stats, _sp, traces0)
-                # a stage error re-raised by pipe.finish() is live in
-                # this finally; hand it to the span so a failed drive
-                # is distinguishable from a clean one in /debug/traces
-                _sp.__exit__(*sys.exc_info())
+        tile_crcs[k] = crc_rows
+
+    def write(fds, k, flat, result):
+        _, _, _, step, rows = tiles[k]
+        parities, off = result[0], out_offs[k]
+        for i in range(DATA_SHARDS):
+            _pwritev_full(
+                fds[i],
+                [
+                    flat[
+                        (r * DATA_SHARDS + i) * step : (r * DATA_SHARDS + i + 1)
+                        * step
+                    ]
+                    for r in range(rows)
+                ],
+                off,
+            )
+        for p in range(PARITY_SHARDS):
+            _pwritev_full(
+                fds[DATA_SHARDS + p],
+                [np.ascontiguousarray(parities[r][p]) for r in range(rows)],
+                off,
+            )
+
+    def report(out, sp, whole):
+        out["driver"] = _driver_name(device_stage)
+        if hasattr(parity_fn, "arms"):
+            out["arms"] = dict(parity_fn.arms)
+        if want_crcs and whole:
+            out["shard_crcs"] = _fold_encode_crcs(tiles, tile_crcs)
+
+    op.run(
+        nbytes=dat_size,
+        items=list(range(len(tiles))),
+        slot_bytes=DATA_SHARDS * tile_bytes,
+        outputs=[
+            (base_file_name + to_ext(i), shard_bytes)
+            for i in range(TOTAL_SHARDS)
+        ],
+        opened=lambda: _opened([dat_path]),
+        fill=fill,
+        dispatch=dispatch,
+        fetch=fetch,
+        checksum=checksum if want_crcs else None,
+        write=write,
+        report=report,
+        fetch_charges=getattr(fetch_fn, "charges", "writeback_s"),
+        stats=stats,
+        durable=durable,
+        reader_threads=reader_threads,
+        writer_threads=writer_threads,
+    )
 
 
 def _fold_encode_crcs(tiles: list, tile_crcs: list) -> list[int]:
@@ -914,8 +1076,8 @@ def stream_rebuild_ec_files(
     if (rebuild_fn is None) != (fetch_fn is None):
         raise ValueError("rebuild_fn and fetch_fn must be injected together")
     device_stage = rebuild_fn is None
+    op = _Op("ec_stream.rebuild", device_stage)
     if device_stage:
-        traces0 = _program_traces()
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs)
     # rebuild tiles read one span from each of 10 FILES. Re-swept with
     # the staging ring (BENCH_r12): LOCAL rebuilds want fine tiles —
@@ -927,9 +1089,6 @@ def stream_rebuild_ec_files(
     tile_bytes = tile_bytes or (
         4 * DEFAULT_TILE_BYTES if remote_readers else DEFAULT_TILE_BYTES // 2
     )
-    writer_threads = writer_threads or DEFAULT_WRITER_THREADS
-    reader_threads = reader_threads or DEFAULT_READER_THREADS
-    depth = pipeline_depth()
     remote_readers = dict(remote_readers or {})
 
     from seaweedfs_tpu.ec.ec_files import shard_presence, to_ext
@@ -959,53 +1118,17 @@ def stream_rebuild_ec_files(
         sorted((local_ids + sorted(remote_ids))[:DATA_SHARDS])
     )
     shard_size = os.path.getsize(base_file_name + to_ext(local_ids[0]))
-
-    out_fds: dict[int, int] = {}  # opened inside the try: no leak on ENOSPC
-    pipe = _Pipeline()
-    read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
-    write_q: queue.Queue = queue.Queue(maxsize=depth)
-    # staging ring for survivor-gather tiles: gap gathers sub-allocate
-    # contiguous [k, g_len] views out of one flat slot per tile
-    ring = _StagingRing(
-        depth + writer_threads + 1, DATA_SHARDS * tile_bytes
-    )
-    busy = {
-        "read_s": 0.0,
-        "stage_s": 0.0,
-        "device_s": 0.0,
-        "writeback_s": 0.0,
-        "compute_s": 0.0,
-        "write_s": 0.0,
-    }
-    busy_lock = threading.Lock()
-    fetch_bucket = getattr(fetch_fn, "charges", "writeback_s")
     # (range offset, range length, [crc per target]) from the writer
     # pool, folded into whole-file CRCs after the join (append is
     # GIL-atomic; order restored by sorting on offset)
     crc_ranges: list[tuple[int, int, list[int]]] = []
-    wall0 = time.perf_counter()
-    # tracing plane: rebuild span (inherits the scrub/repair plane tag
-    # when the caller's context carries one — cross-plane interference
-    # is then directly measurable on /debug/traces)
-    _sp = trace.span(
-        "ec_stream.rebuild", nbytes=shard_size * max(1, len(targets))
-    )
-    _sp.__enter__()
-
-    offsets = list(range(0, shard_size, tile_bytes))
-    idx_lock = threading.Lock()
-    idx_iter = iter(offsets)
-
     n_remote = sum(1 for i in survivors if not present[i])
     read_local = EC_REPAIR_BYTES_READ.labels("local")
     read_remote = EC_REPAIR_BYTES_READ.labels("remote")
 
-    def reader():
-        fds = {
-            i: os.open(base_file_name + to_ext(i), os.O_RDONLY)
-            for i in survivors
-            if present[i]
-        }
+    @contextlib.contextmanager
+    def opened():
+        fds: dict[int, int] = {}
         # remote survivor fetches fan out per tile: serialized, a
         # tile's latency would be n_remote × RTT and a single slow
         # holder would stall the whole tile walk
@@ -1014,82 +1137,11 @@ def stream_rebuild_ec_files(
             if n_remote > 1
             else None
         )
-
-        def gather(g_off: int, g_len: int, dest: np.ndarray) -> np.ndarray:
-            """One [k, g_len] survivor read at g_off into a staging-
-            ring view — the only place rebuild bytes cross a disk or
-            the network, so the repair accounting lives here."""
-            tile = dest.reshape(DATA_SHARDS, g_len)
-            futures = {}
-            if fetch_pool is not None:
-                futures = {
-                    j: fetch_pool.submit(remote_readers[i], g_off, g_len)
-                    for j, i in enumerate(survivors)
-                    if i not in fds
-                }
-            for j, i in enumerate(survivors):
-                if i in fds:
-                    got = _pread_into(fds[i], tile[j], g_off)
-                    read_local.inc(got)
-                else:
-                    fut = futures.get(j)
-                    raw = (
-                        fut.result()
-                        if fut is not None
-                        else remote_readers[i](g_off, g_len)
-                    )
-                    got = len(raw)
-                    read_remote.inc(got)
-                    if got == g_len:
-                        tile[j] = np.frombuffer(raw, dtype=np.uint8)
-                if got != g_len:
-                    raise ValueError(
-                        f"ec shard {i} truncated: expected {g_len} at "
-                        f"{g_off}"
-                    )
-            return tile
-
         try:
-            while True:
-                with idx_lock:
-                    offset = next(idx_iter, None)
-                if offset is None:
-                    return
-                if session is not None:
-                    # serve-first arbitration: degraded GET gathers in
-                    # flight own the disks/links; repair waits (bounded)
-                    session.yield_to_serving()
-                step = min(tile_bytes, shard_size - offset)
-                if session is not None:
-                    covered, gaps = session.consume(offset, step)
-                else:
-                    covered, gaps = [], [(offset, step)]
-                slot_id = -1
-                if gaps:
-                    got_slot = ring.acquire(pipe.stop)
-                    if got_slot is None:
-                        return
-                    slot_id, buf = got_slot
-                t0 = time.perf_counter()
-                # parts: ("don", off, {target: bytes}) ride through as
-                # bytes; ("raw", off, [k, n] tile) get decoded. Only the
-                # gaps pay survivor reads — donated ranges moved zero
-                # new bytes (arXiv:2205.11015's partial-repair shape).
-                # Gap tiles sub-allocate contiguous views out of the
-                # tile's ring slot (Σ gap bytes ≤ step, so they fit).
-                parts: list = [
-                    ("don", d_off, per_t) for d_off, per_t in covered
-                ]
-                cur = 0
-                for g_off, g_len in gaps:
-                    dest = buf[cur : cur + DATA_SHARDS * g_len]
-                    cur += DATA_SHARDS * g_len
-                    parts.append(("raw", g_off, gather(g_off, g_len, dest)))
-                _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
-                if not _q_put(read_q, (offset, slot_id, parts), pipe.stop):
-                    if slot_id >= 0:
-                        ring.release(slot_id)
-                    return
+            for i in survivors:
+                if present[i]:
+                    fds[i] = os.open(base_file_name + to_ext(i), os.O_RDONLY)
+            yield fds, fetch_pool
         finally:
             if fetch_pool is not None:
                 # wait for in-flight remote fetches: the caller closes
@@ -1100,172 +1152,148 @@ def stream_rebuild_ec_files(
             for fd in fds.values():
                 os.close(fd)
 
-    def writer():
-        while True:
-            item = _q_get(write_q, pipe.stop)
-            if item is _EOF or item is _STOPPED:
-                return
-            _offset, slot_id, parts = item
-            t0 = time.perf_counter()
-            fetched = []
-            for kind, off, payload in parts:
-                crcs = None
-                if kind == "h":
-                    payload = fetch_fn(payload)
-                    if isinstance(payload, tuple):
-                        payload, crcs = payload
-                fetched.append((kind, off, payload, crcs))
-            t1 = time.perf_counter()
-            if want_crcs:
-                # donated ranges and declined-fused tiles: table-CRC
-                # the bytes being written, charged as host compute
-                from seaweedfs_tpu.util.crc import crc32c
-
-                filled = []
-                for kind, off, payload, crcs in fetched:
-                    if crcs is None:
-                        if kind == "don":
-                            crcs = [crc32c(payload[i]) for i in targets]
-                        else:
-                            crcs = [
-                                crc32c(np.ascontiguousarray(payload[j]).tobytes())
-                                for j in range(len(targets))
-                            ]
-                    filled.append((kind, off, payload, crcs))
-                fetched = filled
-            t2 = time.perf_counter()
-            for kind, off, payload, crcs in fetched:
-                if kind == "don":
-                    for i in targets:
-                        _pwrite_full(out_fds[i], payload[i], off)
-                        EC_REPAIR_BYTES_WRITTEN.inc(len(payload[i]))
-                    length = len(payload[targets[0]]) if targets else 0
-                else:
-                    length = 0
-                    for j, i in enumerate(targets):
-                        row = np.ascontiguousarray(payload[j])
-                        _pwrite_full(out_fds[i], row, off)
-                        EC_REPAIR_BYTES_WRITTEN.inc(len(row))
-                        length = len(row)
-                if want_crcs and crcs is not None:
-                    crc_ranges.append((off, length, [int(c) for c in crcs]))
-            t3 = time.perf_counter()
-            if slot_id >= 0:
-                ring.release(slot_id)
-            _charge(busy, busy_lock, fetch_bucket, t1 - t0)
-            _charge(busy, busy_lock, "compute_s", t2 - t1)
-            _charge(busy, busy_lock, "write_s", t3 - t2)
-
-    ok = False
-    try:
-        for i in targets:
-            out_fds[i] = os.open(
-                base_file_name + to_ext(i),
-                os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                0o644,
-            )
-        for fd in out_fds.values():
-            _preallocate(fd, shard_size)
-        for _ in range(reader_threads):
-            pipe.spawn(reader)
-        for _ in range(writer_threads):
-            pipe.spawn(writer)
-        for _ in range(len(offsets)):
-            item = _q_get(read_q, pipe.stop)
-            if item is _STOPPED:
-                break
-            offset, slot_id, parts = item
-            t0 = time.perf_counter()
-            parts = [
-                (
-                    ("h", off, rebuild_fn(survivors, targets, payload))
-                    if kind == "raw"
-                    else (kind, off, payload)
+    def gather(src, g_off: int, g_len: int, dest: np.ndarray) -> np.ndarray:
+        """One [k, g_len] survivor read at g_off into a staging-ring
+        view — the only place rebuild bytes cross a disk or the network,
+        so the repair accounting lives here."""
+        fds, fetch_pool = src
+        tile = dest.reshape(DATA_SHARDS, g_len)
+        futures = {}
+        if fetch_pool is not None:
+            futures = {
+                j: fetch_pool.submit(remote_readers[i], g_off, g_len)
+                for j, i in enumerate(survivors)
+                if i not in fds
+            }
+        for j, i in enumerate(survivors):
+            if i in fds:
+                got = _pread_into(fds[i], tile[j], g_off)
+                read_local.inc(got)
+            else:
+                fut = futures.get(j)
+                raw = (
+                    fut.result()
+                    if fut is not None
+                    else remote_readers[i](g_off, g_len)
                 )
-                for kind, off, payload in parts
-            ]
-            _charge(busy, busy_lock, "device_s", time.perf_counter() - t0)
-            if not _q_put(write_q, (offset, slot_id, parts), pipe.stop):
-                break
-        for _ in range(writer_threads):
-            if not _q_put(write_q, _EOF, pipe.stop):
-                break
-        ok = True
-    finally:
-        try:
-            pipe.finish(caller_error=not ok)  # may re-raise a stage error
-        finally:
-            tc0 = time.perf_counter()
-            fsync_err: OSError | None = None
-            try:
-                for fd in out_fds.values():
-                    try:
-                        if durable and ok and not pipe.errors:
-                            # crash contract (weedcrash, docs/ANALYSIS.md
-                            # v3): a rebuild acked to its caller must
-                            # survive power loss — pin the shard bytes
-                            # before the fds close and the ack leaves;
-                            # a FAILED fsync fails the rebuild (below)
-                            # rather than acking page-cache-only bytes
-                            try:
-                                os.fsync(fd)
-                            except OSError as e:
-                                if fsync_err is None:
-                                    fsync_err = e
-                        os.close(fd)
-                    except OSError:
-                        pass
-                if not ok or pipe.errors or fsync_err is not None:
-                    # half-written targets must not survive: a later
-                    # shard_presence would count the garbage files as
-                    # valid shards and silently skip rebuilding them
-                    # (e.g. ec.rebuild's full-copy fallback retry)
-                    for i in targets:
-                        try:
-                            os.remove(base_file_name + to_ext(i))
-                        except OSError:
-                            pass
-                if fsync_err is not None:
-                    raise fsync_err
-            finally:
-                # an ENOSPC surfacing mid-stream must not skip the
-                # stats nor leak any fd (the reader pool closes its own
-                # survivor fds in its thread's finally)
-                busy["flush_s"] = time.perf_counter() - tc0
-                if stats is not None:
-                    _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads
-                    )
-                    stats["pipeline_depth"] = depth
-                    stats["ring_slots"] = ring.slots
-                    stats["driver"] = _driver_name(device_stage)
-                    if hasattr(rebuild_fn, "arms"):
-                        stats["arms"] = dict(rebuild_fn.arms)
-                    if (
-                        want_crcs
-                        and ok
-                        and not pipe.errors
-                        and fsync_err is None
-                    ):
-                        stats["shard_crcs"] = _fold_rebuild_crcs(
-                            targets, crc_ranges
-                        )
-                    if session is not None:
-                        stats["donated_bytes"] = session.donated_bytes
-                        stats["used_donated_bytes"] = (
-                            session.used_donated_bytes
-                        )
-                        stats["serve_yields"] = session.yields
-                _trace_stages(_sp, busy)
-                if device_stage:
-                    _report_traces(stats, _sp, traces0)
-                if session is not None and _sp:
-                    _sp.annotate("donated_bytes", session.used_donated_bytes)
-                    _sp.annotate("serve_yields", session.yields)
-                # a stage error re-raised by pipe.finish() is live in
-                # this finally; hand it to the span so a failed drive
-                # is distinguishable from a clean one in /debug/traces
-                _sp.__exit__(*sys.exc_info())
+                got = len(raw)
+                read_remote.inc(got)
+                if got == g_len:
+                    tile[j] = np.frombuffer(raw, dtype=np.uint8)
+            if got != g_len:
+                raise ValueError(
+                    f"ec shard {i} truncated: expected {g_len} at {g_off}"
+                )
+        return tile
+
+    def claim(offset):
+        """(offset, covered, gaps) of the tile at `offset`."""
+        step = min(tile_bytes, shard_size - offset)
+        if session is None:
+            return offset, [], [(offset, step)]
+        # serve-first arbitration: degraded GET gathers in flight own
+        # the disks/links; repair waits (bounded)
+        session.yield_to_serving()
+        return (offset, *session.consume(offset, step))
+
+    def fill(src, item, buf):
+        # parts: ("don", off, {target: bytes}) ride through as bytes;
+        # ("raw", off, [k, n] tile) get decoded. Only the gaps pay
+        # survivor reads — donated ranges moved zero new bytes
+        # (arXiv:2205.11015's partial-repair shape). Gap tiles
+        # sub-allocate contiguous views out of the tile's ring slot
+        # (Σ gap bytes ≤ step, so they fit).
+        _, covered, gaps = item
+        parts: list = [("don", d_off, per_t) for d_off, per_t in covered]
+        cur = 0
+        for g_off, g_len in gaps:
+            dest = buf[cur : cur + DATA_SHARDS * g_len]
+            cur += DATA_SHARDS * g_len
+            parts.append(("raw", g_off, gather(src, g_off, g_len, dest)))
+        return parts
+
+    def dispatch(item, parts):
+        t0 = time.perf_counter()
+        parts = [
+            (
+                ("h", off, rebuild_fn(survivors, targets, payload))
+                if kind == "raw"
+                else (kind, off, payload)
+            )
+            for kind, off, payload in parts
+        ]
+        op.book("device_s", time.perf_counter() - t0)
+        return parts
+
+    def fetch(item, _parts, parts):
+        """[(kind, off, payload, crcs or None)]: handles fetched."""
+        fetched = []
+        for kind, off, payload in parts:
+            crcs = None
+            if kind == "h":
+                payload = fetch_fn(payload)
+                if isinstance(payload, tuple):
+                    payload, crcs = payload
+            fetched.append((kind, off, payload, crcs))
+        return fetched
+
+    def rows_of(kind, payload) -> list:
+        """One fetched part's buffers, one per target in their order."""
+        if kind == "don":
+            return [payload[i] for i in targets]
+        return [np.ascontiguousarray(payload[j]) for j in range(len(targets))]
+
+    def checksum(item, _parts, fetched):
+        # donated ranges and declined-fused tiles: table-CRC the bytes
+        # being written
+        from seaweedfs_tpu.util.crc import crc32c
+
+        for kind, off, payload, crcs in fetched:
+            rows = rows_of(kind, payload)
+            if crcs is None:
+                crcs = [crc32c(bytes(row)) for row in rows]
+            crc_ranges.append((off, len(rows[0]), [int(c) for c in crcs]))
+
+    def write(fds, item, _parts, fetched):
+        for kind, off, payload, _ in fetched:
+            for fd, row in zip(fds, rows_of(kind, payload)):
+                _pwrite_full(fd, row, off)
+                EC_REPAIR_BYTES_WRITTEN.inc(len(row))
+
+    def report(out, sp, whole):
+        out["driver"] = _driver_name(device_stage)
+        if hasattr(rebuild_fn, "arms"):
+            out["arms"] = dict(rebuild_fn.arms)
+        if want_crcs and whole:
+            out["shard_crcs"] = _fold_rebuild_crcs(targets, crc_ranges)
+        if session is not None:
+            out["donated_bytes"] = session.donated_bytes
+            out["used_donated_bytes"] = session.used_donated_bytes
+            out["serve_yields"] = session.yields
+            sp.annotate("donated_bytes", session.used_donated_bytes)
+            sp.annotate("serve_yields", session.yields)
+
+    op.run(
+        nbytes=shard_size * max(1, len(targets)),
+        items=list(range(0, shard_size, tile_bytes)),
+        # gap gathers sub-allocate contiguous [k, g_len] views out of
+        # one flat slot per tile
+        slot_bytes=DATA_SHARDS * tile_bytes,
+        outputs=[(base_file_name + to_ext(i), shard_size) for i in targets],
+        opened=opened,
+        prepare=claim,
+        fill=fill,
+        dispatch=dispatch,
+        fetch=fetch,
+        checksum=checksum if want_crcs else None,
+        write=write,
+        report=report,
+        fetch_charges=getattr(fetch_fn, "charges", "writeback_s"),
+        stats=stats,
+        durable=durable,
+        reader_threads=reader_threads,
+        writer_threads=writer_threads,
+    )
     return list(targets)
 
 
@@ -1282,36 +1310,6 @@ def _fold_rebuild_crcs(
         for j, i in enumerate(targets):
             acc[i] = crc32c_combine(acc[i], crcs[j], length)
     return acc
-
-
-def _trace_stages(sp, busy: dict) -> None:
-    """The driver's booked seconds on its span, under the report line's
-    own field names (one vocabulary: what an operator reads at
-    /debug/traces is what the node's `ec.<verb> report=` line says).
-    Pool stages are thread-seconds; the phases are the span's children."""
-    sp.add_stages({k: v for k, v in busy.items() if k.endswith("_s")})
-
-
-def _finish_stats(
-    stats: dict,
-    busy: dict,
-    wall0: float,
-    reader_threads: int = 1,
-    writer_threads: int = 1,
-    end: float | None = None,
-) -> None:
-    """Per-stage busy thread-seconds + wall. The PIPELINE stages
-    (read/dispatch/fetch/write) run in thread POOLS, so a stage's Σ can
-    exceed wall (overlap across threads) — the wall a stage explains is
-    its total divided by its pool width. The serial phases of the
-    encode drivers (_OP_PHASES, flush_s among them) are different: they
-    partition the wall, given the clock sample `end` that closed the
-    last of them."""
-    wall = (time.perf_counter() if end is None else end) - wall0
-    stats.update({k: round(v, 4) for k, v in busy.items()})
-    stats["wall_s"] = round(wall, 4)
-    stats["reader_threads"] = reader_threads
-    stats["writer_threads"] = writer_threads
 
 
 # --- default TPU kernel stages ---------------------------------------------
@@ -1521,19 +1519,17 @@ def _tpu_rebuild_fns(want_crcs: bool = False):
     return rebuild_fn, _fetch
 
 
-# --- mesh-batched encode driver ---------------------------------------------
-
-
 def _read_tile_into(
     fd: int, dat_size: int, row_off: int, block: int, batch_off: int,
     step: int, dest: np.ndarray,
 ) -> None:
     """Fill dest [10, step] (ring-slot views) with one volume's tile of
-    the .dat, zero-padded past EOF — the single home of the batch
-    reader's striping math (same layout the single-volume reader
-    inlines). Per-row reads even for full rows: dest rows are strided
-    views of the batch slot, so there is no contiguous span to
-    coalesce into one pread here."""
+    the .dat, zero-padded past EOF — the single home of the striping
+    math, for the batch reader and for the single-volume reader's
+    sub-block tiles. Per-row reads even for full rows: the batch
+    reader's dest rows are strided views of its slot, so there is no
+    contiguous span to coalesce into one pread here (the single-volume
+    reader takes its full rows in one read of its own)."""
     for i in range(DATA_SHARDS):
         row = dest[i]
         off = row_off + i * block + batch_off
@@ -1544,6 +1540,9 @@ def _read_tile_into(
             got = _pread_into(fd, row[:n], off)
             if got < n:
                 row[got:n] = 0
+
+
+# --- mesh-batched encode driver ---------------------------------------------
 
 
 def stream_write_ec_files_batch(
@@ -1709,6 +1708,22 @@ class _HostBatchCodec:
         )
 
 
+def _mesh_width(codec, b: int, max_step: int) -> tuple[int, int, int]:
+    """(vol axis, stripe axis, tile width) of a batch of b volumes on
+    the codec's mesh: ONE static width for every round (finished volumes
+    ride as zero-step entries whose output is discarded), rounded so the
+    u32 lane count splits over the stripe axis in whole SWAR-friendly
+    chunks — shapes stay static, the mesh program compiles once."""
+    vol_axis, stripe = codec.mesh.devices.shape
+    if b % vol_axis:
+        raise ValueError(
+            f"batch of {b} volumes does not shard over the mesh's "
+            f"{vol_axis}-way 'vol' axis"
+        )
+    gran = 4 * 1024 * stripe
+    return vol_axis, stripe, -(-max_step // gran) * gran
+
+
 def _stream_batch_chunk(
     bases: list[str], codec, tile_bytes, large_block_size, small_block_size,
     stats, durable, want_crcs, reader_threads, writer_threads,
@@ -1718,18 +1733,7 @@ def _stream_batch_chunk(
     )
 
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
-    writer_threads = writer_threads or DEFAULT_WRITER_THREADS
-    reader_threads = reader_threads or DEFAULT_READER_THREADS
-    depth = pipeline_depth()
     b = len(bases)
-    vol_axis = codec.mesh.devices.shape[0]
-    stripe = codec.mesh.devices.shape[1]
-    if b % vol_axis:
-        raise ValueError(
-            f"batch of {b} volumes does not shard over the mesh's "
-            f"{vol_axis}-way 'vol' axis"
-        )
-
     sizes = [os.path.getsize(base + ".dat") for base in bases]
     tiles = [
         list(
@@ -1739,26 +1743,17 @@ def _stream_batch_chunk(
     ]
     rounds = max((len(ts) for ts in tiles), default=0)
     if not rounds:
-        # all .dat files empty: 14 empty shard files each — fsynced
-        # when durable, so the caller's .ecx publish can never outlive
-        # shard files a crash could drop
-        from seaweedfs_tpu.util import durable as _durable
-
-        for base in bases:
-            for i in range(TOTAL_SHARDS):
-                open(base + to_ext(i), "wb").close()
-                if durable:
-                    _durable.fsync_path(base + to_ext(i))
+        # all .dat files empty: 14 empty shard files each
+        _create_empty(
+            [base + to_ext(i) for base in bases for i in range(TOTAL_SHARDS)],
+            durable,
+        )
         if stats is not None and want_crcs:
             stats["shard_crcs"] = [[0] * TOTAL_SHARDS for _ in bases]
         return
-    # one static tile width for every round (finished volumes ride as
-    # zero-step entries whose output is discarded), rounded so the u32
-    # lane count splits over the stripe axis in whole SWAR-friendly
-    # chunks — shapes stay static, the mesh program compiles once
-    max_step = max(step for ts in tiles for _, _, _, step in ts)
-    gran = 4 * 1024 * stripe
-    width = -(-max_step // gran) * gran
+    vol_axis, stripe, width = _mesh_width(
+        codec, b, max(step for ts in tiles for _, _, _, step in ts)
+    )
     # fused CRC needs power-of-two lanes per device (crc_kernel); the
     # tail rounds (step < width) are host-checksummed regardless
     fused_crc = want_crcs and codec.crc_supported(width)
@@ -1772,289 +1767,163 @@ def _stream_batch_chunk(
         out_offs.append(list(acc))
         for v in range(b):
             acc[v] += step_of[r][v]
-
-    pipe = _Pipeline()
-    read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
-    write_q: queue.Queue = queue.Queue(maxsize=depth)
-    ring = _StagingRing(
-        depth + writer_threads + 1, b * DATA_SHARDS * width
-    )
-    busy = {
-        "read_s": 0.0,
-        "stage_s": 0.0,
-        "device_s": 0.0,
-        "writeback_s": 0.0,
-        "compute_s": 0.0,
-        "write_s": 0.0,
-        **_DEVICE_BUSY,
-        **_RESERVE_BUSY,
-    }
-    busy_lock = threading.Lock()
     round_crcs: list = [None] * rounds
-    last_fetch = [0.0]  # as in stream_write_ec_files: where ec.op.drain ends
-    traces0 = _program_traces()
-    wall0 = time.perf_counter()
-    _sp = trace.span("ec_stream.encode_batch", nbytes=sum(sizes))
-    _sp.__enter__()
-    phases = trace.Phases("ec.op.head", wall0)
-
-    idx_lock = threading.Lock()
-    idx_iter = iter(range(rounds))
-    out_fds: list[list[int]] = []
-    reservation: _Reservation | None = None
     # fewest mesh devices that held part of a round's batch: the
     # sharding can silently land everything on device 0
     held = vol_axis * stripe
+    op = _Op("ec_stream.encode_batch", True, _DEVICE_BUSY)
 
-    def reader():
-        fds = [os.open(base + ".dat", os.O_RDONLY) for base in bases]
-        try:
-            while True:
-                with idx_lock:
-                    r = next(idx_iter, None)
-                if r is None:
-                    return
-                got_slot = ring.acquire(pipe.stop)
-                if got_slot is None:
-                    return
-                slot_id, buf = got_slot
-                t0 = time.perf_counter()
-                buf3 = buf[: b * DATA_SHARDS * width].reshape(
-                    b, DATA_SHARDS, width
-                )
-                with trace.annotation("ec.read"):
-                    for v in range(b):
-                        if r >= len(tiles[v]):
-                            continue  # volume done: zero-step, output discarded
-                        row_off, block, batch_off, step = tiles[v][r]
-                        _read_tile_into(
-                            fds[v], sizes[v], row_off, block, batch_off, step,
-                            buf3[v, :, :step],
-                        )
-                _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
-                if not _q_put(read_q, (r, slot_id, buf3), pipe.stop):
-                    ring.release(slot_id)
-                    return
-        finally:
-            for fd in fds:
-                os.close(fd)
+    def fill(fds, r, buf):
+        buf3 = buf[: b * DATA_SHARDS * width].reshape(b, DATA_SHARDS, width)
+        for v in range(b):
+            if r >= len(tiles[v]):
+                continue  # volume done: zero-step, output discarded
+            row_off, block, batch_off, step = tiles[v][r]
+            _read_tile_into(
+                fds[v], sizes[v], row_off, block, batch_off, step,
+                buf3[v, :, :step],
+            )
+        return buf3
 
-    def writer():
+    def dispatch(r, buf3):
+        nonlocal held
+        t0 = time.perf_counter()
+        # staging: the u32 lane view is free host-side; device_put
+        # lays the batch out P('vol', None, 'stripe') over the mesh
+        with trace.annotation("ec.h2d"):
+            vols = codec.shard_volumes(buf3.view(np.uint32))
+        th = time.perf_counter()
+        held = min(held, codec.devices_holding(vols))
+        t1 = time.perf_counter()
+        with trace.annotation("ec.launch"):
+            handle = (
+                codec.encode_batch_u32_crc(vols)
+                if fused_crc
+                else codec.encode_batch_u32(vols)
+            )
+        t2 = time.perf_counter()
+        op.book("stage_s", t1 - t0)
+        op.book("device_s", t2 - t1)
+        # the transfer is part of the stage; the launch is all of device_s
+        op.book("h2d_s", th - t0)
+        op.book("launch_s", t2 - t1)
+        return handle
+
+    def fetch(r, buf3, handle):
+        """(parity [b, 4, width], fused CRCs [b, 14] or None)."""
         import jax
 
-        reservation.reserve(functools.partial(_charge, busy, busy_lock))
-        while True:
-            item = _q_get(write_q, pipe.stop)
-            if item is _EOF or item is _STOPPED:
-                return
-            r, slot_id, buf3, handle = item
-            t0 = time.perf_counter()
-            with trace.annotation("ec.writeback"):
-                if fused_crc:
-                    parity_dev, crcs_dev = handle
-                    crcs = np.asarray(jax.device_get(crcs_dev))
-                else:
-                    parity_dev, crcs = handle, None
-                parity = (
-                    np.asarray(jax.device_get(parity_dev))
-                    .view(np.uint8)
-                    .reshape(b, PARITY_SHARDS, width)
+        with trace.annotation("ec.writeback"):
+            crcs = None
+            if fused_crc:
+                handle, crcs_dev = handle
+                crcs = np.asarray(jax.device_get(crcs_dev))
+            parity = (
+                np.asarray(jax.device_get(handle))
+                .view(np.uint8)
+                .reshape(b, PARITY_SHARDS, width)
+            )
+        return parity, crcs
+
+    def checksum(r, buf3, result):
+        from seaweedfs_tpu.util.crc import crc32c
+
+        parity, crcs = result
+        vol_crcs: list = [None] * b
+        for v in range(b):
+            step = step_of[r][v]
+            if not step:
+                continue
+            if crcs is not None and step == width:
+                vol_crcs[v] = [int(c) for c in crcs[v]]
+            else:
+                # tail round: the fused CRC would cover the padded
+                # width; table-CRC the written bytes
+                vol_crcs[v] = [
+                    crc32c(buf3[v, i, :step].tobytes())
+                    for i in range(DATA_SHARDS)
+                ] + [
+                    crc32c(np.ascontiguousarray(parity[v, p, :step]).tobytes())
+                    for p in range(PARITY_SHARDS)
+                ]
+        round_crcs[r] = vol_crcs
+
+    def write(fds, r, buf3, result):
+        parity = result[0]
+        for v in range(b):
+            step = step_of[r][v]
+            if not step:
+                continue
+            off, first = out_offs[r][v], v * TOTAL_SHARDS
+            for i in range(DATA_SHARDS):
+                _pwrite_full(fds[first + i], buf3[v, i, :step], off)
+            for p in range(PARITY_SHARDS):
+                _pwrite_full(
+                    fds[first + DATA_SHARDS + p],
+                    np.ascontiguousarray(parity[v, p, :step]),
+                    off,
                 )
-            t1 = time.perf_counter()
-            with busy_lock:
-                last_fetch[0] = max(last_fetch[0], t1)
-            vol_crcs: list = [None] * b
-            if want_crcs:
-                from seaweedfs_tpu.util.crc import crc32c
 
-                for v in range(b):
-                    step = step_of[r][v]
-                    if not step:
-                        continue
-                    if crcs is not None and step == width:
-                        vol_crcs[v] = [int(c) for c in crcs[v]]
-                    else:
-                        # tail round: the fused CRC would cover the
-                        # padded width; table-CRC the written bytes
-                        vol_crcs[v] = [
-                            crc32c(buf3[v, i, :step].tobytes())
-                            for i in range(DATA_SHARDS)
-                        ] + [
-                            crc32c(
-                                np.ascontiguousarray(
-                                    parity[v, p, :step]
-                                ).tobytes()
-                            )
-                            for p in range(PARITY_SHARDS)
-                        ]
-            t2 = time.perf_counter()
-            if not reservation.wait():
-                return
-            tw = time.perf_counter()
-            with trace.annotation("ec.write"):
-                for v in range(b):
-                    step = step_of[r][v]
-                    if not step:
-                        continue
-                    off = out_offs[r][v]
-                    for i in range(DATA_SHARDS):
-                        _pwrite_full(out_fds[v][i], buf3[v, i, :step], off)
-                    for p in range(PARITY_SHARDS):
-                        _pwrite_full(
-                            out_fds[v][DATA_SHARDS + p],
-                            np.ascontiguousarray(parity[v, p, :step]),
-                            off,
-                        )
-            t3 = time.perf_counter()
-            if want_crcs:
-                round_crcs[r] = vol_crcs
-            ring.release(slot_id)
-            _charge(busy, busy_lock, "writeback_s", t1 - t0)
-            _charge(busy, busy_lock, "compute_s", t2 - t1)
-            _charge(busy, busy_lock, "write_s", t3 - tw)
-
-    ok = False
-    try:
-        for base in bases:
-            fds = []
-            out_fds.append(fds)
-            for i in range(TOTAL_SHARDS):
-                fds.append(
-                    os.open(
-                        base + to_ext(i),
-                        os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                        0o644,
-                    )
+    def report(out, sp, whole):
+        out["batch_volumes"] = b
+        out["mesh"] = {**codec.report(), "devices_per_round": held}
+        # the same count as a number of its own: a metric term reads a
+        # top-level field, not a nested dict
+        out["mesh_devices"] = held
+        if want_crcs and whole:
+            out["shard_crcs"] = [
+                list(crcs.values())
+                for crcs in _fold_round_crcs(
+                    b, range(TOTAL_SHARDS), step_of, round_crcs
                 )
-        reservation = _Reservation(
-            [
-                (fd, shard_file_size(size, large_block_size, small_block_size))
-                for fds, size in zip(out_fds, sizes)
-                for fd in fds
-            ],
-            pipe.stop,
-        )
-        # writers first, as in stream_write_ec_files
-        for _ in range(writer_threads):
-            pipe.spawn(writer)
-        for _ in range(min(reader_threads, rounds)):
-            pipe.spawn(reader)
-        for n in range(rounds):
-            item = _q_get(read_q, pipe.stop)
-            if item is _STOPPED:
-                break
-            r, slot_id, buf3 = item
-            t0 = time.perf_counter()
-            if n == 0:
-                phases.to("ec.op.dispatch", t0)
-            # staging: the u32 lane view is free host-side; device_put
-            # lays the batch out P('vol', None, 'stripe') over the mesh
-            with trace.annotation("ec.h2d"):
-                vols = codec.shard_volumes(buf3.view(np.uint32))
-            th = time.perf_counter()
-            held = min(held, codec.devices_holding(vols))
-            t1 = time.perf_counter()
-            with trace.annotation("ec.launch"):
-                handle = (
-                    codec.encode_batch_u32_crc(vols)
-                    if fused_crc
-                    else codec.encode_batch_u32(vols)
-                )
-            t2 = time.perf_counter()
-            if n == rounds - 1:
-                phases.to("ec.op.drain", t2)
-            _charge(busy, busy_lock, "stage_s", t1 - t0)
-            _charge(busy, busy_lock, "device_s", t2 - t1)
-            # the transfer is part of the stage; the launch is all of device_s
-            _charge(busy, busy_lock, "h2d_s", th - t0)
-            _charge(busy, busy_lock, "launch_s", t2 - t1)
-            if not _q_put(write_q, (r, slot_id, buf3, handle), pipe.stop):
-                break
-        for _ in range(writer_threads):
-            if not _q_put(write_q, _EOF, pipe.stop):
-                break
-        ok = True
-    finally:
-        try:
-            pipe.finish(caller_error=not ok)
-        finally:
-            phases.to("ec.op.write_tail", (ok and last_fetch[0]) or None)
-            phases.to("ec.op.flush")
-            fsync_err: OSError | None = None
-            try:
-                for fds in out_fds:
-                    for fd in fds:
-                        try:
-                            if durable and ok and not pipe.errors:
-                                try:
-                                    os.fsync(fd)
-                                except OSError as e:
-                                    if fsync_err is None:
-                                        fsync_err = e
-                            os.close(fd)
-                        except OSError:
-                            pass
-                if not ok or pipe.errors or fsync_err is not None:
-                    # same abort contract as the single-volume driver:
-                    # no partial shard set may survive for ANY volume
-                    for base in bases:
-                        for i in range(TOTAL_SHARDS):
-                            try:
-                                os.remove(base + to_ext(i))
-                            except OSError:
-                                pass
-                if fsync_err is not None:
-                    raise fsync_err
-            finally:
-                end = _close_phases(phases, busy)
-                _book_reserve_done(busy, reservation, wall0)
-                if stats is not None:
-                    _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads,
-                        end,
-                    )
-                    stats["pipeline_depth"] = depth
-                    stats["ring_slots"] = ring.slots
-                    stats["batch_volumes"] = b
-                    stats["mesh"] = {
-                        **codec.report(), "devices_per_round": held
-                    }
-                    # the same count as a number of its own: a metric
-                    # term reads a top-level field, not a nested dict
-                    stats["mesh_devices"] = held
-                    if (
-                        want_crcs
-                        and ok
-                        and not pipe.errors
-                        and fsync_err is None
-                    ):
-                        stats["shard_crcs"] = _fold_batch_crcs(
-                            b, step_of, round_crcs
-                        )
-                _trace_stages(_sp, busy)
-                _report_traces(stats, _sp, traces0)
-                _sp.annotate("mesh", f"{vol_axis}x{stripe}")
-                _sp.annotate("mesh_devices", held)
-                _sp.annotate("batch_volumes", b)
-                _sp.__exit__(*sys.exc_info())
+            ]
+        sp.annotate("mesh", f"{vol_axis}x{stripe}")
+        sp.annotate("mesh_devices", held)
+        sp.annotate("batch_volumes", b)
+
+    op.run(
+        nbytes=sum(sizes),
+        items=list(range(rounds)),
+        slot_bytes=b * DATA_SHARDS * width,
+        outputs=[
+            (
+                base + to_ext(i),
+                shard_file_size(size, large_block_size, small_block_size),
+            )
+            for base, size in zip(bases, sizes)
+            for i in range(TOTAL_SHARDS)
+        ],
+        opened=lambda: _opened([base + ".dat" for base in bases]),
+        fill=fill,
+        dispatch=dispatch,
+        fetch=fetch,
+        checksum=checksum if want_crcs else None,
+        write=write,
+        report=report,
+        stats=stats,
+        durable=durable,
+        reader_threads=reader_threads,
+        writer_threads=writer_threads,
+    )
 
 
-def _fold_batch_crcs(
-    b: int, step_of: list[list[int]], round_crcs: list
-) -> list[list[int]]:
-    """Per-volume 14-entry whole-file CRCs from the per-round writer
-    records, folded in round order."""
+def _fold_round_crcs(
+    b: int, ids, step_of: list[list[int]], round_crcs: list
+) -> list[dict[int, int]]:
+    """Per-volume {shard id: whole-file CRC-32C} from the batch writer
+    pools' per-round records (one CRC per id of `ids`, in their order),
+    folded in round order."""
     from seaweedfs_tpu.util.crc import crc32c_combine
 
     out = []
     for v in range(b):
-        acc = [0] * TOTAL_SHARDS
+        acc = dict.fromkeys(ids, 0)
         for r, vol_crcs in enumerate(round_crcs):
             step = step_of[r][v]
             if not step or vol_crcs is None or vol_crcs[v] is None:
                 continue
-            for i in range(TOTAL_SHARDS):
-                acc[i] = crc32c_combine(acc[i], vol_crcs[v][i], step)
+            for t, sid in enumerate(ids):
+                acc[sid] = crc32c_combine(acc[sid], vol_crcs[v][t], step)
         out.append(acc)
     return out
 
@@ -2235,49 +2104,40 @@ def _rebuild_batch_chunk(
     reconstruct_batch_u32 once per round, pwrites the rebuilt target
     rows. Same abort contract: any failure removes every volume's
     target files."""
-    if isinstance(codec, _HostBatchCodec):
-        return _rebuild_batch_chunk_host(
-            bases, codec.rs, survivors, targets, tile_bytes, stats,
-            durable, want_crcs, reader_threads, writer_threads,
-        )
     from seaweedfs_tpu.ec.ec_files import to_ext
 
     # local rebuilds want the fine tile (BENCH_r12: more in-flight
     # preads to overlap, page-cache-friendly spans) — and the batch arm
     # is local-survivor-only by contract
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES // 2
-    writer_threads = writer_threads or DEFAULT_WRITER_THREADS
-    reader_threads = reader_threads or DEFAULT_READER_THREADS
-    depth = pipeline_depth()
     b = len(bases)
-    vol_axis = codec.mesh.devices.shape[0]
-    stripe = codec.mesh.devices.shape[1]
-    if b % vol_axis:
-        raise ValueError(
-            f"batch of {b} volumes does not shard over the mesh's "
-            f"{vol_axis}-way 'vol' axis"
-        )
-
     sizes = [
         os.path.getsize(base + to_ext(survivors[0])) for base in bases
     ]
-    rounds = max(-(-size // tile_bytes) for size in sizes)
-    if not rounds:
+    outputs = [
+        (base + to_ext(t), size)
+        for base, size in zip(bases, sizes)
+        for t in targets
+    ]
+    host = isinstance(codec, _HostBatchCodec)
+    if not any(sizes):
         # all-empty shard sets: rebuilt targets are empty files too
-        from seaweedfs_tpu.util import durable as _durable
-
-        for base in bases:
-            for t in targets:
-                open(base + to_ext(t), "wb").close()
-                if durable:
-                    _durable.fsync_path(base + to_ext(t))
+        _create_empty([path for path, _ in outputs], durable)
         if stats is not None:
             stats["batch_volumes"] = b
+            if host:
+                stats["codec_arm"] = "host"
             if want_crcs:
                 stats["shard_crcs"] = [
                     {t: 0 for t in targets} for _ in bases
                 ]
         return
+    if host:
+        return _rebuild_batch_chunk_host(
+            bases, codec.rs, survivors, targets, sizes, outputs, tile_bytes,
+            stats, durable, want_crcs, reader_threads, writer_threads,
+        )
+    rounds = max(-(-size // tile_bytes) for size in sizes)
     step_of = [
         [
             max(0, min(tile_bytes, sizes[v] - r * tile_bytes))
@@ -2285,232 +2145,95 @@ def _rebuild_batch_chunk(
         ]
         for r in range(rounds)
     ]
-    # one static tile width for every round, rounded so the u32 lane
-    # count splits over the stripe axis in whole SWAR-friendly chunks
-    max_step = max(step for row in step_of for step in row)
-    gran = 4 * 1024 * stripe
-    width = -(-max_step // gran) * gran
-
-    pipe = _Pipeline()
-    read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
-    write_q: queue.Queue = queue.Queue(maxsize=depth)
-    ring = _StagingRing(
-        depth + writer_threads + 1, b * DATA_SHARDS * width
+    vol_axis, stripe, width = _mesh_width(
+        codec, b, max(step for row in step_of for step in row)
     )
-    busy = {
-        "read_s": 0.0,
-        "stage_s": 0.0,
-        "device_s": 0.0,
-        "writeback_s": 0.0,
-        "compute_s": 0.0,
-        "write_s": 0.0,
-    }
-    busy_lock = threading.Lock()
     round_crcs: list = [None] * rounds
-    traces0 = _program_traces()
-    wall0 = time.perf_counter()
-    _sp = trace.span(
-        "ec_stream.rebuild_batch",
-        nbytes=sum(sizes) * max(1, len(targets)),
-    )
-    _sp.__enter__()
-
-    idx_lock = threading.Lock()
-    idx_iter = iter(range(rounds))
-    out_fds: list[dict[int, int]] = []
     held = vol_axis * stripe  # see _stream_batch_chunk
-    read_local = EC_REPAIR_BYTES_READ.labels("local")
+    op = _Op("ec_stream.rebuild_batch", True)
 
-    def reader():
-        fds = [
-            [
-                os.open(base + to_ext(s), os.O_RDONLY)
-                for s in survivors
-            ]
-            for base in bases
-        ]
-        try:
-            while True:
-                with idx_lock:
-                    r = next(idx_iter, None)
-                if r is None:
-                    return
-                got_slot = ring.acquire(pipe.stop)
-                if got_slot is None:
-                    return
-                slot_id, buf = got_slot
-                t0 = time.perf_counter()
-                buf3 = buf[: b * DATA_SHARDS * width].reshape(
-                    b, DATA_SHARDS, width
-                )
-                off = r * tile_bytes
-                for v in range(b):
-                    step = step_of[r][v]
-                    if not step:
-                        continue  # volume done: output discarded
-                    tile = buf3[v, :, :step]
-                    for j in range(DATA_SHARDS):
-                        got = _pread_into(fds[v][j], tile[j], off)
-                        read_local.inc(got)
-                        if got != step:
-                            raise ValueError(
-                                f"ec shard {survivors[j]} truncated: "
-                                f"expected {step} at {off} "
-                                f"({bases[v] + to_ext(survivors[j])})"
-                            )
-                _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
-                if not _q_put(read_q, (r, slot_id, buf3), pipe.stop):
-                    ring.release(slot_id)
-                    return
-        finally:
-            for vol_fds in fds:
-                for fd in vol_fds:
-                    os.close(fd)
+    def fill(src, r, buf):
+        buf3 = buf[: b * DATA_SHARDS * width].reshape(b, DATA_SHARDS, width)
+        for v in range(b):
+            step = step_of[r][v]
+            if step:  # else volume done: output discarded
+                src.read(v, r * tile_bytes, buf3[v, :, :step])
+        return buf3
 
-    def writer():
+    def dispatch(r, buf3):
+        nonlocal held
+        t0 = time.perf_counter()
+        vols = codec.shard_volumes(buf3.view(np.uint32))
+        held = min(held, codec.devices_holding(vols))
+        t1 = time.perf_counter()
+        handle = codec.reconstruct_batch_u32(survivors, targets, vols)
+        t2 = time.perf_counter()
+        op.book("stage_s", t1 - t0)
+        op.book("device_s", t2 - t1)
+        return handle
+
+    def fetch(r, buf3, handle):
         import jax
 
-        while True:
-            item = _q_get(write_q, pipe.stop)
-            if item is _EOF or item is _STOPPED:
-                return
-            r, slot_id, buf3, handle = item
-            t0 = time.perf_counter()
-            rebuilt = (
+        with trace.annotation("ec.writeback"):
+            return (
                 np.asarray(jax.device_get(handle))
                 .view(np.uint8)
                 .reshape(b, len(targets), width)
             )
-            t1 = time.perf_counter()
-            vol_crcs: list = [None] * b
-            if want_crcs:
-                from seaweedfs_tpu.util.crc import crc32c
 
-                for v in range(b):
-                    step = step_of[r][v]
-                    if not step:
-                        continue
-                    # no fused CRC tier for reconstruct: host table
-                    # CRC the rebuilt rows (charged to compute_s)
-                    vol_crcs[v] = [
-                        crc32c(
-                            np.ascontiguousarray(
-                                rebuilt[v][t, :step]
-                            ).tobytes()
-                        )
-                        for t in range(len(targets))
-                    ]
-            t2 = time.perf_counter()
-            off = r * tile_bytes
-            for v in range(b):
-                step = step_of[r][v]
-                if not step:
-                    continue
-                for t, tid in enumerate(targets):
-                    _pwrite_full(
-                        out_fds[v][tid],
-                        np.ascontiguousarray(rebuilt[v][t, :step]),
-                        off,
-                    )
-                    EC_REPAIR_BYTES_WRITTEN.inc(step)
-            t3 = time.perf_counter()
-            if want_crcs:
-                round_crcs[r] = vol_crcs
-            ring.release(slot_id)
-            _charge(busy, busy_lock, "writeback_s", t1 - t0)
-            _charge(busy, busy_lock, "compute_s", t2 - t1)
-            _charge(busy, busy_lock, "write_s", t3 - t2)
+    def checksum(r, buf3, rebuilt):
+        # no fused CRC tier for reconstruct: host table CRC the rebuilt
+        # rows
+        from seaweedfs_tpu.util.crc import crc32c
 
-    ok = False
-    try:
-        for v, base in enumerate(bases):
-            fds: dict[int, int] = {}
-            out_fds.append(fds)
-            for tid in targets:
-                fds[tid] = os.open(
-                    base + to_ext(tid),
-                    os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                    0o644,
+        round_crcs[r] = [
+            [
+                crc32c(np.ascontiguousarray(rebuilt[v][t, :step]).tobytes())
+                for t in range(len(targets))
+            ]
+            if step
+            else None
+            for v, step in enumerate(step_of[r])
+        ]
+
+    def write(fds, r, buf3, rebuilt):
+        for v, step in enumerate(step_of[r]):
+            if not step:
+                continue
+            for t in range(len(targets)):
+                _pwrite_full(
+                    fds[v * len(targets) + t],
+                    np.ascontiguousarray(rebuilt[v][t, :step]),
+                    r * tile_bytes,
                 )
-            for fd in fds.values():
-                _preallocate(fd, sizes[v])
-        for _ in range(min(reader_threads, rounds)):
-            pipe.spawn(reader)
-        for _ in range(writer_threads):
-            pipe.spawn(writer)
-        for _ in range(rounds):
-            item = _q_get(read_q, pipe.stop)
-            if item is _STOPPED:
-                break
-            r, slot_id, buf3 = item
-            t0 = time.perf_counter()
-            vols = codec.shard_volumes(buf3.view(np.uint32))
-            held = min(held, codec.devices_holding(vols))
-            t1 = time.perf_counter()
-            handle = codec.reconstruct_batch_u32(survivors, targets, vols)
-            t2 = time.perf_counter()
-            _charge(busy, busy_lock, "stage_s", t1 - t0)
-            _charge(busy, busy_lock, "device_s", t2 - t1)
-            if not _q_put(write_q, (r, slot_id, buf3, handle), pipe.stop):
-                break
-        for _ in range(writer_threads):
-            if not _q_put(write_q, _EOF, pipe.stop):
-                break
-        ok = True
-    finally:
-        try:
-            pipe.finish(caller_error=not ok)
-        finally:
-            tc0 = time.perf_counter()
-            fsync_err: OSError | None = None
-            try:
-                for fds in out_fds:
-                    for fd in fds.values():
-                        try:
-                            if durable and ok and not pipe.errors:
-                                try:
-                                    os.fsync(fd)
-                                except OSError as e:
-                                    if fsync_err is None:
-                                        fsync_err = e
-                            os.close(fd)
-                        except OSError:
-                            pass
-                if not ok or pipe.errors or fsync_err is not None:
-                    # abort contract: no partial rebuilt shard may
-                    # survive for ANY volume in the chunk
-                    for base in bases:
-                        for tid in targets:
-                            try:
-                                os.remove(base + to_ext(tid))
-                            except OSError:
-                                pass
-                if fsync_err is not None:
-                    raise fsync_err
-            finally:
-                busy["flush_s"] = time.perf_counter() - tc0
-                if stats is not None:
-                    _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads
-                    )
-                    stats["pipeline_depth"] = depth
-                    stats["ring_slots"] = ring.slots
-                    stats["batch_volumes"] = b
-                    stats["mesh"] = {
-                        **codec.report(), "devices_per_round": held
-                    }
-                    if (
-                        want_crcs
-                        and ok
-                        and not pipe.errors
-                        and fsync_err is None
-                    ):
-                        stats["shard_crcs"] = _fold_rebuild_batch_crcs(
-                            b, targets, step_of, round_crcs
-                        )
-                _trace_stages(_sp, busy)
-                _report_traces(stats, _sp, traces0)
-                _sp.__exit__(*sys.exc_info())
+                EC_REPAIR_BYTES_WRITTEN.inc(step)
+
+    def report(out, sp, whole):
+        out["batch_volumes"] = b
+        out["mesh"] = {**codec.report(), "devices_per_round": held}
+        if want_crcs and whole:
+            out["shard_crcs"] = _fold_round_crcs(
+                b, targets, step_of, round_crcs
+            )
+
+    op.run(
+        nbytes=sum(sizes) * max(1, len(targets)),
+        items=list(range(rounds)),
+        slot_bytes=b * DATA_SHARDS * width,
+        outputs=outputs,
+        opened=lambda: _Survivors(bases, survivors),
+        fill=fill,
+        dispatch=dispatch,
+        fetch=fetch,
+        checksum=checksum if want_crcs else None,
+        write=write,
+        report=report,
+        stats=stats,
+        durable=durable,
+        reader_threads=reader_threads,
+        writer_threads=writer_threads,
+    )
 
 
 # At or below this many (volume, tile) work items the host arm skips
@@ -2520,136 +2243,11 @@ def _rebuild_batch_chunk(
 _HOST_INLINE_TILES = 16
 
 
-def _rebuild_batch_chunk_host_inline(
-    bases: list[str], rs, rows, survivors: tuple[int, ...],
-    targets: tuple[int, ...], sizes: list[int],
-    items: list[tuple[int, int]], tile_bytes: int, stats, durable,
-    want_crcs,
-) -> None:
-    """Zero-thread host arm for small batches: one staging buffer, one
-    pass over the flat (volume, tile) work list, decode via the group's
-    cached decode-rows matrix. Many-small-volumes repair is latency-
-    bound on fixed costs, so the win here is paying ONE set of them for
-    the whole batch and none of the pipeline's per-handoff scheduler
-    wakeups. Same durability/abort contract as the threaded arms."""
-    from seaweedfs_tpu.ec.ec_files import to_ext
-
-    b = len(bases)
-    busy = {"read_s": 0.0, "compute_s": 0.0, "write_s": 0.0}
-    crc_parts: list[tuple[int, int, int, list[int]]] = []
-    wall0 = time.perf_counter()
-    buf = np.empty((DATA_SHARDS, tile_bytes), dtype=np.uint8)
-    in_fds: list[list[int] | None] = [None] * b
-    out_fds: list[dict[int, int]] = []
-    read_local = EC_REPAIR_BYTES_READ.labels("local")
-    ok = False
-    with trace.span(
-        "ec_stream.rebuild_batch",
-        nbytes=sum(sizes) * max(1, len(targets)),
-    ) as _sp:
-        try:
-            for v, base in enumerate(bases):
-                fds: dict[int, int] = {}
-                out_fds.append(fds)
-                for tid in targets:
-                    fds[tid] = os.open(
-                        base + to_ext(tid),
-                        os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                        0o644,
-                    )
-                    _preallocate(fds[tid], sizes[v])
-            for v, off in items:
-                vol_fds = in_fds[v]
-                if vol_fds is None:
-                    vol_fds = in_fds[v] = [
-                        os.open(bases[v] + to_ext(s), os.O_RDONLY)
-                        for s in survivors
-                    ]
-                step = min(tile_bytes, sizes[v] - off)
-                tile = buf[:, :step]
-                t0 = time.perf_counter()
-                for j in range(DATA_SHARDS):
-                    got = _pread_into(vol_fds[j], tile[j], off)
-                    read_local.inc(got)
-                    if got != step:
-                        raise ValueError(
-                            f"ec shard {survivors[j]} truncated: "
-                            f"expected {step} at {off} "
-                            f"({bases[v] + to_ext(survivors[j])})"
-                        )
-                t1 = time.perf_counter()
-                rebuilt = rs._apply(rows, tile)
-                if want_crcs:
-                    from seaweedfs_tpu.util.crc import crc32c
-
-                    crc_parts.append((v, off, step, [
-                        crc32c(
-                            np.ascontiguousarray(rebuilt[t]).tobytes()
-                        )
-                        for t in range(len(targets))
-                    ]))
-                t2 = time.perf_counter()
-                for t, tid in enumerate(targets):
-                    _pwrite_full(
-                        out_fds[v][tid],
-                        np.ascontiguousarray(rebuilt[t]),
-                        off,
-                    )
-                    EC_REPAIR_BYTES_WRITTEN.inc(step)
-                t3 = time.perf_counter()
-                busy["read_s"] += t1 - t0
-                busy["compute_s"] += t2 - t1
-                busy["write_s"] += t3 - t2
-            ok = True
-        finally:
-            for vol_fds in in_fds:
-                for ifd in vol_fds or ():
-                    try:
-                        os.close(ifd)
-                    except OSError:
-                        pass
-            tc0 = time.perf_counter()
-            fsync_err: OSError | None = None
-            try:
-                for fds in out_fds:
-                    for fd in fds.values():
-                        try:
-                            if durable and ok:
-                                try:
-                                    os.fsync(fd)
-                                except OSError as e:
-                                    if fsync_err is None:
-                                        fsync_err = e
-                            os.close(fd)
-                        except OSError:
-                            pass
-                if not ok or fsync_err is not None:
-                    for base in bases:
-                        for tid in targets:
-                            try:
-                                os.remove(base + to_ext(tid))
-                            except OSError:
-                                pass
-                if fsync_err is not None:
-                    raise fsync_err
-            finally:
-                busy["flush_s"] = time.perf_counter() - tc0
-                if stats is not None:
-                    _finish_stats(stats, busy, wall0, 1, 1)
-                    stats["batch_volumes"] = b
-                    stats["codec_arm"] = "host"
-                    stats["host_inline"] = True
-                    if want_crcs and ok and fsync_err is None:
-                        stats["shard_crcs"] = _fold_host_batch_crcs(
-                            b, targets, crc_parts
-                        )
-                _trace_stages(_sp, busy)
-
-
 def _rebuild_batch_chunk_host(
     bases: list[str], rs, survivors: tuple[int, ...],
-    targets: tuple[int, ...], tile_bytes, stats, durable, want_crcs,
-    reader_threads, writer_threads,
+    targets: tuple[int, ...], sizes: list[int],
+    outputs: list[tuple[str, int]], tile_bytes: int, stats, durable,
+    want_crcs, reader_threads, writer_threads,
 ) -> None:
     """Host arm of the batch rebuild: one shared pipeline whose work
     items are per-(volume, tile) survivor gathers, decoded in the
@@ -2658,17 +2256,14 @@ def _rebuild_batch_chunk_host(
     resident on small hosts — an all-volumes-per-round slot measurably
     loses CPU to memory traffic), and the stream crosses volume
     boundaries without the per-volume spawn/drain the serial path
-    pays. Same abort contract as the mesh arm."""
-    from seaweedfs_tpu.ec.ec_files import to_ext
+    pays. Same abort contract as the mesh arm.
 
-    tile_bytes = tile_bytes or DEFAULT_TILE_BYTES // 2
-    writer_threads = writer_threads or DEFAULT_WRITER_THREADS
-    reader_threads = reader_threads or DEFAULT_READER_THREADS
-    depth = pipeline_depth()
+    At or below _HOST_INLINE_TILES items there are no pools at all: one
+    staging buffer, one pass over the work list on the caller's thread.
+    Many-small-volumes repair is latency-bound on fixed costs, so the
+    win there is paying ONE set of them for the whole batch and none of
+    the pipeline's per-handoff scheduler wakeups."""
     b = len(bases)
-    sizes = [
-        os.path.getsize(base + to_ext(survivors[0])) for base in bases
-    ]
     # flat (volume, offset) work list: the pipeline streams straight
     # through volume boundaries, no drain between them
     items = [
@@ -2676,208 +2271,109 @@ def _rebuild_batch_chunk_host(
         for v in range(b)
         for off in range(0, sizes[v], tile_bytes)
     ]
-    if not items:
-        from seaweedfs_tpu.util import durable as _durable
-
-        for base in bases:
-            for t in targets:
-                open(base + to_ext(t), "wb").close()
-                if durable:
-                    _durable.fsync_path(base + to_ext(t))
-        if stats is not None:
-            stats["batch_volumes"] = b
-            stats["codec_arm"] = "host"
-            if want_crcs:
-                stats["shard_crcs"] = [
-                    {t: 0 for t in targets} for _ in bases
-                ]
-        return
-
     rows = rs.decode_rows(tuple(survivors), tuple(targets))
-    if len(items) <= _HOST_INLINE_TILES:
-        return _rebuild_batch_chunk_host_inline(
-            bases, rs, rows, survivors, targets, sizes, items,
-            tile_bytes, stats, durable, want_crcs,
-        )
-    pipe = _Pipeline()
-    read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
-    write_q: queue.Queue = queue.Queue(maxsize=depth)
-    ring = _StagingRing(
-        depth + writer_threads + 1, DATA_SHARDS * tile_bytes
-    )
-    busy = {
-        "read_s": 0.0,
-        "stage_s": 0.0,
-        "device_s": 0.0,
-        "writeback_s": 0.0,
-        "compute_s": 0.0,
-        "write_s": 0.0,
-    }
-    busy_lock = threading.Lock()
     # (volume, offset, step, [crc per target]); append is GIL-atomic,
-    # order restored by sorting on offset at fold time
+    # order restored by sorting at fold time
     crc_parts: list[tuple[int, int, int, list[int]]] = []
+
+    def fill(src, item, buf):
+        v, off = item
+        step = min(tile_bytes, sizes[v] - off)
+        tile = buf[: DATA_SHARDS * step].reshape(DATA_SHARDS, step)
+        src.read(v, off, tile)
+        return tile
+
+    def fetch(item, tile, _handle):
+        return rs._apply(rows, tile)
+
+    def checksum(item, tile, rebuilt):
+        from seaweedfs_tpu.util.crc import crc32c
+
+        crc_parts.append((*item, tile.shape[1], [
+            crc32c(np.ascontiguousarray(rebuilt[t]).tobytes())
+            for t in range(len(targets))
+        ]))
+
+    def write(fds, item, tile, rebuilt):
+        v, off = item
+        for t in range(len(targets)):
+            _pwrite_full(
+                fds[v * len(targets) + t],
+                np.ascontiguousarray(rebuilt[t]),
+                off,
+            )
+            EC_REPAIR_BYTES_WRITTEN.inc(tile.shape[1])
+
+    def report(out, sp, whole):
+        out["batch_volumes"] = b
+        out["codec_arm"] = "host"
+        if want_crcs and whole:
+            out["shard_crcs"] = _fold_host_batch_crcs(b, targets, crc_parts)
+
+    if len(items) > _HOST_INLINE_TILES:
+        return _Op("ec_stream.rebuild_batch").run(
+            nbytes=sum(sizes) * max(1, len(targets)),
+            items=items,
+            slot_bytes=DATA_SHARDS * tile_bytes,
+            outputs=outputs,
+            opened=lambda: _Survivors(bases, survivors),
+            fill=fill,
+            dispatch=lambda item, tile: None,  # the writers decode
+            fetch=fetch,
+            checksum=checksum if want_crcs else None,
+            write=write,
+            report=report,
+            fetch_charges="compute_s",
+            stats=stats,
+            durable=durable,
+            reader_threads=reader_threads,
+            writer_threads=writer_threads,
+        )
+    busy = {"read_s": 0.0, "compute_s": 0.0, "write_s": 0.0}
     wall0 = time.perf_counter()
-    _sp = trace.span(
-        "ec_stream.rebuild_batch",
-        nbytes=sum(sizes) * max(1, len(targets)),
-    )
-    _sp.__enter__()
-
-    idx_lock = threading.Lock()
-    idx_iter = iter(items)
-    out_fds: list[dict[int, int]] = []
-    read_local = EC_REPAIR_BYTES_READ.labels("local")
-
-    def reader():
-        fds: dict[int, list[int]] = {}  # volume -> survivor fds, lazy
-        try:
-            while True:
-                with idx_lock:
-                    it = next(idx_iter, None)
-                if it is None:
-                    return
-                v, off = it
-                vol_fds = fds.get(v)
-                if vol_fds is None:
-                    vol_fds = fds[v] = [
-                        os.open(bases[v] + to_ext(s), os.O_RDONLY)
-                        for s in survivors
-                    ]
-                got_slot = ring.acquire(pipe.stop)
-                if got_slot is None:
-                    return
-                slot_id, buf = got_slot
-                step = min(tile_bytes, sizes[v] - off)
-                t0 = time.perf_counter()
-                tile = buf[: DATA_SHARDS * step].reshape(
-                    DATA_SHARDS, step
-                )
-                for j in range(DATA_SHARDS):
-                    got = _pread_into(vol_fds[j], tile[j], off)
-                    read_local.inc(got)
-                    if got != step:
-                        raise ValueError(
-                            f"ec shard {survivors[j]} truncated: "
-                            f"expected {step} at {off} "
-                            f"({bases[v] + to_ext(survivors[j])})"
-                        )
-                _charge(busy, busy_lock, "read_s", time.perf_counter() - t0)
-                if not _q_put(
-                    read_q, (v, off, step, slot_id, tile), pipe.stop
-                ):
-                    ring.release(slot_id)
-                    return
-        finally:
-            for vol_fds in fds.values():
-                for fd in vol_fds:
-                    os.close(fd)
-
-    def writer():
-        while True:
-            item = _q_get(write_q, pipe.stop)
-            if item is _EOF or item is _STOPPED:
-                return
-            v, off, step, slot_id, tile = item
-            t0 = time.perf_counter()
-            rebuilt = rs._apply(rows, tile)
-            t1 = time.perf_counter()
-            if want_crcs:
-                from seaweedfs_tpu.util.crc import crc32c
-
-                crc_parts.append((v, off, step, [
-                    crc32c(np.ascontiguousarray(rebuilt[t]).tobytes())
-                    for t in range(len(targets))
-                ]))
-            t2 = time.perf_counter()
-            for t, tid in enumerate(targets):
-                _pwrite_full(
-                    out_fds[v][tid],
-                    np.ascontiguousarray(rebuilt[t]),
-                    off,
-                )
-                EC_REPAIR_BYTES_WRITTEN.inc(step)
-            t3 = time.perf_counter()
-            ring.release(slot_id)
-            _charge(busy, busy_lock, "compute_s", t2 - t0)
-            _charge(busy, busy_lock, "write_s", t3 - t2)
-
+    buf = np.empty(DATA_SHARDS * tile_bytes, dtype=np.uint8)
+    fds: list[int] = []
     ok = False
-    try:
-        for v, base in enumerate(bases):
-            fds: dict[int, int] = {}
-            out_fds.append(fds)
-            for tid in targets:
-                fds[tid] = os.open(
-                    base + to_ext(tid),
-                    os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                    0o644,
-                )
-            for fd in fds.values():
-                _preallocate(fd, sizes[v])
-        for _ in range(min(reader_threads, len(items))):
-            pipe.spawn(reader)
-        for _ in range(writer_threads):
-            pipe.spawn(writer)
-        for _ in range(len(items)):
-            item = _q_get(read_q, pipe.stop)
-            if item is _STOPPED:
-                break
-            if not _q_put(write_q, item, pipe.stop):
-                break
-        for _ in range(writer_threads):
-            if not _q_put(write_q, _EOF, pipe.stop):
-                break
-        ok = True
-    finally:
+    with trace.span(
+        "ec_stream.rebuild_batch", nbytes=sum(sizes) * max(1, len(targets))
+    ) as sp, _Survivors(bases, survivors) as src:
         try:
-            pipe.finish(caller_error=not ok)
+            for path, size in outputs:
+                fds.append(
+                    os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
+                )
+                _preallocate(fds[-1], size)
+            for item in items:
+                t0 = time.perf_counter()
+                tile = fill(src, item, buf)
+                t1 = time.perf_counter()
+                rebuilt = fetch(item, tile, None)
+                if want_crcs:
+                    checksum(item, tile, rebuilt)
+                t2 = time.perf_counter()
+                write(fds, item, tile, rebuilt)
+                t3 = time.perf_counter()
+                busy["read_s"] += t1 - t0
+                busy["compute_s"] += t2 - t1
+                busy["write_s"] += t3 - t2
+            ok = True
         finally:
             tc0 = time.perf_counter()
-            fsync_err: OSError | None = None
+            fsync_err = _settle_outputs(
+                fds, [path for path, _ in outputs], durable, ok
+            )
             try:
-                for fds in out_fds:
-                    for fd in fds.values():
-                        try:
-                            if durable and ok and not pipe.errors:
-                                try:
-                                    os.fsync(fd)
-                                except OSError as e:
-                                    if fsync_err is None:
-                                        fsync_err = e
-                            os.close(fd)
-                        except OSError:
-                            pass
-                if not ok or pipe.errors or fsync_err is not None:
-                    for base in bases:
-                        for tid in targets:
-                            try:
-                                os.remove(base + to_ext(tid))
-                            except OSError:
-                                pass
                 if fsync_err is not None:
                     raise fsync_err
             finally:
                 busy["flush_s"] = time.perf_counter() - tc0
+                out: dict = {}
+                _finish_stats(out, busy, wall0, 1, 1)
+                report(out, sp, ok and fsync_err is None)
+                out["host_inline"] = True
                 if stats is not None:
-                    _finish_stats(
-                        stats, busy, wall0, reader_threads, writer_threads
-                    )
-                    stats["pipeline_depth"] = depth
-                    stats["ring_slots"] = ring.slots
-                    stats["batch_volumes"] = b
-                    stats["codec_arm"] = "host"
-                    if (
-                        want_crcs
-                        and ok
-                        and not pipe.errors
-                        and fsync_err is None
-                    ):
-                        stats["shard_crcs"] = _fold_host_batch_crcs(
-                            b, targets, crc_parts
-                        )
-                _trace_stages(_sp, busy)
-                _sp.__exit__(*sys.exc_info())
+                    stats.update(out)
+                _trace_stages(sp, busy)
 
 
 def _fold_host_batch_crcs(
@@ -2892,27 +2388,4 @@ def _fold_host_batch_crcs(
     for v, off, step, crcs in sorted(crc_parts):
         for t, tid in enumerate(targets):
             out[v][tid] = crc32c_combine(out[v][tid], crcs[t], step)
-    return out
-
-
-def _fold_rebuild_batch_crcs(
-    b: int,
-    targets: tuple[int, ...],
-    step_of: list[list[int]],
-    round_crcs: list,
-) -> list[dict[int, int]]:
-    """Per-volume {rebuilt shard id: whole-file CRC} from the writer
-    pool's per-round records, folded in round order."""
-    from seaweedfs_tpu.util.crc import crc32c_combine
-
-    out = []
-    for v in range(b):
-        acc = {tid: 0 for tid in targets}
-        for r, vol_crcs in enumerate(round_crcs):
-            step = step_of[r][v]
-            if not step or vol_crcs is None or vol_crcs[v] is None:
-                continue
-            for t, tid in enumerate(targets):
-                acc[tid] = crc32c_combine(acc[tid], vol_crcs[v][t], step)
-        out.append(acc)
     return out

@@ -190,3 +190,60 @@ def corrupt_needle_data(volume, needle_id: int, xor: int = 0x5A) -> tuple[str, i
     offset = nv.actual_offset + t.NEEDLE_HEADER_SIZE + 4
     orig = flip_byte(dat_path, offset, xor)
     return dat_path, offset, orig
+
+
+# --- leak checks for the EC stream pipeline ----------------------------------
+# Counted by what the pipeline itself names and by where its files live:
+# under xdist a worker's other tests start lazy threads and open sockets
+# of their own, so a process-wide count before and after says nothing.
+
+EC_STREAM_THREAD_PREFIX = "ec-stream-"
+
+
+def ec_stream_threads() -> list[str]:
+    """Names of the live pool threads of ec_stream's pipeline shell."""
+    import threading
+
+    return [
+        th.name
+        for th in threading.enumerate()
+        if th.name.startswith(EC_STREAM_THREAD_PREFIX)
+    ]
+
+
+def fds_under(root) -> list[str]:
+    """Paths under `root` that this process holds an open fd on."""
+    root = os.path.realpath(str(root))
+    held = []
+    for name in os.listdir("/proc/self/fd"):
+        try:
+            path = os.readlink(f"/proc/self/fd/{name}")
+        except OSError:  # closed since the listing (its own fd among them)
+            continue
+        if path == root or path.startswith(root + os.sep):
+            held.append(path)
+    return held
+
+
+def ec_shards_less(
+    base: str, nbytes: int, seed: int, lost, large: int, small: int
+) -> None:
+    """A seeded `.dat` and its whole shard set from the classic loop
+    (made once), with the `lost` shards gone (again): what a rebuild
+    driver starts from."""
+    import numpy as np
+
+    from seaweedfs_tpu.ec import ec_files
+    from seaweedfs_tpu.ec.codec import new_encoder
+
+    if not os.path.exists(base + ".dat"):
+        rng = np.random.default_rng(seed)
+        with open(base + ".dat", "wb") as f:
+            f.write(rng.integers(0, 256, nbytes, dtype=np.uint8).tobytes())
+        ec_files.write_ec_files(
+            base, rs=new_encoder(backend="cpu"), large_block_size=large,
+            small_block_size=small,
+        )
+    for i in lost:
+        if os.path.exists(base + ec_files.to_ext(i)):
+            os.remove(base + ec_files.to_ext(i))

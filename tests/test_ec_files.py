@@ -359,7 +359,6 @@ class TestStreamDrivers:
     def test_stream_write_stage_error_propagates(self, tmp_path):
         """A kernel-stage failure mid-stream must raise on the caller
         (not hang the reader/writer threads and not leave them alive)."""
-        import threading
 
         import numpy as np
         import pytest as _pytest
@@ -378,12 +377,8 @@ class TestStreamDrivers:
                 raise RuntimeError("kernel died")
             return np.zeros((4, tile.shape[1]), dtype=np.uint8)
 
-        # warm the lazy trace-drainer thread before the leak baseline
-        from seaweedfs_tpu import trace
+        from tests.faults import ec_stream_threads
 
-        with trace.span("warmup"):
-            pass
-        before = threading.active_count()
         with _pytest.raises(RuntimeError, match="kernel died"):
             ec_stream.stream_write_ec_files(
                 str(tmp_path / "1"),
@@ -393,7 +388,7 @@ class TestStreamDrivers:
                 parity_fn=parity_fn,
                 fetch_fn=lambda h: h,
             )
-        assert threading.active_count() <= before  # stage threads joined
+        assert not ec_stream_threads()  # stage threads joined
 
     def test_stream_write_pool_identical_odd_sizes(self, tmp_path):
         """The pwritev writer POOL lands tiles in completion order —
@@ -443,7 +438,6 @@ class TestStreamDrivers:
         fd (the .dat readers and all 14 preallocated shard fds)."""
         import errno
         import os
-        import threading
 
         import numpy as np
         import pytest as _pytest
@@ -464,15 +458,11 @@ class TestStreamDrivers:
             return real_pwritev(fd, bufs, offset)
 
         monkeypatch.setattr(ec_stream, "_pwritev_full", flaky_pwritev)
-        # the first completed span in a process starts the trace
-        # drainer thread lazily — warm it so the leak check below
-        # counts only pool threads
-        from seaweedfs_tpu import trace
+        # leaks are counted by the pipeline's thread names and by this
+        # test's own directory: an xdist worker's other threads and
+        # sockets come and go
+        from tests.faults import ec_stream_threads, fds_under
 
-        with trace.span("warmup"):
-            pass
-        fds_before = len(os.listdir("/proc/self/fd"))
-        threads_before = threading.active_count()
         with _pytest.raises(OSError, match="No space left"):
             ec_stream.stream_write_ec_files(
                 str(tmp_path / "1"),
@@ -484,8 +474,8 @@ class TestStreamDrivers:
                 writer_threads=3,
                 reader_threads=2,
             )
-        assert threading.active_count() <= threads_before
-        assert len(os.listdir("/proc/self/fd")) == fds_before
+        assert not ec_stream_threads()
+        assert not fds_under(tmp_path)
         # the trace span must record the failure: an aborted encode
         # that looks clean in /debug/traces would hide exactly the
         # repair-path behavior the tracing plane exists to attribute
@@ -510,7 +500,6 @@ class TestStreamDrivers:
     def test_stream_rebuild_enospc_abort_no_leaks(self, tmp_path, monkeypatch):
         import errno
         import os
-        import threading
 
         import numpy as np
         import pytest as _pytest
@@ -536,13 +525,8 @@ class TestStreamDrivers:
             raise OSError(errno.ENOSPC, "No space left on device")
 
         monkeypatch.setattr(ec_stream, "_pwrite_full", broken_pwrite)
-        # warm the lazy trace-drainer thread before the leak baseline
-        from seaweedfs_tpu import trace
+        from tests.faults import ec_stream_threads, fds_under
 
-        with trace.span("warmup"):
-            pass
-        fds_before = len(os.listdir("/proc/self/fd"))
-        threads_before = threading.active_count()
         with _pytest.raises(OSError, match="No space left"):
             ec_stream.stream_rebuild_ec_files(
                 base,
@@ -552,8 +536,8 @@ class TestStreamDrivers:
                 writer_threads=2,
                 reader_threads=2,
             )
-        assert threading.active_count() <= threads_before
-        assert len(os.listdir("/proc/self/fd")) == fds_before
+        assert not ec_stream_threads()
+        assert not fds_under(tmp_path)
         # the half-written target was removed (a retry must see it as
         # still missing), the survivors untouched
         assert not os.path.exists(base + ec_files.to_ext(2))

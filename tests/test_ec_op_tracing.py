@@ -1,5 +1,6 @@
-"""Where one encode operation's time goes (ISSUE 26, docs/TRACING.md):
-the serial phases of the two encode drivers partition the wall, the
+"""Where one EC operation's time goes (ISSUE 26, docs/TRACING.md; every
+stream driver since ISSUE 30, whose one pipeline shell takes the
+boundaries): the serial phases of a driver partition the wall, the
 device stage's H2D / launch split reconciles with the pool stages it
 refines, the spans and profiler annotations carry the same
 names, and the node's one report line per operation carries all of it.
@@ -25,6 +26,7 @@ from seaweedfs_tpu.pb import rpc, volume_pb2
 from seaweedfs_tpu.server.master_server import MasterServer
 from seaweedfs_tpu.server.volume_server import VolumeServer
 from seaweedfs_tpu.util.availability import free_port
+from tests.faults import ec_shards_less
 
 LARGE = 64 * 1024
 SMALL = 16 * 1024
@@ -69,10 +71,55 @@ def _encode_batch(tmp_path, volumes: int = 4, rows: int = 3) -> dict:
     return stats
 
 
+LOST = (3, 12)
+
+
+def _lose(base: str, nbytes: int, seed: int) -> None:
+    ec_shards_less(base, nbytes, seed, LOST, LARGE, SMALL)
+
+
+def _rebuild_single(tmp_path, host_pair: bool) -> dict:
+    base = str(tmp_path / "r")
+    _lose(base, 10 * SMALL * 6 + 77, seed=7)
+    fns = {}
+    if host_pair:
+        fns["rebuild_fn"], fns["fetch_fn"] = ec_stream.local_rebuild_fns(
+            new_encoder(backend="cpu"), want_crcs=True
+        )
+    trace.reset()  # the spans below are the rebuild's alone
+    stats: dict = {}
+    rebuilt = ec_stream.stream_rebuild_ec_files(
+        base, tile_bytes=SMALL, stats=stats, want_crcs=True, **fns
+    )
+    assert rebuilt == list(LOST)
+    return stats
+
+
+def _rebuild_batch(tmp_path, mesh: bool, volumes: int = 2) -> dict:
+    bases = [str(tmp_path / f"rb{i}") for i in range(volumes)]
+    for i, base in enumerate(bases):
+        _lose(base, 10 * SMALL * 6 + i, seed=20 + i)
+    # the CPU's default is the host arm; its tiles are fine enough here
+    # for more than _HOST_INLINE_TILES work items, so it runs its pools
+    codec = ec_stream._default_mesh_codec(volumes) if mesh else None
+    trace.reset()
+    stats: dict = {}
+    rebuilt = ec_stream.stream_rebuild_ec_files_batch(
+        bases, codec=codec, tile_bytes=SMALL // 2, stats=stats, want_crcs=True
+    )
+    assert rebuilt == [list(LOST)] * volumes and "host_inline" not in stats
+    assert ("mesh" in stats) == mesh
+    return stats
+
+
 DRIVERS = {
     "single-host-pair": lambda p: _encode_single(p, host_pair=True),
     "single-device": lambda p: _encode_single(p, host_pair=False),
     "batch": _encode_batch,
+    "rebuild-host-pair": lambda p: _rebuild_single(p, host_pair=True),
+    "rebuild-device": lambda p: _rebuild_single(p, host_pair=False),
+    "rebuild-batch-host": lambda p: _rebuild_batch(p, mesh=False),
+    "rebuild-batch-mesh": lambda p: _rebuild_batch(p, mesh=True),
 }
 
 
@@ -101,6 +148,9 @@ def test_phases_partition_the_wall(driver, tmp_path):
     )
     assert stats["dispatch_span_s"] > 0
     assert "loop_s" not in stats and "overlap_s" not in stats
+    # every driver's files are reserved by its writer pool
+    assert stats["reserve_s"] > 0
+    assert 0 < stats["reserve_done_s"] <= stats["wall_s"]
 
 
 def test_phases_partition_an_aborted_operation(tmp_path):
@@ -210,12 +260,21 @@ def test_host_stage_pairs_book_no_device_field(tmp_path):
 # --- spans --------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("driver", ["single-device", "batch"])
+ROOTS = {
+    "single-device": "ec_stream.encode",
+    "batch": "ec_stream.encode_batch",
+    "rebuild-device": "ec_stream.rebuild",
+    "rebuild-batch-host": "ec_stream.rebuild_batch",
+    "rebuild-batch-mesh": "ec_stream.rebuild_batch",
+}
+
+
+@pytest.mark.parametrize("driver", sorted(ROOTS))
 def test_phase_spans_hang_off_the_drivers_root(driver, tmp_path, ring):
     stats = DRIVERS[driver](tmp_path)
     spans = _recent()
-    root = [s for s in spans if s["name"].startswith("ec_stream.encode")]
-    assert len(root) == 1
+    root = [s for s in spans if s["name"].startswith("ec_stream.")]
+    assert [s["name"] for s in root] == [ROOTS[driver]]
     phases = {s["name"]: s for s in spans if s["name"] in PHASE_SPANS}
     assert set(phases) == set(PHASE_SPANS)
     for name, field in ec_stream._OP_PHASES.items():

@@ -188,13 +188,17 @@ class TestPipelinedEncode:
             assert stats["shard_crcs"][i] == crc32c(sb)
 
     def test_depth_knob(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("WEED_EC_PIPELINE_DEPTH", "2")
-        assert ec_stream.pipeline_depth() == 2
-        monkeypatch.setenv("WEED_EC_PIPELINE_DEPTH", "1")
-        assert ec_stream.pipeline_depth() == 2  # min 2: double buffering
-        monkeypatch.setenv("WEED_EC_PIPELINE_DEPTH", "junk")
-        assert ec_stream.pipeline_depth() == 3
-        monkeypatch.setenv("WEED_EC_PIPELINE_DEPTH", "4")
+        """The window is the constant _INFLIGHT: the write queue holds
+        that many dispatched tiles and the ring one slot more than the
+        window plus the writer pool."""
+        queues: list[int] = []
+        real_queue = ec_stream.queue.Queue
+
+        def spy(maxsize=0):
+            queues.append(maxsize)
+            return real_queue(maxsize)
+
+        monkeypatch.setattr(ec_stream.queue, "Queue", spy)
         rs = new_encoder(backend="cpu")
         base = str(tmp_path / "v")
         _make_dat(base, 10 * SMALL * 2)
@@ -203,8 +207,13 @@ class TestPipelinedEncode:
         ec_stream.stream_write_ec_files(
             base, large_block_size=LARGE, small_block_size=SMALL,
             parity_fn=parity_fn, fetch_fn=fetch_fn, stats=stats,
+            writer_threads=4, reader_threads=2,
         )
-        assert stats["pipeline_depth"] == 4
+        assert ec_stream._INFLIGHT == 3
+        assert stats["pipeline_depth"] == ec_stream._INFLIGHT
+        assert stats["ring_slots"] == ec_stream._INFLIGHT + 4 + 1
+        # read queue (one per reader), write queue, the ring's free list
+        assert queues == [2, ec_stream._INFLIGHT, 0]
 
     def test_kill_switch_routes_serial(self, tmp_path, monkeypatch):
         """WEED_EC_PIPELINE=0 restores the classic loop wholesale:

@@ -1,11 +1,13 @@
-"""The shard files' reservation runs in the writer pool (ISSUE 29,
-docs/CODEC.md "Reserving the shard files"): both encode drivers open the
-shard files on the handler's thread, the writer threads preallocate them
-from one shared iterator beside the first reads, and a latch keeps every
-shard write behind the last reservation.
+"""The output files' reservation runs in the writer pool (ISSUE 29,
+docs/CODEC.md "Reserving the shard files"; every driver since ISSUE 30,
+whose one pipeline shell owns it): the shell opens the files on the
+handler's thread, the writer threads preallocate them from one shared
+iterator beside the first reads, and a latch keeps every shard write
+behind the last reservation.
 
 Host arm under JAX_PLATFORMS=cpu; each check runs on the single-volume
-and on the batch driver. What is asserted is order, counts, errors and
+and the batch driver of encode and of rebuild. What is asserted is
+order, counts, errors and
 bookkeeping, never a device time. Every driver call carries a time
 limit of its own: a writer parked on the latch would otherwise hang the
 run, not fail it."""
@@ -21,6 +23,7 @@ import pytest
 
 from seaweedfs_tpu.ec import ec_files, ec_stream
 from seaweedfs_tpu.ec.codec import new_encoder
+from tests.faults import ec_shards_less, ec_stream_threads, fds_under
 
 LARGE = 64 * 1024
 SMALL = 16 * 1024
@@ -63,14 +66,63 @@ def _batch(tmp_path, stats: dict) -> list[str]:
     return bases
 
 
-DRIVERS = {"single": _single, "batch": _batch}
-# the function through which each driver's reader pool reads the .dat
-READ_FN = {"single": "_pread_into", "batch": "_read_tile_into"}
+LOST = (2, 11)  # what the rebuild drivers rebuild: a data and a parity shard
 
 
-def _bases(driver: str, tmp_path) -> list[str]:
-    names = {"single": ["v"], "batch": ["b0", "b1"]}[driver]
-    return [str(tmp_path / n) for n in names]
+def _lose(base: str, nbytes: int, seed: int) -> None:
+    ec_shards_less(base, nbytes, seed, LOST, LARGE, SMALL)
+
+
+def _rebuild(tmp_path, stats: dict) -> list[str]:
+    base = str(tmp_path / "r")
+    _lose(base, 10 * SMALL * 12 + 77, seed=30)
+    rebuild_fn, fetch_fn = ec_stream.local_rebuild_fns(
+        new_encoder(backend="cpu"), want_crcs=True
+    )
+    rebuilt = ec_stream.stream_rebuild_ec_files(
+        base, tile_bytes=SMALL, rebuild_fn=rebuild_fn, fetch_fn=fetch_fn,
+        stats=stats, want_crcs=True, writer_threads=3, reader_threads=2,
+    )
+    assert rebuilt == list(LOST)
+    return [base]
+
+
+def _rebuild_batch(tmp_path, stats: dict) -> list[str]:
+    bases = [str(tmp_path / f"rb{i}") for i in range(2)]
+    for i, base in enumerate(bases):
+        _lose(base, 10 * SMALL * (6 + 3 * i) + i, seed=40 + i)
+    # tiles fine enough for more than _HOST_INLINE_TILES work items: the
+    # host arm then runs its pools, through the shell
+    rebuilt = ec_stream.stream_rebuild_ec_files_batch(
+        bases, tile_bytes=SMALL // 2, stats=stats, want_crcs=True,
+        writer_threads=3, reader_threads=2,
+    )
+    assert rebuilt == [list(LOST)] * 2 and "host_inline" not in stats
+    return bases
+
+
+DRIVERS = {
+    "single": _single, "batch": _batch,
+    "rebuild": _rebuild, "rebuild_batch": _rebuild_batch,
+}
+# the function through which each driver's reader pool reads its input
+READ_FN = {
+    "single": "_pread_into", "batch": "_read_tile_into",
+    "rebuild": "_pread_into", "rebuild_batch": "_pread_into",
+}
+NAMES = {
+    "single": ["v"], "batch": ["b0", "b1"],
+    "rebuild": ["r"], "rebuild_batch": ["rb0", "rb1"],
+}
+
+
+def _outputs(driver: str, tmp_path) -> list[str]:
+    """The files the driver's operation opens, reserves and writes."""
+    ids = LOST if driver.startswith("rebuild") else range(ec_files.TOTAL_SHARDS)
+    return [
+        str(tmp_path / name) + ec_files.to_ext(i)
+        for name in NAMES[driver] for i in ids
+    ]
 
 
 def _within(seconds: float, fn, *args):
@@ -138,11 +190,8 @@ class _Syscalls:
             return sum(1 for k, _, _ in self.events if k == kind)
 
 
-def _no_shard_left(bases: list[str]) -> bool:
-    return not any(
-        os.path.exists(base + ec_files.to_ext(i))
-        for base in bases for i in range(ec_files.TOTAL_SHARDS)
-    )
+def _no_output_left(driver: str, tmp_path) -> bool:
+    return not any(os.path.exists(p) for p in _outputs(driver, tmp_path))
 
 
 # --- (1) order and counts -----------------------------------------------------
@@ -157,23 +206,24 @@ def test_no_shard_byte_is_written_before_the_last_reservation(
     # stand at the latch, not write
     (tmp_path / "warm").mkdir()
     _within(60, DRIVERS[driver], tmp_path / "warm", {})
+    if driver.startswith("rebuild"):
+        _within(60, DRIVERS[driver], tmp_path, {})  # the shard sets, unrecorded
     calls = _Syscalls(monkeypatch, before_reserve=lambda n: time.sleep(0.01))
-    bases = _within(60, DRIVERS[driver], tmp_path, {})
+    _within(60, DRIVERS[driver], tmp_path, {})
     kinds = [k for k, _, _ in calls.events]
     last_reserve = max(i for i, k in enumerate(kinds) if k == "reserve")
     first_write = kinds.index("write")
     assert last_reserve < first_write
     # once per file per operation, at the file's final size
     reserved = {p: s for k, p, s in calls.events if k == "reserve"}
-    assert kinds.count("reserve") == len(reserved) == 14 * len(bases)
-    for base in bases:
+    outputs = _outputs(driver, tmp_path)
+    assert kinds.count("reserve") == len(reserved) == len(outputs)
+    for path in outputs:
         want = ec_files.shard_file_size(
-            os.path.getsize(base + ".dat"), LARGE, SMALL
+            os.path.getsize(path[:-5] + ".dat"), LARGE, SMALL
         )
         assert want > 0
-        for i in range(ec_files.TOTAL_SHARDS):
-            path = base + ec_files.to_ext(i)
-            assert reserved[path] == want == os.path.getsize(path), path
+        assert reserved[path] == want == os.path.getsize(path), path
 
 
 # --- (2) ENOSPC from a pool thread --------------------------------------------
@@ -184,7 +234,7 @@ def test_no_shard_byte_is_written_before_the_last_reservation(
 def test_enospc_from_a_reservation_fails_before_any_write(
     driver, kth, tmp_path, monkeypatch
 ):
-    files = 14 * len(_bases(driver, tmp_path))
+    files = len(_outputs(driver, tmp_path))
     k = 1 if kth == "first" else files
     raised_on: list[str] = []
 
@@ -194,11 +244,10 @@ def test_enospc_from_a_reservation_fails_before_any_write(
             raised_on.append(threading.current_thread().name)
             raise OSError(errno.ENOSPC, "No space left on device")
 
-    # a clean run first: the JAX backend and the trace plane's drainer
-    # thread are up before the leak baseline is taken
-    (tmp_path / "warm").mkdir()
-    _within(60, DRIVERS[driver], tmp_path / "warm", {})
-    threads, fds = threading.active_count(), len(os.listdir("/proc/self/fd"))
+    # a clean run first: the programs are compiled, and a rebuild
+    # driver's shard sets are written before the recorder is on
+    _within(60, DRIVERS[driver], tmp_path, {})
+    assert not ec_stream_threads() and not fds_under(tmp_path)
     with monkeypatch.context() as patched:
         calls = _Syscalls(patched, before_reserve=full_disk)
         with pytest.raises(OSError) as err:
@@ -207,9 +256,11 @@ def test_enospc_from_a_reservation_fails_before_any_write(
     assert raised_on and raised_on[0] != HANDLER
     assert calls.count("write") == 0
     assert calls.count("reserve") < files
-    assert _no_shard_left(_bases(driver, tmp_path))
-    assert threading.active_count() <= threads
-    assert len(os.listdir("/proc/self/fd")) == fds
+    assert _no_output_left(driver, tmp_path)
+    # no pool thread and no fd of the operation outlives it (counted by
+    # the pipeline's own thread names and by this test's directory: an
+    # xdist worker's other threads and sockets come and go)
+    assert not ec_stream_threads() and not fds_under(tmp_path)
 
     # the next operation on the same files succeeds, byte for byte the
     # classic driver's
@@ -239,7 +290,9 @@ def test_reader_error_frees_writers_parked_on_the_latch(
     """One reservation is still in flight, the other writers have fetched
     a tile and stand at the latch, then a reader raises: the operation
     ends within two _Q_TICKs of the error, with the reader's error."""
-    files = 14 * len(_bases(driver, tmp_path))
+    files = len(_outputs(driver, tmp_path))
+    if driver.startswith("rebuild"):
+        _within(60, DRIVERS[driver], tmp_path, {})  # the shard sets, unpatched
     parked, reader_raised = threading.Event(), threading.Event()
     raised_at: list[float] = []
 
@@ -275,7 +328,7 @@ def test_reader_error_frees_writers_parked_on_the_latch(
     with pytest.raises(RuntimeError, match="read failed"):
         _within(30, DRIVERS[driver], tmp_path, {})
     assert time.perf_counter() - raised_at[0] < 2 * ec_stream._Q_TICK
-    assert _no_shard_left(_bases(driver, tmp_path))
+    assert _no_output_left(driver, tmp_path)
 
 
 # --- (4) what the operation books ---------------------------------------------
@@ -285,14 +338,36 @@ def test_reader_error_frees_writers_parked_on_the_latch(
 def test_reservation_is_booked_and_is_not_in_the_head(
     driver, tmp_path, monkeypatch
 ):
-    # 50 ms a file: on the handler's thread the head would hold 14 of them
-    _Syscalls(monkeypatch, before_reserve=lambda n: time.sleep(0.05))
+    """50 ms a file, and the LAST file's reservation does not begin until
+    the handler's thread has dispatched its first tile: a reservation on
+    the handler's thread (in the head) could never see that."""
+    from seaweedfs_tpu import trace
+
+    if driver.startswith("rebuild"):
+        _within(60, DRIVERS[driver], tmp_path, {})  # the shard sets, unpatched
+    files = len(_outputs(driver, tmp_path))
+    dispatched = threading.Event()
+    saw_dispatch: list[bool] = []
+    real_to = trace.Phases.to
+
+    def to(self, name, at=None):
+        if name == "ec.op.dispatch":
+            dispatched.set()
+        return real_to(self, name, at)
+
+    def slow(n):
+        if n == files:
+            saw_dispatch.append(dispatched.wait(20))
+        time.sleep(0.05)
+
+    monkeypatch.setattr(trace.Phases, "to", to)
+    _Syscalls(monkeypatch, before_reserve=slow)
     stats: dict = {}
-    bases = _within(60, DRIVERS[driver], tmp_path, stats)
-    files = 14 * len(bases)
+    _within(60, DRIVERS[driver], tmp_path, stats)
+    assert saw_dispatch == [True]
     assert stats["reserve_s"] >= 0.05 * files * 0.9  # thread-seconds of the pool
     assert 0.05 * files / 3 * 0.9 <= stats["reserve_done_s"] <= stats["wall_s"]
-    assert stats["head_s"] < 0.05
+    assert stats["head_s"] < stats["reserve_done_s"]
     assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
         stats["wall_s"], abs=3.5e-4
     )
