@@ -9,15 +9,33 @@ the same jitted program as the codec kernel, so a dispatch returns
 
 The trick is the same GF(2)-linearity the bitsliced codec kernels
 lean on: with the init/final-xor constants stripped, a CRC register is
-a linear function of the message bits, so
+a linear function of the message bits, so the raw CRC of a row of n
+uint32 lanes is ``XOR_i Z_{4(n-1-i)}(lane(x_i))`` — `lane` the [32,32]
+bit-matrix of one 4-byte message, Z_k the k-zero-byte register transit
+(util/crc) — and ANY grouping of that sum is valid. The fold groups it
+by blocks:
 
-  * the raw CRC of each uint32 LANE (4 stream bytes) is one
-    [N,32]x[32,32] bit-matmul against a constant lane matrix;
-  * adjacent chunks combine with `crc(A||B) = Z_|B|(crc(A)) ^ crc(B)`
-    where Z_k (the k-zero-byte register transit, util/crc) is another
-    [32,32] bit-matrix — log2(lanes) halving rounds reduce a whole row
-    to one register;
+  * the row is viewed as [K, L] (L = min(128, n) lanes, a reshape of a
+    contiguous row) and the raw CRC of every block is ONE int8 matmul:
+    the block's bits, [32, K, L], against a host-built [32, L, 32]
+    operator whose slice for lane l is `lane` composed with the shift
+    past the L-1-l lanes behind it (_block_bitmat). The contraction runs
+    over (bit, lane) with the lane minor, so each bit plane is an
+    elementwise shift of the [K, 128] tiles the row already lies in and
+    nothing is transposed;
+  * the K block CRCs combine by CONTIGUOUS halves,
+    `Z_{h*span}(c[:h]) ^ c[h:]` for h = K/2 .. 1 (`Z_a(u) ^ v` joins any
+    two partial sums whose spans lie a bytes apart): log2(K) rounds over
+    at most a few thousand words;
   * the init/final-xor constants re-enter as a single per-length XOR.
+
+No slice has a stride, and none may: halving the LANE axis with
+`c[..., 0::2]` / `c[..., 1::2]` (eighteen rounds for a 1 MiB row) is
+the same algebra, but a stride along the minor axis is a gather on the
+TPU — 72 of them in the lowering at [14, 262144], each a `kCustom`
+fusion that re-lays the tile out — and those gathers were the device's
+whole burst: 4.14 ms a 14 MiB tile against 0.233 ms for this form,
+where the SWAR kernel beside it takes 0.03 (PERF.md section 6, PR 31).
 
 Everything is ordinary XLA (int8 matmul + bit packing, the
 apply_matrix_bits idiom) — no Pallas, so it lowers on CPU and TPU with
@@ -26,7 +44,7 @@ bench --check pipeline-identity smoke enforce.
 
 Shape contract: lane counts must be a power of two (every stream tile
 the drivers dispatch is; odd tails fall back to the host table CRC in
-the driver).
+the driver). Leading axes pass through.
 """
 
 from __future__ import annotations
@@ -94,7 +112,32 @@ def _final_const(nbytes: int) -> int:
     ) ^ 0xFFFFFFFF if nbytes else 0
 
 
+# lanes folded by the block matmul: one (8, 128) tile row of uint32
+_BLOCK_LANES = 128
+
+
+@functools.lru_cache(maxsize=8)
+def _block_bitmat(lanes: int) -> np.ndarray:
+    """[32(bit), lanes, 32(out)] int8 operator taking the bits of a block
+    of `lanes` consecutive uint32 lanes to the block's raw CRC: slice l
+    is the lane matrix followed by Z past the lanes-1-l lanes behind."""
+    z4 = _shift_bitmat(4).astype(np.int32)
+    op = _bitmat(_lane_cols()).astype(np.int32)
+    out = np.empty((32, lanes, 32), dtype=np.int8)
+    for lane in range(lanes - 1, -1, -1):
+        out[:, lane, :] = op
+        op = (op @ z4) & 1
+    return out
+
+
 _BIT_IDX = np.arange(32, dtype=np.uint32)
+
+
+def _pack_bits(acc: jnp.ndarray) -> jnp.ndarray:
+    """[..., 32] int32 matmul sums -> [...] uint32 of their parities."""
+    return jnp.sum(
+        (acc & 1).astype(jnp.uint32) << jnp.asarray(_BIT_IDX), axis=-1
+    )
 
 
 def _apply_bits(x: jnp.ndarray, m_bits: jnp.ndarray) -> jnp.ndarray:
@@ -108,7 +151,33 @@ def _apply_bits(x: jnp.ndarray, m_bits: jnp.ndarray) -> jnp.ndarray:
         (((bits.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    return jnp.sum((acc & 1).astype(jnp.uint32) << shifts, axis=-1)
+    return _pack_bits(acc)
+
+
+def _block_crcs(x_u32: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    """[..., n32] uint32 -> [..., n32 // lanes] raw CRC of each block of
+    `lanes` consecutive lanes: bit planes [..., 32, K, lanes] against
+    _block_bitmat, contracted over (bit, lane) in one dot_general."""
+    blocks = x_u32.reshape(x_u32.shape[:-1] + (-1, lanes))
+    shifts = jnp.asarray(_BIT_IDX)[:, None, None]
+    bits = ((blocks[..., None, :, :] >> shifts) & jnp.uint32(1)).astype(jnp.int8)
+    acc = jax.lax.dot_general(
+        bits,
+        jnp.asarray(_block_bitmat(lanes)),
+        (((bits.ndim - 3, bits.ndim - 1), (0, 1)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )
+    return _pack_bits(acc)
+
+
+def _fold_halves(c: jnp.ndarray, span: int) -> jnp.ndarray:
+    """[..., K] raw CRCs of K consecutive segments of `span` bytes ->
+    [...] raw CRC of the whole: K halves with Z over the half behind."""
+    while c.shape[-1] > 1:
+        h = c.shape[-1] // 2
+        z = jnp.asarray(_shift_bitmat(h * span))
+        c = _apply_bits(c[..., :h], z) ^ c[..., h:]
+    return c[..., 0]
 
 
 def crc_lin_rows(x_u32: jnp.ndarray) -> jnp.ndarray:
@@ -119,13 +188,8 @@ def crc_lin_rows(x_u32: jnp.ndarray) -> jnp.ndarray:
     n32 = x_u32.shape[-1]
     if n32 & (n32 - 1):
         raise ValueError(f"lane count {n32} is not a power of two")
-    c = _apply_bits(x_u32, jnp.asarray(_bitmat(_lane_cols())))
-    span = 4  # bytes covered by each element of c
-    while c.shape[-1] > 1:
-        m = jnp.asarray(_shift_bitmat(span))
-        c = _apply_bits(c[..., 0::2], m) ^ c[..., 1::2]
-        span *= 2
-    return c[..., 0]
+    lanes = min(_BLOCK_LANES, n32)
+    return _fold_halves(_block_crcs(x_u32, lanes), 4 * lanes)
 
 
 def finalize_rows(lin: jnp.ndarray, nbytes: int) -> jnp.ndarray:

@@ -295,7 +295,7 @@ class MeshCodec:
     def crc_supported(self, n_bytes: int) -> bool:
         """True when the fused Castagnoli pass serves streams of
         n_bytes: whole u32 lanes per device, power-of-two lane count
-        (ec/crc_kernel.py's halving reduction)."""
+        (ec/crc_kernel.py's block fold and ladder of halves)."""
         from seaweedfs_tpu.ec import crc_kernel
 
         stripe = self.mesh.shape[STRIPE_AXIS]

@@ -71,6 +71,73 @@ class TestCrcKernel:
             for j in range(5):
                 assert int(got[i, j]) == crc32c(x[i, j].tobytes())
 
+    @pytest.mark.parametrize("fill", ["zeros", "ones", "random"])
+    @pytest.mark.parametrize("lead", [(14,), (4, 14), (3, 14)])
+    @pytest.mark.parametrize("n32", [1, 2, 64, 128, 256, 4096, 131072])
+    def test_rows_match_host_crc32c_every_dispatched_shape(self, n32, lead, fill):
+        """Every shape a cell or a rebuild dispatches, on both sides of
+        the block width (128 lanes), against the host's table CRC."""
+        import jax
+
+        from seaweedfs_tpu.ec import crc_kernel
+
+        if fill == "random":
+            rng = np.random.default_rng(n32 + len(lead))
+            x = rng.integers(0, 2**32, lead + (n32,), dtype=np.uint32)
+        else:
+            word = 0 if fill == "zeros" else 0xFFFFFFFF
+            x = np.full(lead + (n32,), word, dtype=np.uint32)
+        got = np.asarray(jax.jit(crc_kernel.crc32c_rows)(x))  # as the programs run it
+        assert got.shape == lead and got.dtype == np.uint32
+        rows = x.reshape(-1, n32)
+        want = [crc32c(rows[r].tobytes()) for r in range(rows.shape[0])]
+        assert got.reshape(-1).tolist() == want
+
+    def test_fold_lowers_without_gather_or_strided_slice(self):
+        """The mechanism, where no speed can be read: at the encode tile
+        [14, 262144] the fold's lowering holds no gather and no slice
+        with a stride (the strided halving it replaced held 72 gathers;
+        on the TPU each re-lays the tile out)."""
+        import re
+
+        import jax
+
+        from seaweedfs_tpu.ec import crc_kernel
+
+        x = jax.ShapeDtypeStruct((14, 262144), np.uint32)
+        text = jax.jit(crc_kernel.crc_lin_rows).lower(x).as_text()
+        assert "gather" not in text
+        slices = [ln for ln in text.splitlines() if "stablehlo.slice" in ln]
+        assert len(slices) == 2 * 11 + 1  # the ladder's halves, and c[..., 0]
+        for ln in slices:  # `[0:14, 0:1024]`; a stride prints as a third field
+            bounds = re.search(r"\[([^\]]*)\]", ln).group(1)
+            assert all(dim.count(":") == 1 for dim in bounds.split(",")), ln
+        # one block contraction + eleven ladder rounds
+        assert text.count("stablehlo.dot_general") == 12
+
+    @pytest.mark.parametrize("n32", [64, 128, 1024, 65536])
+    def test_block_operator_and_ladder_match_host_combine(self, n32):
+        """The block matmul gives the raw CRC of each block of lanes, and
+        the ladder over them is the host's crc32c_combine over the same
+        split, block after block."""
+        from seaweedfs_tpu.ec import crc_kernel
+
+        lanes = min(crc_kernel._BLOCK_LANES, n32)
+        rng = np.random.default_rng(n32)
+        x = rng.integers(0, 2**32, (3, n32), dtype=np.uint32)
+        blocks = np.asarray(crc_kernel._block_crcs(x, lanes))
+        assert blocks.shape == (3, n32 // lanes)
+        for r in range(3):
+            parts = x[r].reshape(-1, lanes)
+            folded = 0  # crc32c(b"")
+            for k in range(parts.shape[0]):
+                part = parts[k].tobytes()
+                assert int(blocks[r, k]) == crc_kernel._raw_transit(part, 0)
+                folded = crc32c_combine(folded, crc32c(part), len(part))
+            lin = crc_kernel._fold_halves(blocks[r], 4 * lanes)
+            got = int(np.asarray(crc_kernel.finalize_rows(lin, n32 * 4)))
+            assert got == folded == crc32c(x[r].tobytes())
+
     def test_non_power_of_two_rejected(self):
         from seaweedfs_tpu.ec import crc_kernel
 

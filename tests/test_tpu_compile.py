@@ -258,6 +258,35 @@ def test_mesh_encode_batch_u32_crc_names(topo):
     assert _scoped(gather, "ec.crc_gather")
 
 
+def _relayouts(text: str) -> list[str]:
+    """Compiled instructions that re-lay a tile out for the CRC fold: a
+    gather, or the `kCustom` fusion the chip's compiler wraps one in
+    (the Pallas kernels are `custom-call`s, not fusions)."""
+    return [
+        ln.strip()[:120]
+        for ln in text.splitlines()
+        if " gather(" in ln or "kind=kCustom" in ln
+    ]
+
+
+def test_fused_programs_hold_no_gather(topo, one_chip, on_tpu):
+    """The fold by blocks, as the chip's compiler sees it: neither the
+    single-volume program nor the mesh program (2x2, both axes) holds a
+    gather. The strided halving it replaced compiled to 34 `kCustom`
+    gather fusions a program, the device's whole burst (PERF.md, PR 31)."""
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+
+    text = _compiled_text(
+        TpuCodecKernels().encode_u32_crc, _u32((10, TILE_LANES), one_chip)
+    )
+    assert _relayouts(text) == []
+    codec, sharding = _mesh_codec(topo, 2, 2)
+    text = _compiled_text(
+        codec.encode_batch_u32_crc, _u32((6, 10, TILE_LANES), sharding)
+    )
+    assert _relayouts(text) == []
+
+
 # --- the kept programs themselves ----------------------------------------------
 
 
