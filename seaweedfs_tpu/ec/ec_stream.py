@@ -447,11 +447,12 @@ _OP_PHASES = {
     "ec.op.flush": "flush_s",
 }
 
-# What only the encode drivers' device stages book, beside the pool
-# stages and in thread-seconds like them: the dispatcher's time in the
-# transfer call and in the jitted call. In the single-volume driver
-# h2d_s + launch_s is device_s; the batch driver's transfer is part of
-# its stage_s and launch_s == device_s. Host stage pairs book neither.
+# What the device stages book, beside the pool stages and in
+# thread-seconds like them: the dispatcher's time in the transfer call
+# and in the jitted call. In the single-volume drivers (encode and
+# rebuild) h2d_s + launch_s is device_s; the batch encode driver's
+# transfer is part of its stage_s and launch_s == device_s. Host stage
+# pairs and the batch rebuild's mesh stage book neither.
 _DEVICE_BUSY = {"h2d_s": 0.0, "launch_s": 0.0}
 
 # What every operation books for its output files' reservation
@@ -1076,9 +1077,11 @@ def stream_rebuild_ec_files(
     if (rebuild_fn is None) != (fetch_fn is None):
         raise ValueError("rebuild_fn and fetch_fn must be injected together")
     device_stage = rebuild_fn is None
-    op = _Op("ec_stream.rebuild", device_stage)
+    op = _Op(
+        "ec_stream.rebuild", device_stage, _DEVICE_BUSY if device_stage else None
+    )
     if device_stage:
-        rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs)
+        rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs, book=op.book)
     # rebuild tiles read one span from each of 10 FILES. Re-swept with
     # the staging ring (BENCH_r12): LOCAL rebuilds want fine tiles —
     # 512 KiB ran 3.7 GB/s vs 1.9 at the old 2 MiB (more in-flight
@@ -1122,6 +1125,10 @@ def stream_rebuild_ec_files(
     # pool, folded into whole-file CRCs after the join (append is
     # GIL-atomic; order restored by sorting on offset)
     crc_ranges: list[tuple[int, int, list[int]]] = []
+    # survivor bytes of every gather, from the reader pool (append is
+    # GIL-atomic too), and the dispatcher's own count of its launches
+    gathered: list[int] = []
+    launches = 0
     n_remote = sum(1 for i in survivors if not present[i])
     read_local = EC_REPAIR_BYTES_READ.labels("local")
     read_remote = EC_REPAIR_BYTES_READ.labels("remote")
@@ -1184,6 +1191,7 @@ def stream_rebuild_ec_files(
                 raise ValueError(
                     f"ec shard {i} truncated: expected {g_len} at {g_off}"
                 )
+        gathered.append(DATA_SHARDS * g_len)
         return tile
 
     def claim(offset):
@@ -1213,6 +1221,7 @@ def stream_rebuild_ec_files(
         return parts
 
     def dispatch(item, parts):
+        nonlocal launches
         t0 = time.perf_counter()
         parts = [
             (
@@ -1223,6 +1232,7 @@ def stream_rebuild_ec_files(
             for kind, off, payload in parts
         ]
         op.book("device_s", time.perf_counter() - t0)
+        launches += sum(1 for kind, _, _ in parts if kind == "h")
         return parts
 
     def fetch(item, _parts, parts):
@@ -1266,6 +1276,10 @@ def stream_rebuild_ec_files(
             out["arms"] = dict(rebuild_fn.arms)
         if want_crcs and whole:
             out["shard_crcs"] = _fold_rebuild_crcs(targets, crc_ranges)
+        shape = _rebuild_shape(launches, survivors, targets, sum(gathered))
+        out.update(shape)
+        for key, value in shape.items():
+            sp.annotate(key, value)
         if session is not None:
             out["donated_bytes"] = session.donated_bytes
             out["used_donated_bytes"] = session.used_donated_bytes
@@ -1493,15 +1507,16 @@ def _tpu_encode_fns(want_crcs: bool, book: Callable[[str, float], None]):
     return parity_fn, _fetch
 
 
-def _tpu_rebuild_fns(want_crcs: bool = False):
+def _tpu_rebuild_fns(want_crcs: bool, book: Callable[[str, float], None]):
     """(rebuild_fn, fetch_fn) on the attached device, over the same kept
-    programs as _tpu_encode_fns."""
+    programs as _tpu_encode_fns and booking the same device fields."""
     import jax.numpy as jnp
 
     progs = _device_programs()
     arms = _new_arms()
 
     def rebuild_fn(survivors, targets, tile: np.ndarray):
+        t0 = time.perf_counter()
         swar = _swar_ok(tile.shape[1])
         fused_crc = swar and _crc_ok(tile.shape[1], want_crcs)
         if fused_crc:
@@ -1510,9 +1525,15 @@ def _tpu_rebuild_fns(want_crcs: bool = False):
             arm, program = "swar", progs.reconstruct_u32
         else:
             arm, program = "bit-matmul", progs.reconstruct
-        dev = jnp.asarray(tile.view(np.uint32) if swar else tile)
-        out = program(tuple(survivors), tuple(targets), dev)
+        with trace.annotation("ec.h2d"):
+            dev = jnp.asarray(tile.view(np.uint32) if swar else tile)
+        t1 = time.perf_counter()
+        with trace.annotation("ec.launch"):
+            out = program(tuple(survivors), tuple(targets), dev)
+        t2 = time.perf_counter()
         arms[arm] += 1
+        book("h2d_s", t1 - t0)
+        book("launch_s", t2 - t1)
         return out, swar, fused_crc
 
     rebuild_fn.arms = arms
@@ -2077,7 +2098,7 @@ def stream_rebuild_ec_files_batch(
             for k, v in chunk_stats.items():
                 if isinstance(v, float):
                     summed[k] = round(summed.get(k, 0.0) + v, 4)
-                elif k == "program_traces":
+                elif k in ("program_traces", "tiles", "survivor_bytes"):
                     summed[k] = summed.get(k, 0) + v
                 elif k != "shard_crcs":
                     last_struct[k] = v
@@ -2091,6 +2112,19 @@ def stream_rebuild_ec_files_batch(
                 crcs_by_vol.get(i, {}) for i in range(len(base_file_names))
             ]
     return results
+
+
+def _rebuild_shape(tiles: int, survivors, targets, survivor_bytes: int) -> dict:
+    """The shape of one rebuild on its report line: decode tiles
+    dispatched (rounds or work items in the batch drivers), the survivor
+    set and the targets by count, and the survivor bytes gathered, local
+    and remote: what weed_ec_repair_bytes_read_total took."""
+    return {
+        "tiles": tiles,
+        "survivors": len(survivors),
+        "targets": len(targets),
+        "survivor_bytes": survivor_bytes,
+    }
 
 
 def _rebuild_batch_chunk(
@@ -2212,6 +2246,9 @@ def _rebuild_batch_chunk(
     def report(out, sp, whole):
         out["batch_volumes"] = b
         out["mesh"] = {**codec.report(), "devices_per_round": held}
+        out.update(
+            _rebuild_shape(rounds, survivors, targets, DATA_SHARDS * sum(sizes))
+        )
         if want_crcs and whole:
             out["shard_crcs"] = _fold_round_crcs(
                 b, targets, step_of, round_crcs
@@ -2307,6 +2344,11 @@ def _rebuild_batch_chunk_host(
     def report(out, sp, whole):
         out["batch_volumes"] = b
         out["codec_arm"] = "host"
+        out.update(
+            _rebuild_shape(
+                len(items), survivors, targets, DATA_SHARDS * sum(sizes)
+            )
+        )
         if want_crcs and whole:
             out["shard_crcs"] = _fold_host_batch_crcs(b, targets, crc_parts)
 

@@ -1115,6 +1115,9 @@ class VolumeServer:
             "flush_s", "publish_s",
             # the dispatcher's share of device_s / stage_s in a device stage
             "h2d_s", "launch_s",
+            # a rebuild's shape (decode tiles dispatched, survivor and
+            # target shards, survivor bytes gathered) and its master lookup
+            "tiles", "survivors", "targets", "survivor_bytes", "lookup_s",
             # the writer pool's thread-seconds reserving the shard files,
             # and the wall second at which the last of them was reserved
             "reserve_s", "reserve_done_s",
@@ -1170,12 +1173,12 @@ class VolumeServer:
 
     @contextlib.contextmanager
     def _ec_publish(self, verb: str, vids, st: dict):
-        """The generate verbs' last phase, from the driver's return to
-        the `.ecc` published and the `.ecx` sorted and fsynced: the
-        `ec.publish` span and annotation, `publish_s`, and then the
-        verb's ONE report line — after the publish, so that the line
-        accounts for all of the operation, and in a `finally`, so that
-        a failed publish still reports."""
+        """The generate and rebuild verbs' last phase, from the driver's
+        return to the `.ecc` published (merged, for a rebuild) and the
+        `.ecx` sorted and fsynced: the `ec.publish` span and annotation,
+        `publish_s`, and then the verb's ONE report line — after the
+        publish, so that the line accounts for all of the operation, and
+        in a `finally`, so that a failed publish still reports."""
         phase = trace.Phases("ec.publish")
         try:
             yield
@@ -1262,26 +1265,28 @@ class VolumeServer:
                 base, rs=self._new_rs(), durable=True, stats=st,
                 want_crcs=True,
             )
-            self._log_ec_verb("rebuild", req.volume_id, st)
-            self._log_rebuild_crcs(req.volume_id, base, st)
+            with self._ec_publish("rebuild", req.volume_id, st):
+                self._log_rebuild_crcs(req.volume_id, base, st)
             return pb.VolumeEcShardsRebuildResponse(rebuilt_shard_ids=rebuilt)
         # with a master, always learn which "missing" shards are in
         # fact mounted elsewhere: they serve as remote survivors and
         # are EXCLUDED from the rebuild targets — even a rebuilder
         # holding >= 10 local shards must not regenerate (and later
         # double-mount) shards the cluster still has
+        t0 = time.perf_counter()
         readers, close_readers = self._remote_rebuild_readers(
             req.volume_id, {i for i, p in enumerate(present) if p}
         )
+        # the master lookup, before the driver's clock starts
+        st = {"lookup_s": round(time.perf_counter() - t0, 4)}
         try:
             if not readers:
-                st = {}
                 rebuilt = ec_files.rebuild_ec_files(
                     base, rs=self._new_rs(), durable=True, stats=st,
                     want_crcs=True,
                 )
-                self._log_ec_verb("rebuild", req.volume_id, st)
-                self._log_rebuild_crcs(req.volume_id, base, st)
+                with self._ec_publish("rebuild", req.volume_id, st):
+                    self._log_rebuild_crcs(req.volume_id, base, st)
             else:
                 from seaweedfs_tpu.ec import ec_stream, repair_session
 
@@ -1305,7 +1310,6 @@ class VolumeServer:
                     ev = self.store.find_ec_volume(req.volume_id)
                     if ev is not None:
                         ev.donate_cached_tiles(sess)
-                    st = {}
                     rebuilt = ec_stream.stream_rebuild_ec_files(
                         base,
                         rebuild_fn=rebuild_fn,
@@ -1316,8 +1320,8 @@ class VolumeServer:
                         stats=st,
                         want_crcs=True,
                     )
-                    self._log_ec_verb("rebuild", req.volume_id, st)
-                    self._log_rebuild_crcs(req.volume_id, base, st)
+                    with self._ec_publish("rebuild", req.volume_id, st):
+                        self._log_rebuild_crcs(req.volume_id, base, st)
                 except ValueError as e:
                     context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
                 finally:
@@ -1383,13 +1387,13 @@ class VolumeServer:
                     context.abort(
                         grpc.StatusCode.FAILED_PRECONDITION, str(e)
                     )
-                self._log_ec_verb(
+                with self._ec_publish(
                     "batch_rebuild", [vid for vid, _ in batch], st
-                )
-                for (vid, base), crcs in zip(
-                    batch, st.get("shard_crcs") or []
                 ):
-                    self._log_rebuild_crcs(vid, base, {"shard_crcs": crcs})
+                    for (vid, base), crcs in zip(
+                        batch, st.get("shard_crcs") or []
+                    ):
+                        self._log_rebuild_crcs(vid, base, {"shard_crcs": crcs})
         return pb.VolumeEcShardsBatchGenerateResponse()
 
     def _cluster_present_shards(self, vid: int) -> set[int]:

@@ -251,10 +251,24 @@ def test_chunked_batch_adds_the_seconds_up(tmp_path, monkeypatch):
     assert chunked["launch_s"] == pytest.approx(chunked["device_s"], abs=4e-4)
 
 
-def test_host_stage_pairs_book_no_device_field(tmp_path):
-    stats = _encode_single(tmp_path, host_pair=True)
+@pytest.mark.parametrize("driver", ["single-host-pair", "rebuild-host-pair"])
+def test_host_stage_pairs_book_no_device_field(driver, tmp_path):
+    stats = DRIVERS[driver](tmp_path)
     assert not set(DEVICE_FIELDS) & set(stats)
     assert stats["driver"] == "stream-host" and stats["compute_s"] > 0
+
+
+def test_rebuild_driver_device_split_reconciles(tmp_path):
+    """The rebuild's device stage books the encode's split (ISSUE 32)."""
+    stats = _rebuild_single(tmp_path, host_pair=False)
+    tiles = sum(stats["arms"].values())
+    assert tiles == stats["tiles"] == 7  # seven rows of one small block a shard
+    slack = 5e-3 * tiles + 5e-4  # a loaded worker switches threads between the samples
+    assert stats["h2d_s"] + stats["launch_s"] == pytest.approx(
+        stats["device_s"], abs=slack
+    )
+    assert stats["h2d_s"] + stats["launch_s"] <= stats["device_s"] + 5e-4
+    assert stats["arms"]["bit-matmul"] == tiles
 
 
 # --- spans --------------------------------------------------------------------
@@ -513,10 +527,13 @@ def test_failed_publish_still_reports(node, node_log, monkeypatch, stream_device
 # --- the profiler's clock -----------------------------------------------------
 
 
-def test_annotations_reach_a_profiler_trace(tmp_path):
-    """One CPU profiler session around a small encode: the phases and
-    the pool stages are TraceMe events on the host plane's thread lines
-    (read as benchmark/selftest reads its recorded trace)."""
+@pytest.mark.parametrize("driver", ["single-device", "rebuild-device"])
+def test_annotations_reach_a_profiler_trace(driver, tmp_path):
+    """One CPU profiler session around a small encode, and around a
+    small rebuild (ISSUE 32: its device stage annotates ec.h2d and
+    ec.launch too): the phases and the pool stages are TraceMe events
+    on the host plane's thread lines (read as benchmark/selftest reads
+    its recorded trace)."""
     import jax
     from jax.profiler import ProfileData
 
@@ -524,7 +541,7 @@ def test_annotations_reach_a_profiler_trace(tmp_path):
     options.python_tracer_level = 0  # as the benchmark's node launcher sets it
     jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=options)
     try:
-        _encode_single(tmp_path, host_pair=False)
+        DRIVERS[driver](tmp_path)
     finally:
         jax.profiler.stop_trace()
     found = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))

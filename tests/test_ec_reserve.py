@@ -407,9 +407,13 @@ def test_report_line_feeds_the_benchmark_metric(
     metric = readers.load_metric(name)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == name)
-    for key in ("name", "unit", "better", "layer", "moves", "source", "workloads"):
+    for key in ("name", "unit", "better", "layer", "moves", "source"):
         assert metric[key] == entry[key], key
-    assert entry["workloads"] == CELLS and entry["moves"] == "ec_gbps"
+    # a cell joins a metric by the manifest's list (run.py:per_layer); the
+    # file's copy is the three cells of ISSUE 29, and ISSUE 32's repair
+    # cell was appended to the manifest alone
+    assert metric["workloads"] == CELLS and entry["moves"] == "ec_gbps"
+    assert entry["workloads"] == CELLS + ["rebuild-1data"]
 
     stats: dict = {}
     _within(60, DRIVERS[driver], tmp_path, stats)

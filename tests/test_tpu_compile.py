@@ -244,6 +244,29 @@ def test_encode_u32_crc_names(one_chip, on_tpu):
         assert _scoped(text, scope), scope
 
 
+def test_repair_cell_decode_program_names(one_chip, on_tpu):
+    """The one program the cell `rebuild-1data` launches (ISSUE 32): the
+    fused decode of shard 3 from survivors {0,1,2,4,...,10} at the local
+    rebuild's 512 KiB tile. Its kernel keeps the name that
+    benchmark/metrics/rebuild_swar_roofline.json matches (the encode
+    metric's own pattern), with one output row."""
+    import re
+
+    from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
+
+    survivors = (0, 1, 2, 4, 5, 6, 7, 8, 9, 10)
+    kern = TpuCodecKernels()
+    text = _compiled_text(
+        lambda x: kern.reconstruct_u32_crc(survivors, (3,), x),
+        _u32((10, TILE_LANES // 2), one_chip),
+    )
+    kernel = re.search(SWAR_EVENT, text)
+    assert kernel and "u32[1,131072]" in kernel.group(0)
+    for scope in ("ec.swar", "ec.crc_fold"):
+        assert _scoped(text, scope), scope
+    assert "kind=kCustom" not in text  # PR 31's fold: no gather fusion
+
+
 def test_mesh_encode_batch_u32_crc_names(topo):
     import re
 
