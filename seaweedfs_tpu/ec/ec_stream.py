@@ -9,8 +9,9 @@ byte is written exactly once wherever its tile finishes, so completion
 order never shows in the bytes: they match the synchronous drivers of
 ec_files.py exactly.
 
-The shell owns, once: the pools, their two bounded queues and the ring;
-the stop flag's reach into every wait; the output files (opened on the
+The shell owns, once: the pools, their two bounded queues and the ring
+(its memory borrowed from the process and given back: _KeptRing); the
+stop flag's reach into every wait; the output files (opened on the
 caller's thread, reserved by the writer pool behind a latch:
 _Reservation); the five serial phases that partition wall_s
 (_OP_PHASES) and the pool stages' thread-seconds; the abort and
@@ -61,6 +62,7 @@ from seaweedfs_tpu.ec import locate
 from seaweedfs_tpu.stats.metrics import (
     EC_REPAIR_BYTES_READ,
     EC_REPAIR_BYTES_WRITTEN,
+    EC_RING_FRESH_BYTES,
 )
 
 DATA_SHARDS = locate.DATA_SHARDS
@@ -84,6 +86,16 @@ DEFAULT_TILE_BYTES = 1024 * 1024
 # + one per writer thread (being fetched/written) + reader_threads + 2
 # (read queue + the dispatcher's hands) — 10 tiles at the defaults.
 _INFLIGHT = 3
+# The most staging memory the process keeps between operations
+# (_KeptRing). A ring is _INFLIGHT + writer_threads + 1 slots of the
+# plan's slot_bytes: 12 x 10 MiB for a 1 MiB-tile encode, 12 x 40 and
+# 12 x 60 MiB for batches of four and six volumes, all kept; a ring
+# beyond this (256 volumes a call are 2.5 GiB a slot) is allocated and
+# freed by its operation.
+_RING_KEEP_BYTES = 1 << 30
+# Slots start this far apart in the ring's one allocation (a page), so
+# every slot lies in its allocation as a slot of its own used to.
+_SLOT_ALIGN = 4096
 
 
 def pipeline_enabled() -> bool:
@@ -106,19 +118,34 @@ def pipeline_batch_limit() -> int:
 
 
 class _StagingRing:
-    """N preallocated host staging buffers cycled reader → dispatcher →
-    writer → free. Replaces a fresh np.empty per tile: the pipeline's
-    host memory is bounded at slots x slot_bytes for the whole run and
-    the allocator drops out of the hot loop (page-faulting a new 40 MiB
-    arena per tile showed up as wall no stage accounted for). Slot
-    count = dispatch depth + one in-hand buffer per pool
-    thread, so no stage ever stalls waiting for memory another stage
-    is legitimately using."""
+    """N host staging buffers cycled reader -> dispatcher -> writer ->
+    free, carved from ONE allocation that the operation borrows from the
+    process (_KeptRing) and gives back when it has settled: the
+    pipeline's host memory is bounded at slots x slot_bytes for the
+    whole run, the allocator is out of the hot loop, and from a node's
+    second operation on the readers, the H2D copy and the writers work
+    on pages that are already mapped (six readers faulting in six new
+    40 MiB slots at once made the first read into every slot four times
+    as long as the later ones, PERF.md section 6, PR 33). Slot count =
+    dispatch depth + one in-hand buffer per pool thread, so no stage
+    ever stalls waiting for memory another stage is legitimately using.
+
+    A slot holds whatever its last user left, an earlier operation's
+    bytes included: a plan zero-pads what it does not read
+    (_read_tile_into) and writes only what it read, as it already had to
+    between two tiles of one operation."""
 
     def __init__(self, slots: int, slot_bytes: int):
         self.slots = max(2, slots)
+        stride = -(-slot_bytes // _SLOT_ALIGN) * _SLOT_ALIGN
+        # fresh_bytes: what of this ring was allocated for it (0: all of
+        # it is memory an earlier operation used and gave back)
+        self._arena, self.fresh_bytes = _RING.borrow(
+            self.slots * stride
+        )
         self._bufs = [
-            np.empty(slot_bytes, dtype=np.uint8) for _ in range(self.slots)
+            self._arena[i * stride : i * stride + slot_bytes]
+            for i in range(self.slots)
         ]
         self._free: queue.Queue = queue.Queue()
         for i in range(self.slots):
@@ -134,6 +161,17 @@ class _StagingRing:
 
     def release(self, slot_id: int) -> None:
         self._free.put(slot_id)
+
+    def settle(self, whole: bool) -> None:
+        """The operation's pools are joined: give the memory back to the
+        process if the operation ran whole (every tile was fetched, so
+        no transfer reads a slot any more), and drop it otherwise: an
+        asynchronous H2D copy of an aborted operation may still be
+        reading one, and what it reads must not become another
+        operation's slot."""
+        arena, self._arena, self._bufs = self._arena, None, []
+        if whole:
+            _RING.give_back(arena)
 
 
 # Pool widths: the threads spend their time in GIL-released syscalls
@@ -544,8 +582,9 @@ class _Op:
         pipe = _Pipeline()
         read_q: queue.Queue = queue.Queue(maxsize=max(2, reader_threads))
         write_q: queue.Queue = queue.Queue(maxsize=_INFLIGHT)
-        # every in-flight tile lives in one of these preallocated slots:
-        # the window plus the buffers pool threads legitimately hold
+        # every in-flight tile lives in one of these slots: the window
+        # plus the buffers pool threads legitimately hold. The memory is
+        # the process's where it is free (_KeptRing)
         ring = _StagingRing(_INFLIGHT + writer_threads + 1, slot_bytes)
         claims, claim_lock = iter(items), threading.Lock()
         fds: list[int] = []  # opened inside the try: no leak on ENOSPC
@@ -651,6 +690,7 @@ class _Op:
                 phases.to("ec.op.write_tail", (ok and last_fetch[0]) or None)
                 phases.to("ec.op.flush")
                 whole = ok and not pipe.errors
+                ring.settle(whole)
                 fsync_err = _settle_outputs(
                     fds, [path for path, _ in outputs], durable, whole
                 )
@@ -671,8 +711,10 @@ class _Op:
                     )
                     out["pipeline_depth"] = _INFLIGHT
                     out["ring_slots"] = ring.slots
+                    out["ring_fresh_bytes"] = ring.fresh_bytes
                     report(out, sp, whole and fsync_err is None)
                     _trace_stages(sp, busy)
+                    sp.annotate("ring_fresh_bytes", ring.fresh_bytes)
                     if self.device_stage:
                         _report_traces(out, sp, self._traces0)
                     if stats is not None:
@@ -1394,6 +1436,46 @@ def _kept(key: tuple, build: Callable[[], "object"]):
     return obj
 
 
+class _KeptRing:
+    """The process's kept staging memory: ONE flat allocation that an
+    operation borrows for its ring (_StagingRing) and gives back settled,
+    so that the memory outlives the operation the way the programs do.
+    It belongs to one operation at a time: gRPC handler threads run EC
+    verbs concurrently (an encode beside a repair), and one that finds
+    it lent out, or too small, allocates its own as every operation did
+    before. The largest allocation that comes back is the one kept, so
+    what is kept grows to the largest ring the node has run, up to
+    _RING_KEEP_BYTES; a larger ring is never kept. No knob: the decision
+    is made from the requested bytes."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._arena: np.ndarray | None = None  # None: none yet, or lent out
+
+    def borrow(self, nbytes: int) -> tuple[np.ndarray, int]:
+        """(flat uint8 memory of at least nbytes, the bytes of it that
+        were allocated now: 0 when it is the kept memory)."""
+        with self._lock:
+            arena, self._arena = self._arena, None
+        if arena is not None:
+            if arena.size >= nbytes:
+                return arena, 0
+            self.give_back(arena)  # too small for this one: leave it
+        EC_RING_FRESH_BYTES.inc(nbytes)
+        return np.empty(nbytes, dtype=np.uint8), nbytes
+
+    def give_back(self, arena: np.ndarray) -> None:
+        if arena.size > _RING_KEEP_BYTES:
+            return
+        with self._lock:
+            if self._arena is None or self._arena.size < arena.size:
+                self._arena = arena
+
+
+# the process's one, beside the kept programs
+_RING = _KeptRing()
+
+
 class _DevicePrograms:
     """The single-chip stage pairs' device programs: ONE TpuCodecKernels
     and one jitted program per kernel arm of encode and of rebuild, kept
@@ -1630,7 +1712,7 @@ def stream_write_ec_files_batch(
                     if isinstance(v, float):
                         # stage seconds accumulate across chunks
                         stats[k] = round(stats.get(k, 0.0) + v, 4)
-                    elif k == "program_traces":
+                    elif k in ("program_traces", "ring_fresh_bytes"):
                         stats[k] = stats.get(k, 0) + v
                     elif k != "shard_crcs":
                         # structural fields (pipeline_depth, mesh,
@@ -2098,7 +2180,10 @@ def stream_rebuild_ec_files_batch(
             for k, v in chunk_stats.items():
                 if isinstance(v, float):
                     summed[k] = round(summed.get(k, 0.0) + v, 4)
-                elif k in ("program_traces", "tiles", "survivor_bytes"):
+                elif k in (
+                    "program_traces", "ring_fresh_bytes", "tiles",
+                    "survivor_bytes",
+                ):
                     summed[k] = summed.get(k, 0) + v
                 elif k != "shard_crcs":
                     last_struct[k] = v

@@ -1124,6 +1124,9 @@ class VolumeServer:
             # device programs traced during the operation: 0 after a
             # node's first verb per tile shape and survivor set
             "program_traces",
+            # staging-ring memory the operation allocated anew: 0 while
+            # it ran on what an earlier operation gave back
+            "ring_fresh_bytes",
         )
         wlog.info(
             "ec.%s vid=%s report=%s",

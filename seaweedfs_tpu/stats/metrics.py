@@ -442,6 +442,13 @@ EC_PROGRAM_TRACES = DEFAULT_REGISTRY.counter(
     "(codec_tpu.counted_jit): grows at a node's first verb per tile "
     "shape and survivor set, then stands still",
 )
+EC_RING_FRESH_BYTES = DEFAULT_REGISTRY.counter(
+    "weed_ec_ring_fresh_bytes_total",
+    "bytes of host staging-ring memory EC operations allocated anew "
+    "(ec_stream._KeptRing): grows at a node's first operation, when a "
+    "larger ring is asked for, beside a concurrent operation and after "
+    "an aborted one, and stands still while operations reuse kept memory",
+)
 EC_REPAIR_BYTES_READ = DEFAULT_REGISTRY.counter(
     "weed_ec_repair_bytes_read_total",
     "survivor bytes gathered by EC rebuild, by where they came from",

@@ -459,28 +459,22 @@ def test_handler_span_report_line_and_publish(
         assert os.path.exists(base + ".ecx") and os.path.exists(base + ".ecc")
 
 
-@pytest.mark.parametrize("verb,volumes", [("generate", 1), ("batch_generate", 2)])
-def test_report_line_and_metrics_carry_program_traces(
-    verb, volumes, node, node_log, stream_device_driver
-):
-    """The same verb twice on one node (ISSUE 27): every report line
-    carries `program_traces`, the repeat's is 0, and the node's
-    /metrics has the process-wide counter, which the repeat leaves
-    where it was."""
+def _same_verb_twice(node, node_log, verb: str, volumes: int, family: str):
+    """The verb twice on one node, each time on new sealed volumes:
+    (the two report lines, the /metrics family's value before each call,
+    its value after both)."""
     import urllib.request
+
+    master, vs = node
 
     def counter() -> float:
         with urllib.request.urlopen(
             f"http://127.0.0.1:{vs.port}/metrics", timeout=10
         ) as r:
             text = r.read().decode()
-        line = next(
-            ln for ln in text.splitlines()
-            if ln.startswith("weed_ec_program_traces_total")
-        )
+        line = next(ln for ln in text.splitlines() if ln.startswith(family))
         return float(line.split()[-1])
 
-    master, vs = node
     before = []
     with grpc.insecure_channel(f"127.0.0.1:{vs.grpc_port}") as ch:
         stub = rpc.volume_stub(ch)
@@ -497,11 +491,48 @@ def test_report_line_and_metrics_carry_program_traces(
             CALLS[verb](stub, vids, None)
     reports = _verb_reports("\n".join(node_log), verb)
     assert len(reports) == 2
+    return reports, before, counter()
+
+
+@pytest.mark.parametrize("verb,volumes", [("generate", 1), ("batch_generate", 2)])
+def test_report_line_and_metrics_carry_program_traces(
+    verb, volumes, node, node_log, stream_device_driver
+):
+    """The same verb twice on one node (ISSUE 27): every report line
+    carries `program_traces`, the repeat's is 0, and the node's
+    /metrics has the process-wide counter, which the repeat leaves
+    where it was."""
+    reports, before, after = _same_verb_twice(
+        node, node_log, verb, volumes, "weed_ec_program_traces_total"
+    )
     # the worker's other tests may have traced these shapes already
     assert reports[0]["program_traces"] in (0, 1)
     assert reports[1]["program_traces"] == 0
     assert before[1] - before[0] == reports[0]["program_traces"]
-    assert counter() == before[1]
+    assert after == before[1]
+
+
+@pytest.mark.parametrize("verb,volumes", [("generate", 1), ("batch_generate", 2)])
+def test_report_line_and_metrics_carry_ring_fresh_bytes(
+    verb, volumes, node, node_log, stream_device_driver, monkeypatch
+):
+    """The same verb twice on one node (ISSUE 33): every report line
+    carries `ring_fresh_bytes`, the repeat ran on the memory the first
+    gave back and reads 0, and the node's /metrics counts what was
+    allocated, which the repeat leaves where it was."""
+    # the node is this process: make it one that has run no operation
+    # (the worker's other tests may have left a ring large enough)
+    monkeypatch.setattr(ec_stream, "_RING", ec_stream._KeptRing())
+    reports, before, after = _same_verb_twice(
+        node, node_log, verb, volumes, "weed_ec_ring_fresh_bytes_total"
+    )
+    # whole slots of [volumes, 10, 1 MiB] each
+    slot = volumes * 10 * ec_stream.DEFAULT_TILE_BYTES
+    assert reports[0]["ring_fresh_bytes"] >= 2 * slot
+    assert reports[0]["ring_fresh_bytes"] % slot == 0
+    assert reports[1]["ring_fresh_bytes"] == 0
+    assert before[1] - before[0] == reports[0]["ring_fresh_bytes"]
+    assert after == before[1]
 
 
 def test_failed_publish_still_reports(node, node_log, monkeypatch, stream_device_driver):

@@ -390,6 +390,34 @@ def bench_harness(monkeypatch):
             importlib.import_module("harness.node"))
 
 
+def _node_reports(node, verb: str, stats: dict) -> list[dict]:
+    """`stats` as the node writes them on its `ec.<verb> report=` line,
+    read back by the benchmark's own parser."""
+    import logging
+
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+
+    lines: list[str] = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    logger = logging.getLogger("seaweedfs_tpu")
+    logger.addHandler(handler)
+    try:
+        VolumeServer._log_ec_verb(verb, [1], stats)
+    finally:
+        logger.removeHandler(handler)
+    return node.verb_reports("I] " + lines[-1], verb)
+
+
+def _parents_reports(node) -> list[dict]:
+    """Report lines of a program that has neither PR 29's nor PR 33's fields."""
+    with open(os.path.join(REPO, "benchmark", "selftest", "node_log_phases.txt")) as f:
+        return node.verb_reports(f.read(), "generate")
+
+
+WINDOW = {"seconds": 1.0, "gib": 0.5, "requests": 1}
+
+
 @pytest.mark.parametrize("name", sorted(METRICS))
 @pytest.mark.parametrize("driver,verb", [("single", "generate"), ("batch", "batch_generate")])
 def test_report_line_feeds_the_benchmark_metric(
@@ -399,9 +427,6 @@ def test_report_line_feeds_the_benchmark_metric(
     it through the readers that were there, and a report line of a
     program without the field (the parent's) makes it read nothing."""
     import json
-    import logging
-
-    from seaweedfs_tpu.server.volume_server import VolumeServer
 
     readers, node = bench_harness
     metric = readers.load_metric(name)
@@ -417,20 +442,56 @@ def test_report_line_feeds_the_benchmark_metric(
 
     stats: dict = {}
     _within(60, DRIVERS[driver], tmp_path, stats)
-    lines: list[str] = []
-    handler = logging.Handler()
-    handler.emit = lambda record: lines.append(record.getMessage())
-    logger = logging.getLogger("seaweedfs_tpu")
-    logger.addHandler(handler)
-    try:
-        VolumeServer._log_ec_verb(verb, [1], stats)
-    finally:
-        logger.removeHandler(handler)
-    reports = node.verb_reports("I] " + lines[-1], verb)
+    reports = _node_reports(node, verb, stats)
     assert len(reports) == 1 and reports[0][METRICS[name]] == stats[METRICS[name]] > 0
-    obs = {"reports": reports, "window": {"seconds": 1.0, "gib": 0.5, "requests": 1},
-           "trace": None}
+    obs = {"reports": reports, "window": WINDOW, "trace": None}
     assert readers.read_metric(metric, obs) == pytest.approx(stats[METRICS[name]] / 0.5)
-    with open(os.path.join(REPO, "benchmark", "selftest", "node_log_phases.txt")) as f:
-        obs["reports"] = node.verb_reports(f.read(), "generate")
+    obs["reports"] = _parents_reports(node)
+    assert obs["reports"] and readers.read_metric(metric, obs) is None
+
+
+ALL_CELLS = CELLS + ["rebuild-1data"]
+
+
+@pytest.mark.parametrize("driver,verb", [
+    ("single", "generate"), ("batch", "batch_generate"),
+    ("rebuild", "rebuild"), ("rebuild_batch", "rebuild"),
+])
+def test_report_line_feeds_the_ring_metric(
+    driver, verb, tmp_path, bench_harness, monkeypatch
+):
+    """ISSUE 33's one metric, `ring_fresh_bytes_per_gib`: the file is its
+    manifest entry word for word and lists all four cells, the report
+    line of an operation that allocated its ring reads its bytes, that of
+    one that ran on kept memory reads 0 (a value, not nothing), and a
+    line of a program without the field (the parent's) reads nothing."""
+    import json
+
+    readers, node = bench_harness
+    name = "ring_fresh_bytes_per_gib"
+    metric = readers.load_metric(name)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"] if m["name"] == name)
+    for key in ("name", "unit", "better", "layer", "moves", "source"):
+        assert metric[key] == entry[key], key
+    # the file's copy of the list is this PR's four cells; a later cell
+    # joins by the manifest's list alone (run.py:per_layer)
+    assert metric["workloads"] == ALL_CELLS
+    assert entry["workloads"][:4] == ALL_CELLS
+    assert (entry["unit"], entry["better"], entry["moves"], entry["layer"]) == (
+        "bytes/GiB", "lower", "ec_gbps", "stream driver")
+    assert (metric["num"], metric["den"]) == (["report:ring_fresh_bytes"], ["window:gib"])
+
+    # a process before its first operation
+    monkeypatch.setattr(ec_stream, "_RING", ec_stream._KeptRing())
+    for fresh in (True, False):
+        stats: dict = {}
+        _within(60, DRIVERS[driver], tmp_path, stats)
+        assert (stats["ring_fresh_bytes"] > 0) == fresh
+        reports = _node_reports(node, verb, stats)
+        assert len(reports) == 1
+        assert reports[0]["ring_fresh_bytes"] == stats["ring_fresh_bytes"]
+        obs = {"reports": reports, "window": WINDOW, "trace": None}
+        assert readers.read_metric(metric, obs) == stats["ring_fresh_bytes"] / 0.5
+    obs["reports"] = _parents_reports(node)
     assert obs["reports"] and readers.read_metric(metric, obs) is None
