@@ -493,6 +493,14 @@ _OP_PHASES = {
 # pairs and the batch rebuild's mesh stage book neither.
 _DEVICE_BUSY = {"h2d_s": 0.0, "launch_s": 0.0}
 
+# What the single-volume rebuild books for its rack gather, a pool
+# stage like read_s: the fetch pool's thread-seconds inside the remote
+# readers' calls (each one span of one survivor over the wire, whatever
+# the caller's reader waits for before it moves bytes included). Inside
+# read_s, which books a tile's ten reads together, local and remote; 0.0
+# where every survivor is local.
+_GATHER_BUSY = {"remote_read_s": 0.0}
+
 # What every operation books for its output files' reservation
 # (_Reservation): reserve_s is a pool stage like read_s, the writer
 # pool's thread-seconds inside _preallocate; reserve_done_s is one
@@ -1076,6 +1084,7 @@ def stream_rebuild_ec_files(
     fetch_fn: Callable[["object"], np.ndarray] | None = None,
     stats: dict | None = None,
     remote_readers: dict[int, Callable[[int, int], bytes]] | None = None,
+    remote_report: Callable[[], dict] | None = None,
     writer_threads: int | None = None,
     reader_threads: int | None = None,
     session=None,
@@ -1101,7 +1110,11 @@ def stream_rebuild_ec_files(
     tiles over the wire in parallel with local preadv and the decode,
     and shards readable remotely are treated as present (not rebuilt).
     At least one survivor must be local — its file size fixes the tile
-    walk.
+    walk. Each remote fetch is one `ec.remote_read` annotation on the
+    thread that makes it and is booked under remote_read_s;
+    remote_report(), called once the pools are joined, gives what the
+    caller's readers have to add to the report line and the root
+    span's attributes (the volume server's: arbiter_wait_s).
 
     `session` (an ec.repair_session.RebuildSession) is the repair-
     bandwidth-frugal hookup: tiles degraded serving already decoded are
@@ -1120,7 +1133,9 @@ def stream_rebuild_ec_files(
         raise ValueError("rebuild_fn and fetch_fn must be injected together")
     device_stage = rebuild_fn is None
     op = _Op(
-        "ec_stream.rebuild", device_stage, _DEVICE_BUSY if device_stage else None
+        "ec_stream.rebuild",
+        device_stage,
+        {**(_DEVICE_BUSY if device_stage else {}), **_GATHER_BUSY},
     )
     if device_stage:
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs, book=op.book)
@@ -1135,6 +1150,7 @@ def stream_rebuild_ec_files(
         4 * DEFAULT_TILE_BYTES if remote_readers else DEFAULT_TILE_BYTES // 2
     )
     remote_readers = dict(remote_readers or {})
+    fold_spans = device_stage and want_crcs
 
     from seaweedfs_tpu.ec.ec_files import shard_presence, to_ext
 
@@ -1170,6 +1186,9 @@ def stream_rebuild_ec_files(
     # survivor bytes of every gather, from the reader pool (append is
     # GIL-atomic too), and the dispatcher's own count of its launches
     gathered: list[int] = []
+    gathered_remote: list[int] = []
+    # rebuilt bytes of every write, from the writer pool
+    written: list[int] = []
     launches = 0
     n_remote = sum(1 for i in survivors if not present[i])
     read_local = EC_REPAIR_BYTES_READ.labels("local")
@@ -1201,6 +1220,15 @@ def stream_rebuild_ec_files(
             for fd in fds.values():
                 os.close(fd)
 
+    def remote_read(i: int, g_off: int, g_len: int) -> bytes:
+        """One span of one remote survivor, on a fetch-pool thread (the
+        reader's own where a lone remote survivor has no pool)."""
+        t0 = time.perf_counter()
+        with trace.annotation("ec.remote_read"):
+            raw = remote_readers[i](g_off, g_len)
+        op.book("remote_read_s", time.perf_counter() - t0)
+        return raw
+
     def gather(src, g_off: int, g_len: int, dest: np.ndarray) -> np.ndarray:
         """One [k, g_len] survivor read at g_off into a staging-ring
         view — the only place rebuild bytes cross a disk or the network,
@@ -1210,7 +1238,7 @@ def stream_rebuild_ec_files(
         futures = {}
         if fetch_pool is not None:
             futures = {
-                j: fetch_pool.submit(remote_readers[i], g_off, g_len)
+                j: fetch_pool.submit(remote_read, i, g_off, g_len)
                 for j, i in enumerate(survivors)
                 if i not in fds
             }
@@ -1223,10 +1251,11 @@ def stream_rebuild_ec_files(
                 raw = (
                     fut.result()
                     if fut is not None
-                    else remote_readers[i](g_off, g_len)
+                    else remote_read(i, g_off, g_len)
                 )
                 got = len(raw)
                 read_remote.inc(got)
+                gathered_remote.append(got)
                 if got == g_len:
                     tile[j] = np.frombuffer(raw, dtype=np.uint8)
             if got != g_len:
@@ -1256,10 +1285,15 @@ def stream_rebuild_ec_files(
         _, covered, gaps = item
         parts: list = [("don", d_off, per_t) for d_off, per_t in covered]
         cur = 0
-        for g_off, g_len in gaps:
-            dest = buf[cur : cur + DATA_SHARDS * g_len]
-            cur += DATA_SHARDS * g_len
-            parts.append(("raw", g_off, gather(src, g_off, g_len, dest)))
+        for gap in gaps:
+            # the device stage folds the CRC of a power-of-two span in
+            # its program; a short tile (the 3 MiB tail of a 103 MiB
+            # shard under 4 MiB tiles) goes as such spans
+            spans = _pow2_spans(*gap, tile_bytes) if fold_spans else [gap]
+            for g_off, g_len in spans:
+                dest = buf[cur : cur + DATA_SHARDS * g_len]
+                cur += DATA_SHARDS * g_len
+                parts.append(("raw", g_off, gather(src, g_off, g_len, dest)))
         return parts
 
     def dispatch(item, parts):
@@ -1311,6 +1345,7 @@ def stream_rebuild_ec_files(
             for fd, row in zip(fds, rows_of(kind, payload)):
                 _pwrite_full(fd, row, off)
                 EC_REPAIR_BYTES_WRITTEN.inc(len(row))
+                written.append(len(row))
 
     def report(out, sp, whole):
         out["driver"] = _driver_name(device_stage)
@@ -1319,6 +1354,14 @@ def stream_rebuild_ec_files(
         if want_crcs and whole:
             out["shard_crcs"] = _fold_rebuild_crcs(targets, crc_ranges)
         shape = _rebuild_shape(launches, survivors, targets, sum(gathered))
+        # the gather's split, in what the repair counters took: of the
+        # survivors and their bytes those that crossed the wire, and the
+        # rebuilt bytes written (weed_ec_repair_bytes_written_total)
+        shape["remote_survivors"] = n_remote
+        shape["survivor_bytes_remote"] = sum(gathered_remote)
+        shape["rebuilt_bytes"] = sum(written)
+        if remote_report is not None:
+            shape.update(remote_report())
         out.update(shape)
         for key, value in shape.items():
             sp.annotate(key, value)
@@ -1351,6 +1394,22 @@ def stream_rebuild_ec_files(
         writer_threads=writer_threads,
     )
     return list(targets)
+
+
+def _pow2_spans(off: int, length: int, tile_bytes: int) -> list[tuple[int, int]]:
+    """(offset, length) spans covering [off, off + length), each a power
+    of two (what crc_kernel.crc_supported takes) from the largest down,
+    for as long as a span is an eighth of a tile or more; what is left
+    below that rides as one last span of any length. A whole tile is one
+    span; 3 MiB under 4 MiB tiles are 2 MiB + 1 MiB."""
+    spans = []
+    while length:
+        span = 1 << (length.bit_length() - 1)
+        if span * 8 < tile_bytes:
+            span = length
+        spans.append((off, span))
+        off, length = off + span, length - span
+    return spans
 
 
 def _fold_rebuild_crcs(

@@ -128,6 +128,16 @@ class BandwidthArbiter:
         """Charge `n` background bytes to claimant `name`, blocking
         until the claimant's current share admits them. Returns False
         (without consuming) when `stop` fires first."""
+        return self.take_timed(name, n, stop)[0]
+
+    def take_timed(
+        self, name: str, n: int, stop: threading.Event | None = None
+    ) -> tuple[bool, float]:
+        """`take`, and the seconds this call stood waiting for its
+        share (what the claimant's WaitedSeconds grew by: 0.0 with
+        pacing off or when `stop` fired) — for a caller that books the
+        wait against one operation of its own (the rebuild verb's
+        arbiter_wait_s)."""
         from seaweedfs_tpu.stats.metrics import ARBITER_BYTES, ARBITER_WAIT_SECONDS
 
         with self._lock:
@@ -138,7 +148,7 @@ class BandwidthArbiter:
             claim.bytes += int(n)
         ARBITER_BYTES.labels(name).inc(int(n))
         if not self.enabled:
-            return True
+            return True, 0.0
         started = time.monotonic()
         while True:
             with self._lock:
@@ -157,14 +167,14 @@ class BandwidthArbiter:
                     claim.waited_s += waited
                     if waited > 0:
                         ARBITER_WAIT_SECONDS.labels(name).inc(waited)
-                    return True
+                    return True, waited
                 wait = (need - claim.tokens) / rate
             wait = min(wait, 0.25)
             if stop is not None:
                 if stop.wait(wait):
                     with self._lock:
                         claim.bytes -= int(n)  # never moved
-                    return False
+                    return False, 0.0
             else:
                 time.sleep(wait)
 

@@ -1118,6 +1118,12 @@ class VolumeServer:
             # a rebuild's shape (decode tiles dispatched, survivor and
             # target shards, survivor bytes gathered) and its master lookup
             "tiles", "survivors", "targets", "survivor_bytes", "lookup_s",
+            # its rack gather: the survivors and bytes that crossed the
+            # wire, the fetch pool's thread-seconds in those reads and,
+            # inside them, their wait at the bandwidth arbiter; the
+            # rebuilt bytes written
+            "remote_survivors", "survivor_bytes_remote", "remote_read_s",
+            "arbiter_wait_s", "rebuilt_bytes",
             # the writer pool's thread-seconds reserving the shard files,
             # and the wall second at which the last of them was reserved
             "reserve_s", "reserve_done_s",
@@ -1277,7 +1283,7 @@ class VolumeServer:
         # holding >= 10 local shards must not regenerate (and later
         # double-mount) shards the cluster still has
         t0 = time.perf_counter()
-        readers, close_readers = self._remote_rebuild_readers(
+        readers, close_readers, gather_report = self._remote_rebuild_readers(
             req.volume_id, {i for i, p in enumerate(present) if p}
         )
         # the master lookup, before the driver's clock starts
@@ -1318,6 +1324,7 @@ class VolumeServer:
                         rebuild_fn=rebuild_fn,
                         fetch_fn=fetch_fn,
                         remote_readers=readers,
+                        remote_report=gather_report,
                         session=sess,
                         durable=True,
                         stats=st,
@@ -1460,13 +1467,19 @@ class VolumeServer:
             self._publish_ecc(base, dict(crcs))
 
     def _remote_rebuild_readers(self, vid: int, skip: set[int]):
-        """(readers, closer): shard id → fetch(offset, size) callables
-        over VolumeEcShardRead against holders learned from the master,
-        for survivors not in `skip` (the locally-present set). One
-        cached channel per holder — the stream driver's reader pool
-        calls these concurrently, and grpc channels are thread-safe."""
+        """(readers, closer, report): shard id → fetch(offset, size)
+        callables over VolumeEcShardRead against holders learned from
+        the master, for survivors not in `skip` (the locally-present
+        set). One cached channel per holder — the stream driver's
+        reader pool calls these concurrently, and grpc channels are
+        thread-safe. report() is what the readers have to say of the
+        operation they served, for its report line and root span:
+        arbiter_wait_s, the seconds their reads stood at the bandwidth
+        arbiter (thread-seconds of the driver's fetch pool, inside its
+        remote_read_s)."""
+        none = ({}, (lambda: None), dict)
         if not self.master:
-            return {}, (lambda: None)
+            return none
         try:
             with rpc.dial(self._master_grpc()) as ch:
                 resp = rpc.master_stub(ch).LookupEcVolume(
@@ -1474,7 +1487,7 @@ class VolumeServer:
                     timeout=5,
                 )
         except grpc.RpcError:
-            return {}, (lambda: None)
+            return none
         me = self._self_urls()
         locations: dict[int, list[str]] = {}
         for entry in resp.shard_id_locations:
@@ -1504,6 +1517,9 @@ class VolumeServer:
         # survivor then fails the gather within the budget instead of
         # parking each read for the full per-op timeout
         factory_dl = _op_deadline.current()
+        # each read's wait at the arbiter (append is GIL-atomic: the
+        # reads run on the driver's pool threads)
+        arbiter_waits: list[float] = []
 
         def make_reader(sid: int, urls: list[str]):
             def read(offset: int, size: int) -> bytes:
@@ -1511,7 +1527,9 @@ class VolumeServer:
                 # pulling remote bytes — max-min share against
                 # replication/handoff/tier, yielding to foreground
                 # serving (docs/TIERING.md)
-                get_arbiter().take("rebuild", size, stop=self._stop)
+                arbiter_waits.append(
+                    get_arbiter().take_timed("rebuild", size, stop=self._stop)[1]
+                )
                 last: Exception | None = None
                 t_o = 30 if factory_dl is None else factory_dl.cap(30)
                 # the hop HEADER rides too (re-stamped per read, the
@@ -1559,6 +1577,7 @@ class VolumeServer:
         return (
             {sid: make_reader(sid, urls) for sid, urls in locations.items()},
             closer,
+            lambda: {"arbiter_wait_s": round(sum(arbiter_waits), 4)},
         )
 
     def VolumeEcShardsCopy(self, req: pb.VolumeEcShardsCopyRequest, context):

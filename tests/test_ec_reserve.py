@@ -435,10 +435,10 @@ def test_report_line_feeds_the_benchmark_metric(
     for key in ("name", "unit", "better", "layer", "moves", "source"):
         assert metric[key] == entry[key], key
     # a cell joins a metric by the manifest's list (run.py:per_layer); the
-    # file's copy is the three cells of ISSUE 29, and ISSUE 32's repair
-    # cell was appended to the manifest alone
+    # file's copy is the three cells of ISSUE 29, and the repair cells of
+    # ISSUEs 32 and 34 were appended to the manifest alone
     assert metric["workloads"] == CELLS and entry["moves"] == "ec_gbps"
-    assert entry["workloads"] == CELLS + ["rebuild-1data"]
+    assert entry["workloads"] == CELLS + ["rebuild-1data", "rack-rebuild-4lost"]
 
     stats: dict = {}
     _within(60, DRIVERS[driver], tmp_path, stats)
