@@ -482,7 +482,8 @@ def rebuild_ec_files(
     (ec_encoder.go:83 generateMissingEcFiles). Returns rebuilt ids.
 
     buffer_size=None lets each driver pick its default (1 MiB classic
-    batches; 8 MiB pipelined tiles on TPU/native hosts). want_crcs
+    batches; ec_stream.REBUILD_TILE_BYTES pipelined tiles on TPU/native
+    hosts). want_crcs
     lands {rebuilt shard id: whole-file CRC-32C} in `stats` on every
     driver (see write_ec_files)."""
     rs = rs or new_encoder()

@@ -81,6 +81,20 @@ SMALL_BLOCK_SIZE = locate.SMALL_BLOCK_SIZE
 # 8 MiB lost everywhere). TPU dispatch amortization keeps the floor at
 # 1 MiB — a [10, 1 MiB] tile is still 16x the SWAR minimum stream.
 DEFAULT_TILE_BYTES = 1024 * 1024
+# Per-shard bytes per tile of a single-volume rebuild, whether its
+# survivors are local files or streamed from other servers: [10, 4 MiB]
+# in, 40 MiB a ring slot. The ONE dispatcher thread pays about a
+# millisecond to copy a tile to the device and another to launch it
+# whatever the tile's size (0.90 + 0.83 ms at 512 KiB a survivor, 1.2 +
+# 1.2 ms at 4 MiB on a v5e), so a repair of 103 MiB shard files costs
+# that thread 0.36 s in 206 tiles of 512 KiB and 0.07 s in 27 of 4 MiB.
+# The reader pool's ten preads a tile move 3.7 GB/s a thread in 4 MiB
+# spans against 1.1 in 512 KiB ones: 0.28 thread-seconds a repair, 0.05 s
+# of wall over six readers, against 1.02 and 0.17. Read through the cell
+# rebuild-1data at 512 KiB, 1, 2 and 4 MiB (2.08, 3.23, 4.60 and 5.51 GB/s
+# of volume repaired): PERF.md section 6, PR 35. A rack gather wants the
+# same size for a reason of its own: one RPC per remote survivor and tile.
+REBUILD_TILE_BYTES = 4 * DEFAULT_TILE_BYTES
 # Dispatched-but-unfetched tiles queued toward the writer pool (the
 # report line's pipeline_depth). Live host-tile bound: _INFLIGHT queued
 # + one per writer thread (being fetched/written) + reader_threads + 2
@@ -88,8 +102,9 @@ DEFAULT_TILE_BYTES = 1024 * 1024
 _INFLIGHT = 3
 # The most staging memory the process keeps between operations
 # (_KeptRing). A ring is _INFLIGHT + writer_threads + 1 slots of the
-# plan's slot_bytes: 12 x 10 MiB for a 1 MiB-tile encode, 12 x 40 and
-# 12 x 60 MiB for batches of four and six volumes, all kept; a ring
+# plan's slot_bytes: 12 x 10 MiB for a 1 MiB-tile encode, 12 x 40 for
+# a rebuild's 4 MiB tiles, 12 x 40 and 12 x 60 MiB for batches of four
+# and six volumes, all kept; a ring
 # beyond this (256 volumes a call are 2.5 GiB a slot) is allocated and
 # freed by its operation.
 _RING_KEEP_BYTES = 1 << 30
@@ -1139,16 +1154,7 @@ def stream_rebuild_ec_files(
     )
     if device_stage:
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs, book=op.book)
-    # rebuild tiles read one span from each of 10 FILES. Re-swept with
-    # the staging ring (BENCH_r12): LOCAL rebuilds want fine tiles —
-    # 512 KiB ran 3.7 GB/s vs 1.9 at the old 2 MiB (more in-flight
-    # preads for the pool to overlap, page-cache-friendly spans) and
-    # 1.33x the serial classic driver. REMOTE rack-gathers keep a big
-    # tile: each tile costs one RPC per remote survivor, and 8x fewer
-    # round-trips beats overlap granularity across a network hop.
-    tile_bytes = tile_bytes or (
-        4 * DEFAULT_TILE_BYTES if remote_readers else DEFAULT_TILE_BYTES // 2
-    )
+    tile_bytes = tile_bytes or REBUILD_TILE_BYTES
     remote_readers = dict(remote_readers or {})
     fold_spans = device_stage and want_crcs
 

@@ -74,7 +74,7 @@ def bench():
     (4 * MIB, 4 * MIB, [4 * MIB]),              # a whole tile is one span
     (3 * MIB, 4 * MIB, [2 * MIB, MIB]),         # the cell's tail
     (MIB, 4 * MIB, [MIB]),
-    (512 * 1024, 512 * 1024, [512 * 1024]),     # a local repair's tile
+    (512 * 1024, 512 * 1024, [512 * 1024]),     # a whole small tile
     (300 * 1024 + 5, 512 * 1024, [256 * 1024, 44 * 1024 + 5]),  # under an eighth: one odd rest
     (7, 4 * MIB, [7]),
 ], ids=["whole", "tail-3m", "tail-1m", "local", "odd-rest", "tiny"])

@@ -18,6 +18,8 @@ loads the TPU library; everything compiles in this process.
 import numpy as np
 import pytest
 
+from seaweedfs_tpu.ec import ec_stream
+
 TILE_LANES = 262144  # ec_stream.DEFAULT_TILE_BYTES // 4
 
 
@@ -244,12 +246,18 @@ def test_encode_u32_crc_names(one_chip, on_tpu):
         assert _scoped(text, scope), scope
 
 
-def test_repair_cell_decode_program_names(one_chip, on_tpu):
-    """The one program the cell `rebuild-1data` launches (ISSUE 32): the
+# lanes of a tile of the local rebuild's default (ISSUE 35) and of the two
+# power-of-two spans its tail goes to the device as (ec_stream._pow2_spans)
+REPAIR_LANES = [ec_stream.REBUILD_TILE_BYTES // (4 * k) for k in (1, 2, 4)]
+
+
+@pytest.mark.parametrize("lanes", REPAIR_LANES)
+def test_repair_cell_decode_program_names(one_chip, on_tpu, lanes):
+    """The programs the cell `rebuild-1data` launches (ISSUE 32): the
     fused decode of shard 3 from survivors {0,1,2,4,...,10} at the local
-    rebuild's 512 KiB tile. Its kernel keeps the name that
-    benchmark/metrics/rebuild_swar_roofline.json matches (the encode
-    metric's own pattern), with one output row."""
+    rebuild's default tile and at its tail's spans. The kernel keeps the
+    name that benchmark/metrics/rebuild_swar_roofline.json matches (the
+    encode metric's own pattern), with one output row."""
     import re
 
     from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
@@ -258,10 +266,10 @@ def test_repair_cell_decode_program_names(one_chip, on_tpu):
     kern = TpuCodecKernels()
     text = _compiled_text(
         lambda x: kern.reconstruct_u32_crc(survivors, (3,), x),
-        _u32((10, TILE_LANES // 2), one_chip),
+        _u32((10, lanes), one_chip),
     )
     kernel = re.search(SWAR_EVENT, text)
-    assert kernel and "u32[1,131072]" in kernel.group(0)
+    assert kernel and f"u32[1,{lanes}]" in kernel.group(0)
     for scope in ("ec.swar", "ec.crc_fold"):
         assert _scoped(text, scope), scope
     assert "kind=kCustom" not in text  # PR 31's fold: no gather fusion
