@@ -14,7 +14,8 @@ The shell owns, once: the pools, their two bounded queues and the ring
 stop flag's reach into every wait; the output files (opened on the
 caller's thread, reserved by the writer pool behind a latch:
 _Reservation); the five serial phases that partition wall_s
-(_OP_PHASES) and the pool stages' thread-seconds; the abort and
+(_OP_PHASES), the pool stages' thread-seconds and the waits between
+the stages (_WAIT_BUSY: who stood waiting for whom); the abort and
 durability contract (_settle_outputs); the report line and the span.
 A driver is a plan and its stage bodies, handed to _Op.run:
 
@@ -169,7 +170,7 @@ class _StagingRing:
     def acquire(self, stop: threading.Event):
         """(slot id, flat uint8 buffer) or None when the pipeline
         aborted while waiting for a free slot."""
-        i = _q_get(self._free, stop)
+        i = _q_get(self._free, stop, "ec.wait.slot")
         if i is _STOPPED:
             return None
         return i, self._bufs[i]
@@ -204,26 +205,54 @@ _EOF = object()  # end-of-stream marker flowing through the queues
 _STOPPED = object()  # returned by _q_get when the pipeline aborted
 
 _Q_TICK = 0.2  # seconds between stop-flag checks while blocked
+_NO_WAIT = contextlib.nullcontext()
 
 
-def _q_put(q: queue.Queue, item, stop: threading.Event) -> bool:
+def _standing(wait: str | None):
+    """The blocking part of a wait in a profiler trace, under its
+    `ec.wait.*` name; a wait nobody named leaves no event."""
+    return trace.annotation(wait) if wait else _NO_WAIT
+
+
+def _q_put(
+    q: queue.Queue, item, stop: threading.Event, wait: str | None = None
+) -> bool:
     """put() that gives up when the pipeline aborts (a dead consumer
-    must not leave the producer blocked forever)."""
-    while not stop.is_set():
-        try:
-            q.put(item, timeout=_Q_TICK)
-            return True
-        except queue.Full:
-            continue
+    must not leave the producer blocked forever). It tries without
+    blocking first, and `wait` names only the blocking call in a
+    profiler trace (ec.wait.*): a put that finds room leaves no event."""
+    if stop.is_set():
+        return False
+    try:
+        q.put_nowait(item)
+        return True
+    except queue.Full:
+        pass
+    with _standing(wait):
+        while not stop.is_set():
+            try:
+                q.put(item, timeout=_Q_TICK)
+                return True
+            except queue.Full:
+                continue
     return False
 
 
-def _q_get(q: queue.Queue, stop: threading.Event):
-    while not stop.is_set():
-        try:
-            return q.get(timeout=_Q_TICK)
-        except queue.Empty:
-            continue
+def _q_get(q: queue.Queue, stop: threading.Event, wait: str | None = None):
+    """get() under the same rules: _STOPPED when the pipeline aborted,
+    and no trace event for an item that was already there."""
+    if stop.is_set():
+        return _STOPPED
+    try:
+        return q.get_nowait()
+    except queue.Empty:
+        pass
+    with _standing(wait):
+        while not stop.is_set():
+            try:
+                return q.get(timeout=_Q_TICK)
+            except queue.Empty:
+                continue
     return _STOPPED
 
 
@@ -305,11 +334,15 @@ class _Reservation:
 
     def wait(self) -> bool:
         """True once every file is reserved; False when the pipeline
-        aborted first."""
-        while not self._open.is_set():
-            if self._stop.is_set():
-                return False
-            self._open.wait(_Q_TICK)
+        aborted first. Only a wait that blocks is an event in a profiler
+        trace (ec.wait.latch)."""
+        if self._open.is_set():
+            return True
+        with trace.annotation("ec.wait.latch"):
+            while not self._open.is_set():
+                if self._stop.is_set():
+                    return False
+                self._open.wait(_Q_TICK)
         return True
 
 
@@ -524,6 +557,35 @@ _GATHER_BUSY = {"remote_read_s": 0.0}
 # (0.0 on an operation that aborted before that).
 _RESERVE_BUSY = {"reserve_s": 0.0, "reserve_done_s": 0.0}
 
+# Where a thread of the shell stands waiting for another, booked where
+# it blocks (two clock samples a wait) and named `ec.wait.*` in a
+# profiler trace only when the call did block. A pool's thread-seconds
+# beside its busy stages; the dispatcher's wall seconds, which with its
+# time inside the plan's dispatch call close on dispatch_span_s (the
+# residue is the loop's own statements). The pool whose waits are near
+# zero is the pace.
+#   slot_wait_s        readers   ring.acquire: a free ring slot (the
+#                                writers give them back)
+#   read_q_wait_s      readers   room in the read queue (the dispatcher
+#                                is behind)
+#   first_tile_wait_s  dispatcher  tile 0 off the read queue: the part
+#                                of head_s that is the first read
+#   tile_wait_s        dispatcher  tiles 1..n-1 off the read queue (the
+#                                readers, or the ring behind them)
+#   dispatch_call_s    dispatcher  the plan's dispatch(item, staged) as
+#                                the shell sees it, tile 0's included
+#   window_wait_s      dispatcher  room in the in-flight window for
+#                                tiles 0..n-2 (the writers are behind);
+#                                the last tile's put lies in drain_s
+#   work_wait_s        writers   a launched tile off the write queue,
+#                                the wait for _EOF included
+#   latch_wait_s       writers   _Reservation.wait, result in hand
+_WAIT_BUSY = {
+    "slot_wait_s": 0.0, "read_q_wait_s": 0.0, "first_tile_wait_s": 0.0,
+    "tile_wait_s": 0.0, "dispatch_call_s": 0.0, "window_wait_s": 0.0,
+    "work_wait_s": 0.0, "latch_wait_s": 0.0,
+}
+
 
 def _book_reserve_done(busy: dict, reservation, wall0: float) -> None:
     if reservation is not None and reservation.opened_at:
@@ -554,11 +616,12 @@ class _Op:
         # the stage traces and launches this process's device programs
         # (program_traces is reported, 0 in steady state)
         self.device_stage = device_stage
-        # per-stage busy thread-seconds (queue waits excluded): read |
-        # stage (host staging prep) | device (async dispatch) | writeback
-        # (device drain / D2H) or compute (host codec, host CRC) | write
-        # — how e2e numbers stay attributable and reader/device/writer
-        # overlap is provable per run
+        # per-stage busy thread-seconds: read | stage (host staging
+        # prep) | device (async dispatch) | writeback (device drain /
+        # D2H) or compute (host codec, host CRC) | write — how e2e
+        # numbers stay attributable and reader/device/writer overlap is
+        # provable per run; the queue, ring and latch waits between the
+        # stages are booked apart from them (_WAIT_BUSY)
         self.busy = {
             "read_s": 0.0,
             "stage_s": 0.0,
@@ -568,6 +631,7 @@ class _Op:
             "write_s": 0.0,
             **(extra_busy or {}),
             **_RESERVE_BUSY,
+            **_WAIT_BUSY,
         }
         self._lock = threading.Lock()
         self.book = functools.partial(_charge, self.busy, self._lock)
@@ -627,52 +691,78 @@ class _Op:
         phases = trace.Phases("ec.op.head", wall0)
 
         def reader():
-            with opened() as src:
-                while True:
-                    with claim_lock:
-                        item = next(claims, None)
-                    if item is None:
-                        return
-                    if prepare is not None:
-                        item = prepare(item)
-                    got = ring.acquire(pipe.stop)
-                    if got is None:
-                        return
-                    slot_id, buf = got
-                    t0 = time.perf_counter()
-                    with trace.annotation("ec.read"):
-                        staged = fill(src, item, buf)
-                    book("read_s", time.perf_counter() - t0)
-                    if not _q_put(read_q, (item, slot_id, staged), pipe.stop):
-                        ring.release(slot_id)
-                        return
+            slot_wait = q_wait = 0.0
+            try:
+                with opened() as src:
+                    while True:
+                        with claim_lock:
+                            item = next(claims, None)
+                        if item is None:
+                            return
+                        if prepare is not None:
+                            item = prepare(item)
+                        ta = time.perf_counter()
+                        got = ring.acquire(pipe.stop)
+                        if got is None:
+                            slot_wait += time.perf_counter() - ta
+                            return
+                        slot_id, buf = got
+                        t0 = time.perf_counter()
+                        slot_wait += t0 - ta
+                        with trace.annotation("ec.read"):
+                            staged = fill(src, item, buf)
+                        book("read_s", time.perf_counter() - t0)
+                        ta = time.perf_counter()
+                        put = _q_put(
+                            read_q, (item, slot_id, staged), pipe.stop,
+                            "ec.wait.read_q",
+                        )
+                        q_wait += time.perf_counter() - ta
+                        if not put:
+                            ring.release(slot_id)
+                            return
+            finally:
+                book("slot_wait_s", slot_wait)
+                book("read_q_wait_s", q_wait)
 
         def writer():
-            reservation.reserve(book)
-            while True:
-                got = _q_get(write_q, pipe.stop)
-                if got is _EOF or got is _STOPPED:
-                    return
-                item, slot_id, staged, handle = got
-                t0 = time.perf_counter()
-                result = fetch(item, staged, handle)
-                t1 = time.perf_counter()
-                with self._lock:
-                    last_fetch[0] = max(last_fetch[0], t1)
-                if checksum is not None:
-                    checksum(item, staged, result)
-                t2 = time.perf_counter()
-                if not reservation.wait():
-                    return
-                tw = time.perf_counter()
-                with trace.annotation("ec.write"):
-                    write(fds, item, staged, result)
-                t3 = time.perf_counter()
-                ring.release(slot_id)
-                book(fetch_charges, t1 - t0)
-                book("compute_s", t2 - t1)
-                book("write_s", t3 - tw)
+            work_wait = latch_wait = 0.0
+            try:
+                reservation.reserve(book)
+                while True:
+                    ta = time.perf_counter()
+                    got = _q_get(write_q, pipe.stop, "ec.wait.work")
+                    work_wait += time.perf_counter() - ta
+                    if got is _EOF or got is _STOPPED:
+                        return
+                    item, slot_id, staged, handle = got
+                    t0 = time.perf_counter()
+                    result = fetch(item, staged, handle)
+                    t1 = time.perf_counter()
+                    with self._lock:
+                        last_fetch[0] = max(last_fetch[0], t1)
+                    if checksum is not None:
+                        checksum(item, staged, result)
+                    t2 = time.perf_counter()
+                    reserved = reservation.wait()
+                    tw = time.perf_counter()
+                    latch_wait += tw - t2
+                    if not reserved:
+                        return
+                    with trace.annotation("ec.write"):
+                        write(fds, item, staged, result)
+                    t3 = time.perf_counter()
+                    ring.release(slot_id)
+                    book(fetch_charges, t1 - t0)
+                    book("compute_s", t2 - t1)
+                    book("write_s", t3 - tw)
+            finally:
+                book("work_wait_s", work_wait)
+                book("latch_wait_s", latch_wait)
 
+        # the dispatcher's own waits and its time in the plan's call:
+        # locals of the pacing thread, booked once when the loop is over
+        first_tile_wait = tile_wait = dispatch_call = window_wait = 0.0
         ok = False
         try:
             for path, _ in outputs:
@@ -687,20 +777,31 @@ class _Op:
                 pipe.spawn(writer, "writer")
             for _ in range(min(reader_threads, len(items))):
                 pipe.spawn(reader, "reader")
+            last = len(items) - 1
             for n in range(len(items)):
-                got = _q_get(read_q, pipe.stop)
+                ta = time.perf_counter()
+                got = _q_get(read_q, pipe.stop, "ec.wait.tile")
                 if got is _STOPPED:
                     break
                 item, slot_id, staged = got
                 t0 = time.perf_counter()
                 if n == 0:
+                    first_tile_wait = t0 - ta
                     phases.to("ec.op.dispatch", t0)
-                handle = dispatch(item, staged)
-                if n == len(items) - 1:
-                    phases.to("ec.op.drain")
-                if not _q_put(
-                    write_q, (item, slot_id, staged, handle), pipe.stop
-                ):
+                else:
+                    tile_wait += t0 - ta
+                work = (item, slot_id, staged, dispatch(item, staged))
+                if n == last:
+                    # the last call's end is the span's: one sample for
+                    # both, and this tile's put lies in ec.op.drain
+                    t1 = phases.to("ec.op.drain")
+                    put = _q_put(write_q, work, pipe.stop)
+                else:
+                    t1 = time.perf_counter()
+                    put = _q_put(write_q, work, pipe.stop, "ec.wait.window")
+                    window_wait += time.perf_counter() - t1
+                dispatch_call += t1 - t0
+                if not put:
                     break
             for _ in range(writer_threads):
                 if not _q_put(write_q, _EOF, pipe.stop):
@@ -728,6 +829,11 @@ class _Op:
                     # fsync + close syscalls
                     end = _close_phases(phases, busy)
                     _book_reserve_done(busy, reservation, wall0)
+                    # the pools are joined: the books are this thread's
+                    busy["first_tile_wait_s"] = first_tile_wait
+                    busy["tile_wait_s"] = tile_wait
+                    busy["dispatch_call_s"] = dispatch_call
+                    busy["window_wait_s"] = window_wait
                     out: dict = {}
                     _finish_stats(
                         out, busy, wall0, reader_threads, writer_threads, end

@@ -1133,6 +1133,15 @@ class VolumeServer:
             # staging-ring memory the operation allocated anew: 0 while
             # it ran on what an earlier operation gave back
             "ring_fresh_bytes",
+            # where the shell's threads stood waiting for one another:
+            # the readers for a ring slot and for room in the read
+            # queue, the dispatcher for its first and its later tiles,
+            # inside the plan's dispatch call, and for room in the
+            # in-flight window, the writers for work and at the latch
+            # (pools: thread-seconds; the dispatcher: wall seconds)
+            "slot_wait_s", "read_q_wait_s", "first_tile_wait_s",
+            "tile_wait_s", "dispatch_call_s", "window_wait_s",
+            "work_wait_s", "latch_wait_s",
         )
         wlog.info(
             "ec.%s vid=%s report=%s",

@@ -33,6 +33,7 @@ SMALL = 16 * 1024
 PHASE_FIELDS = ("head_s", "dispatch_span_s", "drain_s", "write_tail_s", "flush_s")
 PHASE_SPANS = tuple(ec_stream._OP_PHASES)
 DEVICE_FIELDS = tuple(ec_stream._DEVICE_BUSY)
+WAIT_FIELDS = tuple(ec_stream._WAIT_BUSY)
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -153,6 +154,35 @@ def test_phases_partition_the_wall(driver, tmp_path):
     assert 0 < stats["reserve_done_s"] <= stats["wall_s"]
 
 
+def _span_of_the_waits(stats: dict) -> float:
+    return stats["tile_wait_s"] + stats["dispatch_call_s"] + stats["window_wait_s"]
+
+
+@pytest.mark.parametrize("driver", sorted(DRIVERS))
+def test_dispatchers_waits_and_calls_close_on_the_span(driver, tmp_path):
+    """ISSUE 36: the dispatcher's waits for a tile and for the window and
+    its time inside the plan's dispatch call leave of dispatch_span_s
+    only the loop's own statements; every wait is on every driver's
+    line, 0.0 where nobody stood."""
+    stats = DRIVERS[driver](tmp_path)
+    for field in WAIT_FIELDS:
+        assert type(stats[field]) is float and stats[field] >= 0, field
+    # a device stage's two calls lie inside the plan's call (a host
+    # stage pair's dispatch hands the tile through: microseconds)
+    assert stats["dispatch_call_s"] >= (
+        stats.get("h2d_s", 0.0) + stats.get("launch_s", 0.0) - 5e-4
+    )
+    assert _span_of_the_waits(stats) == pytest.approx(
+        stats["dispatch_span_s"], rel=0.02, abs=2e-3
+    )
+    # the first tile's wait lies in the head, the pools' in their threads
+    assert stats["first_tile_wait_s"] <= stats["head_s"] + 1e-4
+    assert stats["work_wait_s"] <= stats["writer_threads"] * stats["wall_s"] + 1e-3
+    assert stats["slot_wait_s"] + stats["read_q_wait_s"] <= (
+        stats["reader_threads"] * stats["wall_s"] + 1e-3
+    )
+
+
 def test_phases_partition_an_aborted_operation(tmp_path):
     """A stage error skips phases; what was entered still sums to the
     wall, and the fields are all there."""
@@ -172,6 +202,9 @@ def test_phases_partition_an_aborted_operation(tmp_path):
     assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
         stats["wall_s"], abs=3.5e-4
     )
+    # the waits are on an aborted line too: 0.0 or what was booked
+    for field in WAIT_FIELDS:
+        assert stats[field] >= 0, field
 
 
 def test_phases_helper_shares_its_samples(ring):
@@ -243,12 +276,33 @@ def test_chunked_batch_adds_the_seconds_up(tmp_path, monkeypatch):
     seconds add up: over all chunks the phases still partition the wall
     and the device stage's split still reconciles."""
     monkeypatch.setenv("WEED_EC_PIPELINE_BATCH", "2")
+    chunks: list[dict] = []
+    real = ec_stream._stream_batch_chunk
+
+    def chunk(*args):
+        real(*args)
+        chunks.append(dict(args[5]))  # the chunk's own stats
+
+    monkeypatch.setattr(ec_stream, "_stream_batch_chunk", chunk)
     chunked = _encode_batch(tmp_path, volumes=4, rows=2)
     assert chunked["batch_volumes"] == 4
     assert sum(chunked[f] for f in PHASE_FIELDS) == pytest.approx(
         chunked["wall_s"], abs=7e-4
     )
     assert chunked["launch_s"] == pytest.approx(chunked["device_s"], abs=4e-4)
+    # the waits add up like every stage: two chunks' spans, two chunks'
+    # calls (the batch stage's own stage_s + device_s lie inside them)
+    assert _span_of_the_waits(chunked) == pytest.approx(
+        chunked["dispatch_span_s"], rel=0.02, abs=4e-3
+    )
+    assert chunked["dispatch_call_s"] >= (
+        chunked["stage_s"] + chunked["device_s"] - 4e-4
+    )
+    assert len(chunks) == 2
+    for field in WAIT_FIELDS:
+        assert chunked[field] == pytest.approx(
+            sum(c[field] for c in chunks), abs=1e-4
+        ), field
 
 
 @pytest.mark.parametrize("driver", ["single-host-pair", "rebuild-host-pair"])
@@ -459,6 +513,138 @@ def test_handler_span_report_line_and_publish(
         assert os.path.exists(base + ".ecx") and os.path.exists(base + ".ecc")
 
 
+def _ec_volume_less_a_shard(stub, vid: int, collection: str) -> None:
+    """The sealed volume taken through ec.encode to its end and served
+    as an EC volume, then shard 3 unmounted and deleted: what
+    `VolumeEcShardsRebuild` repairs (tests/test_ec_rebuild_cell.py)."""
+    stub.VolumeEcShardsGenerate(volume_pb2.VolumeEcShardsGenerateRequest(
+        volume_id=vid, collection=collection))
+    stub.VolumeEcShardsMount(volume_pb2.VolumeEcShardsMountRequest(
+        volume_id=vid, collection=collection, shard_ids=list(range(14))))
+    stub.VolumeDelete(volume_pb2.VolumeDeleteRequest(volume_id=vid))
+    time.sleep(0.5)  # a heartbeat: the master lists all 14 on this node
+    stub.VolumeEcShardsUnmount(volume_pb2.VolumeEcShardsUnmountRequest(
+        volume_id=vid, shard_ids=[3]))
+    stub.VolumeEcShardsDelete(volume_pb2.VolumeEcShardsDeleteRequest(
+        volume_id=vid, collection=collection, shard_ids=[3]))
+
+
+@pytest.mark.parametrize(
+    "verb,volumes", [("generate", 1), ("batch_generate", 2), ("rebuild", 1)]
+)
+def test_report_line_and_root_span_carry_the_waits(
+    verb, volumes, node, node_log, ring, stream_device_driver
+):
+    """ISSUE 36: the eight fields stand on the verb's ONE report line
+    and among the root span's `stages_ms` under the same names, and the
+    verb still leaves eight spans: a wait is never a span."""
+    master, vs = node
+    collection = f"wt{volumes}{verb[0]}"
+    vids = [_sealed_volume(master, vs, collection) for _ in range(volumes)]
+    with grpc.insecure_channel(f"127.0.0.1:{vs.grpc_port}") as ch:
+        stub = rpc.volume_stub(ch)
+        for vid in vids:
+            stub.VolumeMarkReadonly(volume_pb2.VolumeMarkReadonlyRequest(volume_id=vid))
+        if verb == "rebuild":
+            _ec_volume_less_a_shard(stub, vids[0], collection)
+        trace.reset()
+        del node_log[:]
+        if verb == "rebuild":
+            resp = stub.VolumeEcShardsRebuild(volume_pb2.VolumeEcShardsRebuildRequest(
+                volume_id=vids[0], collection=collection))
+            assert list(resp.rebuilt_shard_ids) == [3]
+        elif verb == "generate":
+            stub.VolumeEcShardsGenerate(volume_pb2.VolumeEcShardsGenerateRequest(
+                volume_id=vids[0], collection=collection))
+        else:
+            CALLS[verb](stub, vids, None)
+    spans = _recent()
+    assert len(spans) == 8
+    root = [s for s in spans if s["name"].startswith("ec_stream.")]
+    assert len(root) == 1
+    reports = _verb_reports("\n".join(node_log), verb)
+    assert len(reports) == 1
+    for field in WAIT_FIELDS:
+        assert field in reports[0], field
+        assert root[0]["stages_ms"][field] == pytest.approx(
+            reports[0][field] * 1e3, abs=0.11
+        ), field
+    assert _span_of_the_waits(reports[0]) == pytest.approx(
+        reports[0]["dispatch_span_s"], rel=0.02, abs=2e-3
+    )
+
+
+# --- the eight metrics that read the waits, as files (ISSUE 36) ---------------
+
+WAIT_METRICS = {
+    "reader_slot_wait_s_per_gib": "slot_wait_s",
+    "reader_queue_wait_s_per_gib": "read_q_wait_s",
+    "first_tile_wait_s_per_gib": "first_tile_wait_s",
+    "dispatcher_tile_wait_s_per_gib": "tile_wait_s",
+    "dispatcher_window_wait_s_per_gib": "window_wait_s",
+    "writer_work_wait_s_per_gib": "work_wait_s",
+    "writer_latch_wait_s_per_gib": "latch_wait_s",
+    "dispatch_span_unbooked_pct": None,
+}
+CELLS = ["encode-1g", "batch-encode-256m", "batch-encode-x4", "rebuild-1data",
+         "rack-rebuild-4lost"]
+
+
+@pytest.fixture(scope="module")
+def wait_report(tmp_path_factory) -> dict:
+    """A real operation's books as the node writes them on its report
+    line and as the benchmark's own parser reads them back."""
+    stats = _encode_single(tmp_path_factory.mktemp("waits"), host_pair=False)
+    handler = _Lines()
+    logger = logging.getLogger("seaweedfs_tpu")
+    logger.addHandler(handler)
+    try:
+        VolumeServer._log_ec_verb("generate", [1], stats)
+    finally:
+        logger.removeHandler(handler)
+    reports = _verb_reports("\n".join(handler.lines), "generate")
+    assert len(reports) == 1
+    return reports[0]
+
+
+@pytest.mark.parametrize("name", sorted(WAIT_METRICS))
+def test_wait_metric_file_manifest_entry_and_report_line(name, wait_report, monkeypatch):
+    """The file is its manifest entry word for word, all five cells
+    among it; it reads the hand-computed value from a real report line
+    through the readers that were there; and it reads nothing from the
+    lines of a program without the fields (the parent's)."""
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmark"))
+    import importlib
+
+    readers = importlib.import_module("harness.readers")
+    metric = readers.load_metric(name)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"] if m["name"] == name)
+    for key in entry:
+        assert metric[key] == entry[key], key
+    assert entry["workloads"] == CELLS
+    assert (entry["layer"], entry["source"], entry["moves"], entry["better"]) == (
+        "stream driver", "program_span", "ec_gbps", "lower")
+    assert "reads nothing" in metric["reads"] or "read nothing" in metric["reads"]
+
+    obs = {"reports": [wait_report, wait_report], "trace": None,
+           "window": {"seconds": 1.0, "gib": 0.5, "requests": 2}}
+    field = WAIT_METRICS[name]
+    if field is None:
+        assert entry["unit"] == "%"
+        want = 100 * (1 - _span_of_the_waits(wait_report) / wait_report["dispatch_span_s"])
+        assert abs(want) < 2 + 100 * 2e-3 / wait_report["dispatch_span_s"]
+    else:
+        assert entry["unit"] == "s/GiB" and field in wait_report
+        want = 2 * wait_report[field] / 0.5
+    assert readers.read_metric(metric, obs) == pytest.approx(want, abs=1e-9)
+
+    with open(os.path.join(REPO, "benchmark", "selftest", "node_log_phases.txt")) as f:
+        obs["reports"] = _verb_reports(f.read(), "generate")
+    assert obs["reports"] and "dispatch_span_s" in obs["reports"][0]
+    assert readers.read_metric(metric, obs) is None
+
+
 def _same_verb_twice(node, node_log, verb: str, volumes: int, family: str):
     """The verb twice on one node, each time on new sealed volumes:
     (the two report lines, the /metrics family's value before each call,
@@ -578,17 +764,77 @@ def test_annotations_reach_a_profiler_trace(driver, tmp_path):
     found = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))
     assert found
     on_host: dict[str, int] = {}
+    lines: list[set[str]] = []  # the ec.* names on each thread's line
     for plane in ProfileData.from_file(found[0]).planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith("ec."):
-                    on_host[ev.name] = on_host.get(ev.name, 0) + 1
+            names = [ev.name for ev in line.events if ev.name.startswith("ec.")]
+            for name in names:
+                on_host[name] = on_host.get(name, 0) + 1
+            if names:
+                lines.append(set(names))
     for name in PHASE_SPANS:
         assert on_host.get(name) == 1, (name, on_host)
     for name in ("ec.read", "ec.h2d", "ec.launch", "ec.writeback", "ec.write"):
         assert on_host.get(name) == 7, (name, on_host)  # one per tile
+    # the waits (ISSUE 36): an event only where the call blocked, on the
+    # line of the thread that stood. Some writer always stands for work
+    # (there are more of them than tiles are ready at once, and each ends
+    # in a wait for the end of the stream); no reader ever stands for a
+    # slot, because the ring has at least as many as this run has tiles
+    assert ec_stream._INFLIGHT + ec_stream.DEFAULT_WRITER_THREADS + 1 >= 7
+    assert on_host.get("ec.wait.work", 0) >= 1, on_host
+    assert "ec.wait.slot" not in on_host, on_host
+    waits = {n for n in on_host if n.startswith("ec.wait.")}
+    assert waits <= {"ec.wait.read_q", "ec.wait.tile", "ec.wait.window",
+                     "ec.wait.work", "ec.wait.latch"}, on_host
+    assert on_host.get("ec.wait.read_q", 0) <= 7 and on_host.get("ec.wait.tile", 0) <= 7
+    for names in lines:
+        if "ec.op.head" in names:  # the handler's thread, the dispatcher
+            assert not names & {"ec.wait.work", "ec.wait.latch", "ec.wait.read_q"}
+        elif "ec.read" in names:  # a reader
+            assert not names & {"ec.wait.work", "ec.wait.latch",
+                                "ec.wait.tile", "ec.wait.window"}
+        else:  # a writer
+            assert not names & {"ec.wait.read_q", "ec.wait.tile", "ec.wait.window"}
+
+
+def test_gap_reader_puts_device_gaps_down_to_host_annotations(tmp_path, capsys):
+    """`python -m seaweedfs_tpu.trace.gaps` on a trace made here: the
+    bursts of the CPU client's threads stand in for a chip's planes."""
+    import jax
+
+    from seaweedfs_tpu.trace import gaps
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=options)
+    try:
+        stats = _encode_single(tmp_path, host_pair=False)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    rep = gaps.report(found[0], min_gap_ms=0.0)
+    assert rep["device_planes"] == 1 and rep["bursts"] >= 2
+    assert 0 < rep["busy_s"] <= rep["span_s"]
+    assert rep["annotations"]["ec.read"][0] == 7
+    assert rep["annotations"]["ec.wait.work"][1] == pytest.approx(
+        stats["work_wait_s"], rel=0.25, abs=5e-3
+    )
+    (at, seconds), = rep["phases"]["ec.op.dispatch"]
+    assert seconds == pytest.approx(stats["dispatch_span_s"], abs=2e-3)
+    assert rep["gaps"] == sorted(rep["gaps"], key=lambda g: -g["seconds"])
+    for gap in rep["gaps"]:
+        # the phases lie end to end on ONE thread: they cover a gap once
+        on_handler = sum(s for name, s in gap["cover"].items() if name.startswith("ec.op."))
+        assert on_handler <= gap["seconds"] + 1e-6
+    covered = set().union(*(g["cover"] for g in rep["gaps"]))
+    assert "ec.op.dispatch" in covered
+    assert gaps.main([found[0], "0.5"]) == 0
+    out = capsys.readouterr().out
+    assert out.startswith("device busy ") and "ec.wait.work" in out
+    assert gaps.main([]) == 2
 
 
 def test_tracing_off_books_the_fields_and_annotates_nothing(tmp_path, monkeypatch, ring):
@@ -614,14 +860,20 @@ def test_tracing_off_books_the_fields_and_annotates_nothing(tmp_path, monkeypatc
         trace.set_enabled(True)
     assert opened == []
     assert _recent() == []
-    for field in PHASE_FIELDS + DEVICE_FIELDS:
+    for field in PHASE_FIELDS + DEVICE_FIELDS + WAIT_FIELDS:
         assert field in stats, field
     assert sum(stats[f] for f in PHASE_FIELDS) == pytest.approx(
         stats["wall_s"], abs=3.5e-4
     )
+    # the writers stood waiting for work, whoever was told of it
+    assert stats["work_wait_s"] > 0
+    assert _span_of_the_waits(stats) == pytest.approx(
+        stats["dispatch_span_s"], rel=0.02, abs=2e-3
+    )
     # and with it on, the same helper does open them
     _encode_single(tmp_path, host_pair=False)
     assert "ec.op.drain" in opened and "ec.writeback" in opened
+    assert "ec.wait.work" in opened
 
 
 def test_annotation_leaves_jax_alone_where_it_is_not_loaded():
