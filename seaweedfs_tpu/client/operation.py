@@ -19,6 +19,7 @@ import functools
 import http.client
 import json
 import socket
+import struct
 import threading
 import time
 import urllib.error
@@ -265,6 +266,21 @@ class _RawHTTPConnection:
         self.timeout = timeout
         self.sock.settimeout(timeout)
 
+    def block_for(self, timeout: float) -> None:
+        """Bound every socket operation by `timeout` in the KERNEL
+        (SO_RCVTIMEO / SO_SNDTIMEO) and leave the socket blocking, for
+        a caller that receives large bodies (read_response_into): a
+        socket with a Python timeout is non-blocking underneath, so a
+        body arrives a chunk a wake-up of the thread (a poll, a recv
+        and the interpreter lock each time), where a blocking recv
+        with MSG_WAITALL takes it in one call. An operation that runs
+        out of its time raises BlockingIOError, not TimeoutError."""
+        self.timeout = timeout
+        tv = struct.pack("ll", int(timeout), int(timeout % 1 * 1e6))
+        self.sock.settimeout(None)
+        self.sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVTIMEO, tv)
+        self.sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDTIMEO, tv)
+
     def close(self) -> None:
         try:
             self.sock.close()
@@ -292,8 +308,9 @@ class _RawHTTPConnection:
             raise http.client.IncompleteRead(data, n - len(data))
         return data
 
-    def read_response(self, method: str):
-        """(status, FastHeaders, body, will_close)."""
+    def _read_head(self):
+        """(status, FastHeaders, will_close) of the next final response
+        (100 Continue interims are passed over)."""
         from seaweedfs_tpu.util.httpd import FastHeaders
 
         while True:
@@ -331,6 +348,11 @@ class _RawHTTPConnection:
         will_close = conn_tok == "close" or (
             version == "HTTP/1.0" and conn_tok != "keep-alive"
         )
+        return status, headers, will_close
+
+    def read_response(self, method: str):
+        """(status, FastHeaders, body, will_close)."""
+        status, headers, will_close = self._read_head()
         body = b""
         if method != "HEAD" and status not in (204, 304):
             if "chunked" in headers.get("transfer-encoding", "").lower():
@@ -375,6 +397,31 @@ class _RawHTTPConnection:
                 body = self.rfile.read()
                 will_close = True
         return status, headers, body, will_close
+
+    def read_response_into(self, dest: memoryview):
+        """(status, FastHeaders, bytes placed, will_close) of a GET
+        whose 200 body belongs in the caller's memory: the body is
+        received straight into `dest` (socket to buffer, the kernel's
+        one copy) and may be shorter than it, never longer. Any other
+        status leaves its body unread and says will_close; a body cut
+        short raises IncompleteRead, as read_response does."""
+        status, headers, will_close = self._read_head()
+        if status != 200:
+            return status, headers, 0, True
+        try:
+            n = int(headers["content-length"])
+        except (KeyError, ValueError):
+            raise http.client.HTTPException(
+                "a 200 read into a buffer needs a Content-Length"
+            ) from None
+        if not 0 <= n <= len(dest):
+            raise http.client.HTTPException(
+                f"a body of {n} bytes for a buffer of {len(dest)}"
+            )
+        got = self.rfile.readinto(dest[:n])
+        if got != n:
+            raise http.client.IncompleteRead(b"", n - got)
+        return status, headers, got, will_close
 
 
 def _pooled_conn(netloc: str, timeout: float):

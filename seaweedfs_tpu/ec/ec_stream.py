@@ -34,9 +34,9 @@ A driver is a plan and its stage bodies, handed to _Op.run:
 Only the [4, N] parity crosses device->host on encode: the ten data
 shard files are byte copies of the blocks read from the .dat, written
 straight from the ring slot. The rebuild driver also takes REMOTE
-survivor readers (`remote_readers`: shard id -> fetch(offset, size)),
-which is how VolumeEcShardsRebuild overlaps a rack-wide gather with
-reconstruction.
+survivor readers (`remote_readers`: shard id -> read_into(offset,
+row of the ring slot)), which is how VolumeEcShardsRebuild overlaps a
+rack-wide gather with reconstruction.
 
 Role match: the 256 KB-batch loops at reference
 weed/storage/erasure_coding/ec_encoder.go:188-225 (encodeDatFile) and
@@ -1204,7 +1204,7 @@ def stream_rebuild_ec_files(
     | None = None,
     fetch_fn: Callable[["object"], np.ndarray] | None = None,
     stats: dict | None = None,
-    remote_readers: dict[int, Callable[[int, int], bytes]] | None = None,
+    remote_readers: dict[int, Callable[[int, np.ndarray], int]] | None = None,
     remote_report: Callable[[], dict] | None = None,
     writer_threads: int | None = None,
     reader_threads: int | None = None,
@@ -1226,16 +1226,20 @@ def stream_rebuild_ec_files(
     (device-fused where the stage supports the shape, host table CRC
     for donated ranges and odd tails, charged to compute_s).
 
-    remote_readers maps shard id → fetch(offset, size) -> bytes for
-    survivors that live on OTHER nodes: the reader pool pulls their
-    tiles over the wire in parallel with local preadv and the decode,
-    and shards readable remotely are treated as present (not rebuilt).
-    At least one survivor must be local — its file size fixes the tile
-    walk. Each remote fetch is one `ec.remote_read` annotation on the
-    thread that makes it and is booked under remote_read_s;
-    remote_report(), called once the pools are joined, gives what the
-    caller's readers have to add to the report line and the root
-    span's attributes (the volume server's: arbiter_wait_s).
+    remote_readers maps shard id → read_into(offset, dest) -> bytes
+    received for survivors that live on OTHER nodes, `dest` the
+    [g_len] u8 row of the ring slot the span belongs in: the reader
+    fills it where it lies (the gather neither joins nor copies), and
+    a count short of len(dest) is a truncated survivor. The reader
+    pool pulls their tiles over the wire in parallel with local preadv
+    and the decode, and shards readable remotely are treated as
+    present (not rebuilt). At least one survivor must be local — its
+    file size fixes the tile walk. Each remote fetch is one
+    `ec.remote_read` annotation on the thread that makes it and is
+    booked under remote_read_s; remote_report(), called once the pools
+    are joined, gives what the caller's readers have to add to the
+    report line and the root span's attributes (the volume server's:
+    arbiter_wait_s, remote_fetches, remote_fetches_dataplane).
 
     `session` (an ec.repair_session.RebuildSession) is the repair-
     bandwidth-frugal hookup: tiles degraded serving already decoded are
@@ -1324,22 +1328,24 @@ def stream_rebuild_ec_files(
             yield fds, fetch_pool
         finally:
             if fetch_pool is not None:
-                # wait for in-flight remote fetches: the caller closes
-                # the reader channels right after the driver returns,
-                # and an RPC still running on a pool thread would see
-                # its channel yanked (and leak the thread past return)
+                # wait for in-flight remote fetches: they write into
+                # ring slots, the caller closes the readers' connections
+                # and channels right after the driver returns, and a
+                # fetch still running on a pool thread would see its
+                # wire yanked (and leak the thread past return)
                 fetch_pool.shutdown(wait=True, cancel_futures=True)
             for fd in fds.values():
                 os.close(fd)
 
-    def remote_read(i: int, g_off: int, g_len: int) -> bytes:
-        """One span of one remote survivor, on a fetch-pool thread (the
-        reader's own where a lone remote survivor has no pool)."""
+    def remote_read(i: int, g_off: int, row: np.ndarray) -> int:
+        """One span of one remote survivor into its row of the slot, on
+        a fetch-pool thread (the reader's own where a lone remote
+        survivor has no pool)."""
         t0 = time.perf_counter()
         with trace.annotation("ec.remote_read"):
-            raw = remote_readers[i](g_off, g_len)
+            got = remote_readers[i](g_off, row)
         op.book("remote_read_s", time.perf_counter() - t0)
-        return raw
+        return got
 
     def gather(src, g_off: int, g_len: int, dest: np.ndarray) -> np.ndarray:
         """One [k, g_len] survivor read at g_off into a staging-ring
@@ -1350,7 +1356,7 @@ def stream_rebuild_ec_files(
         futures = {}
         if fetch_pool is not None:
             futures = {
-                j: fetch_pool.submit(remote_read, i, g_off, g_len)
+                j: fetch_pool.submit(remote_read, i, g_off, tile[j])
                 for j, i in enumerate(survivors)
                 if i not in fds
             }
@@ -1360,16 +1366,13 @@ def stream_rebuild_ec_files(
                 read_local.inc(got)
             else:
                 fut = futures.get(j)
-                raw = (
+                got = (
                     fut.result()
                     if fut is not None
-                    else remote_read(i, g_off, g_len)
+                    else remote_read(i, g_off, tile[j])
                 )
-                got = len(raw)
                 read_remote.inc(got)
                 gathered_remote.append(got)
-                if got == g_len:
-                    tile[j] = np.frombuffer(raw, dtype=np.uint8)
             if got != g_len:
                 raise ValueError(
                     f"ec shard {i} truncated: expected {g_len} at {g_off}"

@@ -73,10 +73,16 @@ def _reset_channel_cache() -> None:
             pass
 
 
+def tls_enabled() -> bool:
+    """True when the process speaks mTLS on its gRPC channels: cluster
+    traffic that must not leave by a plaintext wire beside them."""
+    return _TLS is not None and _TLS.is_enabled
+
+
 def dial(addr: str) -> grpc.Channel:
     """TLS channel when the process has TLS configured, else plaintext
     (the single seam every client-side channel goes through)."""
-    if _TLS is not None and _TLS.is_enabled:
+    if tls_enabled():
         from seaweedfs_tpu.security.tls import client_credentials
 
         options = []
@@ -90,7 +96,7 @@ def dial(addr: str) -> grpc.Channel:
 
 def add_port(server: grpc.Server, addr: str) -> None:
     """Bind a server port honoring the process TLS config."""
-    if _TLS is not None and _TLS.is_enabled:
+    if tls_enabled():
         from seaweedfs_tpu.security.tls import server_credentials
 
         server.add_secure_port(addr, server_credentials(_TLS))

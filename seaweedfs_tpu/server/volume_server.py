@@ -26,8 +26,10 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import http.client
 import json
 import os
+import select
 import threading
 import time
 from concurrent import futures
@@ -72,6 +74,32 @@ def _http_date(ts: int) -> str:
 
 
 COPY_CHUNK = 1024 * 1024
+
+
+def _sendfile_full(sock, fd: int, offset: int, count: int) -> int:
+    """os.sendfile of [offset, offset + count) of `fd` down `sock`,
+    resumed after short writes; a socket with a timeout is non-blocking
+    underneath, so a full send buffer is waited out for that long.
+    Returns the bytes sent: short of `count` only where the file ends
+    before the span does."""
+    out = sock.fileno()
+    timeout = sock.gettimeout()
+    poller = None
+    sent = 0
+    while sent < count:
+        try:
+            n = os.sendfile(out, fd, offset + sent, count - sent)
+        except BlockingIOError:
+            if poller is None:
+                poller = select.poll()
+                poller.register(out, select.POLLOUT)
+            if not poller.poll(None if timeout is None else timeout * 1000.0):
+                raise TimeoutError("sendfile: the peer takes no bytes") from None
+            continue
+        if n == 0:
+            break
+        sent += n
+    return sent
 
 
 def _needle_manifest_bytes(n: Needle) -> bytes:
@@ -1121,9 +1149,11 @@ class VolumeServer:
             # its rack gather: the survivors and bytes that crossed the
             # wire, the fetch pool's thread-seconds in those reads and,
             # inside them, their wait at the bandwidth arbiter; the
-            # rebuilt bytes written
+            # rebuilt bytes written; the remote spans fetched and those
+            # of them that came over the holders' HTTP data plane
             "remote_survivors", "survivor_bytes_remote", "remote_read_s",
             "arbiter_wait_s", "rebuilt_bytes",
+            "remote_fetches", "remote_fetches_dataplane",
             # the writer pool's thread-seconds reserving the shard files,
             # and the wall second at which the last of them was reserved
             "reserve_s", "reserve_done_s",
@@ -1476,16 +1506,31 @@ class VolumeServer:
             self._publish_ecc(base, dict(crcs))
 
     def _remote_rebuild_readers(self, vid: int, skip: set[int]):
-        """(readers, closer, report): shard id → fetch(offset, size)
-        callables over VolumeEcShardRead against holders learned from
-        the master, for survivors not in `skip` (the locally-present
-        set). One cached channel per holder — the stream driver's
-        reader pool calls these concurrently, and grpc channels are
-        thread-safe. report() is what the readers have to say of the
-        operation they served, for its report line and root span:
-        arbiter_wait_s, the seconds their reads stood at the bandwidth
-        arbiter (thread-seconds of the driver's fetch pool, inside its
-        remote_read_s)."""
+        """(readers, closer, report): shard id → read_into(offset, dest)
+        callables against holders learned from the master, for
+        survivors not in `skip` (the locally-present set); `dest` is the
+        row of the driver's ring slot the span belongs in, and the
+        return the bytes received there.
+
+        A span comes over the holder's HTTP data plane (GET
+        /ec/shard/read: sendfile there, recv_into `dest` here; one kept
+        connection per fetch thread and holder) and over
+        VolumeEcShardRead where that wire is not to be had, by what the
+        reader can see: a process whose gRPC is mTLS makes no HTTP
+        fetch; a holder that answers the route with 401 / 403 / 404 /
+        405 / 501 or refuses the connection is not asked again in this
+        operation; a fetch that fails on the data plane any other way
+        (a reset, a body cut short) is made again over the RPC, and a
+        timeout goes on to the shard's next url as an RPC's does. One
+        cached channel and Stub per holder — the stream driver's pools
+        call these concurrently, and grpc channels are thread-safe.
+
+        report() is what the readers have to say of the operation they
+        served, for its report line and root span: arbiter_wait_s, the
+        seconds their reads stood at the bandwidth arbiter
+        (thread-seconds of the driver's fetch pool, inside its
+        remote_read_s), remote_fetches, the spans they fetched, and
+        remote_fetches_dataplane, those of them that came over HTTP."""
         none = ({}, (lambda: None), dict)
         if not self.master:
             return none
@@ -1503,20 +1548,36 @@ class VolumeServer:
             urls = [l.url for l in entry.locations if l.url not in me]
             if urls and entry.shard_id not in skip:
                 locations[entry.shard_id] = urls
-        channels: dict[str, grpc.Channel] = {}
-        channels_lock = threading.Lock()
+        from seaweedfs_tpu.client.operation import _RawHTTPConnection
+        from seaweedfs_tpu.stats.metrics import EC_REMOTE_FETCH
 
-        def channel(url: str) -> grpc.Channel:
-            with channels_lock:
-                ch = channels.get(url)
-                if ch is None:
+        # one lock for the small maps below; no wire is touched
+        # under it but a channel's (lazy) construction
+        wires_lock = threading.Lock()
+        stubs: dict[str, tuple[grpc.Channel, rpc.Stub]] = {}
+        # (fetch thread, holder) -> its kept data-plane connection
+        conns: dict[tuple[int, str], _RawHTTPConnection] = {}
+        # holders that do not serve the route: RPC for the rest of the
+        # operation (every holder, where this process speaks mTLS)
+        rpc_only: set[str] = set()
+        no_dataplane = rpc.tls_enabled()
+        # holder -> set once its answer to the route is in: the first
+        # fetch to a holder asks for all that run beside it, so one that
+        # does not know the route is asked once, not once a fetch thread
+        asked: dict[str, threading.Event] = {}
+
+        def stub(url: str) -> rpc.Stub:
+            with wires_lock:
+                pair = stubs.get(url)
+                if pair is None:
                     host, _, port = url.partition(":")
-                    ch = channels[url] = rpc.dial(f"{host}:{int(port) + 10000}")
-                return ch
+                    ch = rpc.dial(f"{host}:{int(port) + 10000}")
+                    pair = stubs[url] = (ch, rpc.volume_stub(ch))
+                return pair[1]
 
-        # capture the trace context NOW: the stream driver's reader pool
-        # calls these from its own threads, where the contextvar span is
-        # not ambient — the captured metadata keeps remote-read spans
+        # capture the trace context NOW: the stream driver's fetch pools
+        # call these from their own threads, where the contextvar span is
+        # not ambient — the captured context keeps remote-read spans
         # parented under the rebuild span that built the readers
         md = trace.grpc_metadata()
         # ...and the ambient deadline the same way (docs/CHAOS.md): the
@@ -1526,12 +1587,101 @@ class VolumeServer:
         # survivor then fails the gather within the budget instead of
         # parking each read for the full per-op timeout
         factory_dl = _op_deadline.current()
-        # each read's wait at the arbiter (append is GIL-atomic: the
-        # reads run on the driver's pool threads)
+        # each read's wait at the arbiter, and the wire that carried it
+        # (append is GIL-atomic: the reads run on the driver's pool
+        # threads)
         arbiter_waits: list[float] = []
+        fetched: list[str] = []
+
+        def over_http(url, sid, offset, view, t_o, hop) -> int | None:
+            """The span over `url`'s data plane into `view`: the bytes
+            received, or None where the RPC is to take this fetch."""
+            key = (threading.get_ident(), url)
+            with wires_lock:
+                answered = asked.get(url)
+                if answered is None:
+                    asked[url] = threading.Event()
+            if answered is not None:
+                answered.wait(t_o)
+            try:
+                return fetch_http(key, url, sid, offset, view, t_o, hop)
+            finally:
+                if answered is None:
+                    asked[url].set()
+
+        def fetch_http(key, url, sid, offset, view, t_o, hop) -> int | None:
+            with wires_lock:
+                if url in rpc_only:
+                    return None
+                c = conns.get(key)
+            path = (
+                f"/ec/shard/read?volumeId={vid}&shard={sid}"
+                f"&offset={offset}&size={len(view)}"
+            )
+            while True:
+                reused = c is not None
+                try:
+                    if c is None:
+                        host, _, port = url.partition(":")
+                        c = _RawHTTPConnection(host, int(port), timeout=t_o)
+                        with wires_lock:
+                            conns[key] = c
+                    # a blocking socket under the kernel's timeouts: the
+                    # body then lands in one recv; t_o bounds the whole
+                    # fetch besides, as an RPC's timeout does
+                    c.block_for(t_o)
+                    c.rfile.deadline = _op_deadline.Deadline.after(t_o)
+                    c.send_request("GET", path, None, hop)
+                    status, _, got, will_close = c.read_response_into(view)
+                except (http.client.HTTPException, OSError) as e:
+                    refused = isinstance(e, ConnectionRefusedError)
+                    with wires_lock:
+                        conns.pop(key, None)
+                        if refused:
+                            rpc_only.add(url)
+                    if c is not None:
+                        c.close()
+                    if isinstance(e, (TimeoutError, BlockingIOError)):
+                        raise TimeoutError(f"ec shard {sid}@{url}: {e!r}") from e
+                    if reused and not refused and not isinstance(
+                        e, http.client.IncompleteRead
+                    ):
+                        # a kept connection the holder let go while it
+                        # idled: once more on a fresh one
+                        c = None
+                        continue
+                    return None
+                if will_close:
+                    with wires_lock:
+                        conns.pop(key, None)
+                    c.close()
+                if status == 200:
+                    return got
+                if status in (401, 403, 404, 405, 501):
+                    with wires_lock:
+                        rpc_only.add(url)
+                return None
+
+        def over_grpc(url, sid, offset, view, t_o, call_md) -> int:
+            got = 0
+            for r in stub(url).VolumeEcShardRead(
+                pb.VolumeEcShardReadRequest(
+                    volume_id=vid, shard_id=sid, offset=offset, size=len(view)
+                ),
+                timeout=t_o,
+                metadata=call_md,
+            ):
+                end = got + len(r.data)
+                if end > len(view):
+                    return end  # more than was asked for: not this span
+                view[got:end] = r.data
+                got = end
+            return got
 
         def make_reader(sid: int, urls: list[str]):
-            def read(offset: int, size: int) -> bytes:
+            def read_into(offset: int, dest) -> int:
+                view = memoryview(dest).cast("B")
+                size = len(view)
                 # rebuild traffic pays the bandwidth arbiter before
                 # pulling remote bytes — max-min share against
                 # replication/handoff/tier, yielding to foreground
@@ -1552,41 +1702,50 @@ class VolumeServer:
                          factory_dl.header_value()),
                     )
                 for url in urls:
+                    wire = "http"
                     try:
-                        data = b"".join(
-                            r.data
-                            for r in rpc.volume_stub(channel(url)).VolumeEcShardRead(
-                                pb.VolumeEcShardReadRequest(
-                                    volume_id=vid,
-                                    shard_id=sid,
-                                    offset=offset,
-                                    size=size,
-                                ),
-                                timeout=t_o,
-                                metadata=call_md,
+                        got = (
+                            None
+                            if no_dataplane
+                            else over_http(
+                                url, sid, offset, view, t_o, dict(call_md or ())
                             )
                         )
-                    except grpc.RpcError as e:
+                        if got is None:
+                            wire = "grpc"
+                            got = over_grpc(url, sid, offset, view, t_o, call_md)
+                    except (grpc.RpcError, TimeoutError) as e:
                         last = e
                         continue
-                    if len(data) == size:
-                        return data
+                    if got == size:
+                        fetched.append(wire)
+                        EC_REMOTE_FETCH.labels(wire).inc()
+                        return got
                     last = ValueError(
-                        f"shard {sid}@{url} returned {len(data)} of {size} "
+                        f"shard {sid}@{url} returned {got} of {size} "
                         f"bytes at {offset}"
                     )
                 raise last or ValueError(f"no holder for ec shard {sid}")
 
-            return read
+            return read_into
 
         def closer() -> None:
-            for ch in channels.values():
+            for c in conns.values():
+                c.close()
+            for ch, _ in stubs.values():
                 ch.close()
+
+        def report() -> dict:
+            return {
+                "arbiter_wait_s": round(sum(arbiter_waits), 4),
+                "remote_fetches": len(fetched),
+                "remote_fetches_dataplane": fetched.count("http"),
+            }
 
         return (
             {sid: make_reader(sid, urls) for sid, urls in locations.items()},
             closer,
-            lambda: {"arbiter_wait_s": round(sum(arbiter_waits), 4)},
+            report,
         )
 
     def VolumeEcShardsCopy(self, req: pb.VolumeEcShardsCopyRequest, context):
@@ -1666,6 +1825,7 @@ class VolumeServer:
             if sp:
                 sp.annotate("vid", req.volume_id)
                 sp.annotate("shard", req.shard_id)
+                sp.annotate("transport", "grpc")
             if req.file_key:
                 # tombstone check against .ecj-backed index state
                 try:
@@ -2191,6 +2351,8 @@ class VolumeServer:
                     return self._json(
                         {"volumeId": vid, "shard": sid, "quarantined": ok}
                     )
+                if url_path == "/ec/shard/read":
+                    return self._serve_ec_shard_span()
                 if url_path == "/metrics":
                     from seaweedfs_tpu.stats.metrics import DEFAULT_REGISTRY
 
@@ -2411,6 +2573,72 @@ class VolumeServer:
                 self._serve_maybe_ranged(data, headers)
                 stages["send"] = time.perf_counter() - now_pc
                 req_span.add_stages(stages)
+
+            def _serve_ec_shard_span(self):
+                """GET /ec/shard/read?volumeId=&shard=&offset=&size=: one
+                span of a mounted EC shard file, file to socket in the
+                kernel. What VolumeEcShardRead's plain branch does, over
+                the data plane, for the one caller that moves whole
+                shard files: a rack repair's gather
+                (_remote_rebuild_readers), which falls back to the RPC
+                on a 404 from here. The span clamps to the shard as the
+                RPC's does. 404: no such volume or shard mounted here, a
+                tiered-away shard (the RPC streams those from the
+                backend), a fileKey (the tombstone check is the needle
+                path's), a process whose cluster traffic is mTLS (the
+                bytes then leave by that wire alone). 401: the server
+                has a white list and the peer is not on it. An expired
+                x-weed-deadline never gets here (the funnel's 504). Not
+                foreground serving: no arbiter stamp, no admission
+                charge (admission_exempt): the caller's arbiter is the
+                budget, as for the RPC. The funnel's span of the request
+                IS the read's span: parent from the caller's
+                X-Weed-Trace, named and annotated as the RPC's."""
+                q = fast_query(self.path.partition("?")[2])
+                try:
+                    vid, sid = int(q.get("volumeId", "")), int(q.get("shard", ""))
+                    offset, size = int(q.get("offset", "0")), int(q.get("size", "0"))
+                except ValueError:
+                    return self._json({"error": "bad span"}, 400)
+                if offset < 0 or size < 0:
+                    return self._json({"error": "bad span"}, 400)
+                sp = getattr(self, "_trace_span", None)
+                if sp is not None:
+                    sp.name = "volume.ec_shard_read"
+                    sp.nbytes = size
+                    sp.annotate("vid", vid)
+                    sp.annotate("shard", sid)
+                    sp.annotate("transport", "http")
+                guard = server.guard
+                if (
+                    guard is not None
+                    and guard.white_list
+                    and not guard.white_list_ok(self.client_address[0])
+                ):
+                    return self._json({"error": "not in the white list"}, 401)
+                ev = server.store.find_ec_volume(vid)
+                shard = ev.shards.get(sid) if ev is not None else None
+                if shard is None or q.get("fileKey") or rpc.tls_enabled():
+                    return self._json(
+                        {"error": f"ec shard {vid}.{sid} is not served here"}, 404
+                    )
+                count = min(size, max(0, shard.size - offset))
+                head = b"HTTP/1.1 200 OK\r\nContent-Type: application/octet-stream\r\n"
+                if self.close_connection:
+                    head += b"Connection: close\r\n"
+                self._trace_status = 200
+                try:
+                    fd = shard._f.fileno()
+                    self.wfile.write(head + b"Content-Length: %d\r\n\r\n" % count)
+                    sent = _sendfile_full(self.connection, fd, offset, count)
+                except (OSError, ValueError):
+                    # the shard's fd closed under the read (an unmount, a
+                    # quarantine) or the peer went away
+                    sent = -1
+                if sent != count:
+                    # a body cut short: the client's fetch fails and
+                    # nothing more may go down this connection
+                    self.close_connection = True
 
             def _serve_maybe_ranged(self, data: bytes, headers: dict):
                 """Full 200 or single-range 206 per the Range header
@@ -3038,6 +3266,9 @@ class VolumeServer:
         # load signal) and runs per-client admission when configured
         self._http_server.load_tracker = self.load
         self._http_server.admission = self.admission
+        # a repair's shard spans answer to the rebuilder's arbiter, as
+        # the VolumeEcShardRead they stand in for does
+        self._http_server.admission_exempt = frozenset({"/ec/shard/read"})
         threading.Thread(target=self._http_server.serve_forever, daemon=True).start()
         if self.internal_port:
             self._internal_server = WeedHTTPServer(

@@ -454,6 +454,13 @@ EC_REPAIR_BYTES_READ = DEFAULT_REGISTRY.counter(
     "survivor bytes gathered by EC rebuild, by where they came from",
     ("source",),  # source: local | remote
 )
+EC_REMOTE_FETCH = DEFAULT_REGISTRY.counter(
+    "weed_ec_remote_fetch_total",
+    "remote survivor spans an EC rebuild on this node fetched, by the "
+    "wire that carried them: the holder's HTTP data plane "
+    "(/ec/shard/read) or VolumeEcShardRead",
+    ("transport",),  # transport: http | grpc
+)
 EC_REPAIR_BYTES_WRITTEN = DEFAULT_REGISTRY.counter(
     "weed_ec_repair_bytes_written_total",
     "rebuilt shard bytes written by EC rebuild",

@@ -276,12 +276,18 @@ class _BufReader:
         self.deadline = None
         self.op_timeout = None
 
-    def _fill(self) -> bool:
+    def _arm(self) -> None:
         dl = self.deadline
         if dl is not None:
             # raises DeadlineExceeded once the whole-request budget is
             # spent; otherwise shrinks this recv's window to what's left
-            self._sock.settimeout(dl.cap(self.op_timeout))
+            # (a blocking socket keeps the kernel timeouts its owner set)
+            window = dl.cap(self.op_timeout)
+            if self._sock.gettimeout() is not None:
+                self._sock.settimeout(window)
+
+    def _fill(self) -> bool:
+        self._arm()
         chunk = self._sock.recv(65536)
         if not chunk:
             return False
@@ -333,6 +339,29 @@ class _BufReader:
         self._pos += len(out)
         self.consumed += len(out)
         return out
+
+    def readinto(self, view: memoryview) -> int:
+        """Fill `view` as far as the peer sends: what the buffer holds
+        first, then recv_into straight off the socket, so a large body
+        lands in the caller's memory with no copy of it made here.
+        MSG_WAITALL: on a BLOCKING socket the kernel keeps the call
+        until the view is full, one wake-up of this thread a body; a
+        socket with a Python timeout is non-blocking underneath and
+        gets a chunk a wake-up either way. Returns the bytes placed;
+        short of len(view) means EOF."""
+        n = len(view)
+        got = min(n, len(self._buf) - self._pos)
+        if got:
+            view[:got] = self._buf[self._pos : self._pos + got]
+            self._pos += got
+        while got < n:
+            self._arm()
+            r = self._sock.recv_into(view[got:], 0, socket.MSG_WAITALL)
+            if not r:
+                break
+            got += r
+        self.consumed += got
+        return got
 
     def readline(self, limit: int = 65537) -> bytes:
         while True:
@@ -544,11 +573,19 @@ def serve_connection(
         ddl_default = _deadline.default_budget_s()
     ddl_hdr_key = _deadline.DEADLINE_HEADER
     if admission is not None or load_tracker is not None:
+        # routes that are not foreground serving and answer to a budget
+        # of their caller's (a repair's shard spans: the bandwidth
+        # arbiter) pass the bucket by
+        adm_exempt = getattr(server, "admission_exempt", ())
+
         def qos_dispatch(method, h, _adm=admission, _lt=load_tracker):
             if _lt is not None:
                 _lt.enter()
             try:
-                if _adm is not None:
+                if _adm is not None and (
+                    not adm_exempt
+                    or h.path.partition("?")[0] not in adm_exempt
+                ):
                     return _adm.gate(method, h)
                 return method(h)
             finally:
@@ -930,6 +967,8 @@ class WeedHTTPServer(ThreadingHTTPServer):
     # Retry-After) and/or a qos.LoadTracker (in-flight count for the
     # heartbeat load signal); None = today's behavior
     admission = None
+    # bare paths the admission gate lets by uncharged (serve_connection)
+    admission_exempt: frozenset = frozenset()
     load_tracker = None
 
     # deadline plane (docs/CHAOS.md): budget (seconds) minted at entry

@@ -407,9 +407,12 @@ class TestRepairSession:
         remote = {}
         for i in (10, 11, 12, 13):
             os.remove(base + ec_files.to_ext(i))
-            remote[i] = (
-                lambda off, size, data=shard_bytes[i]: data[off : off + size]
-            )
+            def read_into(off, dest, data=shard_bytes[i]):
+                got = data[off : off + len(dest)]
+                memoryview(dest)[: len(got)] = got
+                return len(got)
+
+            remote[i] = read_into
         rl0 = EC_REPAIR_BYTES_READ.value("local")
         rr0 = EC_REPAIR_BYTES_READ.value("remote")
         w0 = EC_REPAIR_BYTES_WRITTEN.value()

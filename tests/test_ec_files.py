@@ -581,12 +581,12 @@ class TestStreamDrivers:
         def make_reader(sid):
             path = str(remote_dir / f"1{ec_files.to_ext(sid)}")
 
-            def read(offset, size):
+            def read_into(offset, dest):
                 with open(path, "rb") as f:
                     f.seek(offset)
-                    return f.read(size)
+                    return f.readinto(dest)
 
-            return read
+            return read_into
 
         _, rebuild_fn, fetch = self._cpu_stages()
         rebuilt = ec_stream.stream_rebuild_ec_files(

@@ -44,7 +44,7 @@ CELL, CONFIG, MIX = "rack-rebuild-4lost", "rack-rebuild-1g", "rack-rebuild-loop"
 SHARD_BYTES = 3 * MIB
 PHASE_FIELDS = ("head_s", "dispatch_span_s", "drain_s", "write_tail_s", "flush_s")
 GATHER_FIELDS = ("remote_survivors", "survivor_bytes_remote", "rebuilt_bytes",
-                 "arbiter_wait_s")
+                 "arbiter_wait_s", "remote_fetches", "remote_fetches_dataplane")
 
 
 def _json(*parts: str) -> dict:
@@ -303,6 +303,8 @@ def test_report_line_carries_the_gather(report):
     assert report["survivor_bytes_remote"] == 6 * SHARD_BYTES
     assert report["rebuilt_bytes"] == 4 * SHARD_BYTES
     assert report["remote_read_s"] > 0
+    # six remote survivors, two spans, every one over the holders' data plane
+    assert report["remote_fetches"] == report["remote_fetches_dataplane"] == 12
     # inside the fetch pool's seconds; with room in the budget, next to nothing
     assert 0 <= report["arbiter_wait_s"] <= report["remote_read_s"]
     # one 3 MiB tile, dispatched as its 2 MiB and 1 MiB spans
@@ -363,6 +365,8 @@ METRICS = {
     "remote_read_s_per_gib": lambda rep: rep["remote_read_s"] / GIB,
     "remote_bytes_per_rebuilt_byte": lambda rep: 1.5,
     "arbiter_wait_s_per_gib": lambda rep: rep["arbiter_wait_s"] / GIB,
+    "remote_dataplane_fetch_pct": lambda rep: (
+        100.0 * rep["remote_fetches_dataplane"] / rep["remote_fetches"]),
 }
 JOINED = [
     "rebuild_kernel_roofline", "rebuild_swar_roofline", "device_idle_pct.ec",
