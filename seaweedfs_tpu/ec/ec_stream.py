@@ -536,9 +536,9 @@ _OP_PHASES = {
 # What the device stages book, beside the pool stages and in
 # thread-seconds like them: the dispatcher's time in the transfer call
 # and in the jitted call. In the single-volume drivers (encode and
-# rebuild) h2d_s + launch_s is device_s; the batch encode driver's
-# transfer is part of its stage_s and launch_s == device_s. Host stage
-# pairs and the batch rebuild's mesh stage book neither.
+# rebuild) h2d_s + launch_s is device_s; the batch drivers' transfer
+# (encode and rebuild) is part of their stage_s and launch_s ==
+# device_s. Host stage pairs book neither.
 _DEVICE_BUSY = {"h2d_s": 0.0, "launch_s": 0.0}
 
 # What the single-volume rebuild books for its rack gather, a pool
@@ -2241,6 +2241,17 @@ def stream_rebuild_ec_files_batch(
     lists in base_file_names order; volumes with nothing missing
     return [].
 
+    `tile_bytes` defaults to DEFAULT_TILE_BYTES // 2 (512 KiB a
+    survivor row): a CPU-sandbox record's number (BENCH_r12), with no
+    chip reading behind it yet. The benchmark cell `batch-rebuild-2lost`
+    runs this driver on the chip (`rebuild_launches_per_gib`,
+    `rebuild_dispatcher_busy_pct`): a change of the tile is judged there.
+
+    `stats` gets the stage seconds of every group summed, `tiles` and
+    `survivor_bytes` summed, `survivors` / `targets` / `mesh` of the last
+    group, `batch_volumes` and `batch_groups` (damage signatures: decode
+    programs the call ran); each chunk's root span carries the same.
+
     `durable=True` fsyncs every rebuilt shard before returning; a
     failed chunk removes ALL its volumes' target files (the abort
     contract scrub relies on: no partial rebuilt shard survives)."""
@@ -2343,6 +2354,7 @@ def stream_rebuild_ec_files_batch(
                 [base_file_names[i] for i in chunk],
                 chunk_codec, survivors, targets, tile_bytes, chunk_stats,
                 durable, want_crcs, reader_threads, writer_threads,
+                groups=len(groups),
             )
             for i in chunk:
                 results[i] = list(targets)
@@ -2386,22 +2398,39 @@ def _rebuild_shape(tiles: int, survivors, targets, survivor_bytes: int) -> dict:
     }
 
 
+def _report_batch_rebuild(
+    out: dict, sp, volumes: int, groups: int, shape: dict
+) -> None:
+    """A batch rebuild chunk's shape on its stats and on its root span,
+    under the same names: the volumes it stacked, the call's damage
+    signatures, and _rebuild_shape's four."""
+    shape = {"batch_volumes": volumes, **shape}
+    out.update(shape)
+    sp.annotate("batch_groups", groups)
+    for key, value in shape.items():
+        sp.annotate(key, value)
+
+
 def _rebuild_batch_chunk(
     bases: list[str], codec, survivors: tuple[int, ...],
     targets: tuple[int, ...], tile_bytes, stats, durable, want_crcs,
-    reader_threads, writer_threads,
+    reader_threads, writer_threads, groups: int,
 ) -> None:
     """One (survivors, targets)-homogeneous chunk through the mesh:
     the rebuild-side mirror of _stream_batch_chunk. Reads [k, step]
     survivor tiles per volume into a [B, k, W] staging slot, runs
     reconstruct_batch_u32 once per round, pwrites the rebuilt target
     rows. Same abort contract: any failure removes every volume's
-    target files."""
+    target files. `groups` is the call's count of damage signatures,
+    for the root span."""
     from seaweedfs_tpu.ec.ec_files import to_ext
 
-    # local rebuilds want the fine tile (BENCH_r12: more in-flight
-    # preads to overlap, page-cache-friendly spans) — and the batch arm
-    # is local-survivor-only by contract
+    # local rebuilds want the fine tile (more in-flight preads to
+    # overlap, page-cache-friendly spans), and the batch arm is
+    # local-survivor-only by contract. The number rests on a CPU-sandbox
+    # record (BENCH_r12) and on no chip reading yet: the single-volume
+    # driver's tile went from this to REBUILD_TILE_BYTES once the chip
+    # was asked. The cell batch-rebuild-2lost is where this one is.
     tile_bytes = tile_bytes or DEFAULT_TILE_BYTES // 2
     b = len(bases)
     sizes = [
@@ -2428,7 +2457,7 @@ def _rebuild_batch_chunk(
     if host:
         return _rebuild_batch_chunk_host(
             bases, codec.rs, survivors, targets, sizes, outputs, tile_bytes,
-            stats, durable, want_crcs, reader_threads, writer_threads,
+            stats, durable, want_crcs, reader_threads, writer_threads, groups,
         )
     rounds = max(-(-size // tile_bytes) for size in sizes)
     step_of = [
@@ -2443,7 +2472,7 @@ def _rebuild_batch_chunk(
     )
     round_crcs: list = [None] * rounds
     held = vol_axis * stripe  # see _stream_batch_chunk
-    op = _Op("ec_stream.rebuild_batch", True)
+    op = _Op("ec_stream.rebuild_batch", True, _DEVICE_BUSY)
 
     def fill(src, r, buf):
         buf3 = buf[: b * DATA_SHARDS * width].reshape(b, DATA_SHARDS, width)
@@ -2456,13 +2485,20 @@ def _rebuild_batch_chunk(
     def dispatch(r, buf3):
         nonlocal held
         t0 = time.perf_counter()
-        vols = codec.shard_volumes(buf3.view(np.uint32))
+        with trace.annotation("ec.h2d"):
+            vols = codec.shard_volumes(buf3.view(np.uint32))
+        th = time.perf_counter()
         held = min(held, codec.devices_holding(vols))
         t1 = time.perf_counter()
-        handle = codec.reconstruct_batch_u32(survivors, targets, vols)
+        with trace.annotation("ec.launch"):
+            handle = codec.reconstruct_batch_u32(survivors, targets, vols)
         t2 = time.perf_counter()
         op.book("stage_s", t1 - t0)
         op.book("device_s", t2 - t1)
+        # as the mesh encode stage: the transfer is part of the stage,
+        # the launch is all of device_s
+        op.book("h2d_s", th - t0)
+        op.book("launch_s", t2 - t1)
         return handle
 
     def fetch(r, buf3, handle):
@@ -2503,11 +2539,11 @@ def _rebuild_batch_chunk(
                 EC_REPAIR_BYTES_WRITTEN.inc(step)
 
     def report(out, sp, whole):
-        out["batch_volumes"] = b
         out["mesh"] = {**codec.report(), "devices_per_round": held}
-        out.update(
-            _rebuild_shape(rounds, survivors, targets, DATA_SHARDS * sum(sizes))
-        )
+        sp.annotate("mesh", f"{vol_axis}x{stripe}")
+        _report_batch_rebuild(out, sp, b, groups, _rebuild_shape(
+            rounds, survivors, targets, DATA_SHARDS * sum(sizes)
+        ))
         if want_crcs and whole:
             out["shard_crcs"] = _fold_round_crcs(
                 b, targets, step_of, round_crcs
@@ -2543,7 +2579,7 @@ def _rebuild_batch_chunk_host(
     bases: list[str], rs, survivors: tuple[int, ...],
     targets: tuple[int, ...], sizes: list[int],
     outputs: list[tuple[str, int]], tile_bytes: int, stats, durable,
-    want_crcs, reader_threads, writer_threads,
+    want_crcs, reader_threads, writer_threads, groups: int,
 ) -> None:
     """Host arm of the batch rebuild: one shared pipeline whose work
     items are per-(volume, tile) survivor gathers, decoded in the
@@ -2601,13 +2637,10 @@ def _rebuild_batch_chunk_host(
             EC_REPAIR_BYTES_WRITTEN.inc(tile.shape[1])
 
     def report(out, sp, whole):
-        out["batch_volumes"] = b
         out["codec_arm"] = "host"
-        out.update(
-            _rebuild_shape(
-                len(items), survivors, targets, DATA_SHARDS * sum(sizes)
-            )
-        )
+        _report_batch_rebuild(out, sp, b, groups, _rebuild_shape(
+            len(items), survivors, targets, DATA_SHARDS * sum(sizes)
+        ))
         if want_crcs and whole:
             out["shard_crcs"] = _fold_host_batch_crcs(b, targets, crc_parts)
 

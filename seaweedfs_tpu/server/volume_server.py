@@ -1136,7 +1136,8 @@ class VolumeServer:
         ... flush_s) partition wall_s, and publish_s follows it."""
         keys = (
             "driver", "arms", "mesh", "mesh_devices", "fallback",
-            "codec_arm", "batch_volumes", "read_s", "stage_s", "device_s",
+            "codec_arm", "batch_volumes", "batch_groups", "fell_through",
+            "read_s", "stage_s", "device_s",
             "writeback_s", "compute_s", "write_s", "encode_s", "wall_s",
             # serial phases of the operation on the handler's thread
             "head_s", "dispatch_span_s", "drain_s", "write_tail_s",
@@ -1304,8 +1305,10 @@ class VolumeServer:
                 sp.annotate("vid", req.volume_id)
             return self._ec_shards_rebuild(req, context)
 
-    def _ec_shards_rebuild(self, req, context):
-        base = self._base_name(req.collection, req.volume_id)
+    def _ec_shards_rebuild(self, req, context, base: str | None = None):
+        """`base` where the caller has found the volume's files already
+        (the batch verb, whose request names no collection)."""
+        base = base or self._base_name(req.collection, req.volume_id)
         present, missing = ec_files.shard_presence(base)
         if not missing or not self.master:
             st: dict = {}
@@ -1392,7 +1395,17 @@ class VolumeServer:
         single-volume rebuild path per volume, so the verb is safe to
         aim at any mix. Reuses the BatchGenerate message pair: ids in,
         empty response (rebuilt ids are logged; callers recompute
-        presence, as ec.rebuild already does)."""
+        presence, as ec.rebuild already does).
+
+        ONE `ec.batch_rebuild` report line a call, written after the
+        publish whatever the mix was: the batch driver's books
+        (`batch_volumes`, `batch_groups`, the rebuild's shape, `h2d_s` /
+        `launch_s`, the waits), `lookup_s`, the seconds of the
+        per-volume master lookups that sorted the volumes, and
+        `fell_through`, the volumes sent down the single-volume path
+        (each leaves an `ec.rebuild` line of its own; `batch_volumes` 0
+        where all did). The last two are attributes of the
+        `volume.ec_rebuild_batch` span too."""
         with trace.span(
             "volume.ec_rebuild_batch",
             header=trace.header_from_grpc_context(context),
@@ -1401,6 +1414,7 @@ class VolumeServer:
             if sp:
                 sp.annotate("vids", list(req.volume_ids))
             batch: list[tuple[int, str]] = []
+            lookup_s, fell_through = 0.0, 0
             for vid in req.volume_ids:
                 ev = self.store.find_ec_volume(vid)
                 base = (
@@ -1411,19 +1425,23 @@ class VolumeServer:
                 present, missing = ec_files.shard_presence(base)
                 if not missing:
                     continue
+                t0 = time.perf_counter()
                 remote = self._cluster_present_shards(vid)
+                lookup_s += time.perf_counter() - t0
                 if (
                     sum(present) >= ec_files.DATA_SHARDS
                     and not (set(missing) & remote)
                 ):
                     batch.append((vid, base))
                 else:
+                    fell_through += 1
                     self._ec_shards_rebuild(
                         pb.VolumeEcShardsRebuildRequest(volume_id=vid),
                         context,
+                        base=base,
                     )
+            st: dict = {"batch_volumes": 0, "batch_groups": 0}
             if batch:
-                st: dict = {}
                 try:
                     ec_files.rebuild_ec_files_batch(
                         [base for _, base in batch],
@@ -1436,13 +1454,18 @@ class VolumeServer:
                     context.abort(
                         grpc.StatusCode.FAILED_PRECONDITION, str(e)
                     )
-                with self._ec_publish(
-                    "batch_rebuild", [vid for vid, _ in batch], st
+            st["lookup_s"] = round(lookup_s, 4)
+            st["fell_through"] = fell_through
+            if sp:
+                sp.annotate("lookup_s", st["lookup_s"])
+                sp.annotate("fell_through", fell_through)
+            with self._ec_publish(
+                "batch_rebuild", list(req.volume_ids), st
+            ):
+                for (vid, base), crcs in zip(
+                    batch, st.get("shard_crcs") or []
                 ):
-                    for (vid, base), crcs in zip(
-                        batch, st.get("shard_crcs") or []
-                    ):
-                        self._log_rebuild_crcs(vid, base, {"shard_crcs": crcs})
+                    self._log_rebuild_crcs(vid, base, {"shard_crcs": crcs})
         return pb.VolumeEcShardsBatchGenerateResponse()
 
     def _cluster_present_shards(self, vid: int) -> set[int]:

@@ -102,3 +102,19 @@ def native_post_toolchain():
         write_path._needle_ext, "post"
     ):
         pytest.skip("no C toolchain: native needle_ext.post unavailable")
+
+
+@pytest.fixture
+def one_bench_rehearsal_at_a_time():
+    """A `benchmark/run.py --rehearse` child keeps its run's files in
+    ONE directory per checkout, which it empties first, and picks its
+    node's ports from the bottom of one fixed range: two at once (two
+    cells' test files on two xdist workers) take each other's files and
+    ports. The rehearsals of every cell's tests hold this while they run."""
+    import fcntl
+    import tempfile
+
+    path = os.path.join(tempfile.gettempdir(), "tpu-weed-bench-rehearsal.lock")
+    with open(path, "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        yield

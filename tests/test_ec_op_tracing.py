@@ -621,8 +621,12 @@ def test_wait_metric_file_manifest_entry_and_report_line(name, wait_report, monk
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == name)
     for key in entry:
-        assert metric[key] == entry[key], key
-    assert entry["workloads"] == CELLS
+        if key != "workloads":
+            assert metric[key] == entry[key], key
+    # a cell joins a metric by the manifest's list (run.py:per_layer): the
+    # file's copy is ISSUE 36's five, and later cells (ISSUE 38's
+    # batch-rebuild-2lost) are appended to the manifest alone
+    assert metric["workloads"] == CELLS == entry["workloads"][:len(CELLS)]
     assert (entry["layer"], entry["source"], entry["moves"], entry["better"]) == (
         "stream driver", "program_span", "ec_gbps", "lower")
     assert "reads nothing" in metric["reads"] or "read nothing" in metric["reads"]

@@ -385,17 +385,16 @@ def _observed(reports: list[dict]) -> dict:
 
 def test_manifest_entries_of_the_cell(config):
     manifest = _json("BENCHMARK.json")
-    entry = manifest["configs"][-1]
+    entry = manifest["configs"][4]
     assert entry["name"] == config["name"] == CONFIG
     assert entry["source"] == config["source"] and len(entry["source"]) <= 200
     assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
     assert entry["reduced"] == list(config["reduced"])
-    cell = manifest["workloads"][-1]
+    cell = manifest["workloads"][4]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, CONFIG, MIX, 1)
     assert len(cell["why"]) <= 200
-    assert len(manifest["workloads"]) == 5
-    assert sum(1 for w in manifest["workloads"] if w["chips"] == 4) == 1
+    assert sum(1 for w in manifest["workloads"][:5] if w["chips"] == 4) == 1
     # the cell's own three list it alone; the lists it joined by the
     # manifest are held as a subset, so that a later PR's metric that
     # lists every cell (ISSUE 36's eight waits) does not fail this test
@@ -479,7 +478,7 @@ CASES = [
 
 @pytest.mark.parametrize("extra,correct,number", CASES,
                          ids=["sound", "cauchy", "crc32", "not_rebuilt"])
-def test_rehearsal_of_the_cell(extra, correct, number):
+def test_rehearsal_of_the_cell(extra, correct, number, one_bench_rehearsal_at_a_time):
     bench_dir = os.path.join(REPO, "benchmark")
     code = RUN.format(bench=bench_dir, root=REPO, run=os.path.join(bench_dir, "run.py"))
     proc = subprocess.run(

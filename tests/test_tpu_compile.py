@@ -184,7 +184,8 @@ def _mesh_codec(topo, vol: int, stripe: int):
     from seaweedfs_tpu.parallel.mesh_codec import STRIPE_AXIS, VOL_AXIS
 
     mesh = Mesh(
-        np.array(topo.devices).reshape(vol, stripe), (VOL_AXIS, STRIPE_AXIS)
+        np.array(topo.devices[: vol * stripe]).reshape(vol, stripe),
+        (VOL_AXIS, STRIPE_AXIS),
     )
     codec = MeshCodec(mesh)
     assert codec.report()["arm"] == "swar"  # described devices are TPUs
@@ -287,6 +288,30 @@ def test_mesh_encode_batch_u32_crc_names(topo):
         assert _scoped(text, scope), scope
     gather = next(ln for ln in text.splitlines() if " all-gather(" in ln)
     assert _scoped(gather, "ec.crc_gather")
+
+
+@pytest.mark.parametrize("vol,stripe", [(1, 1), (2, 2)])
+def test_drive_loss_cell_decode_program_names(topo, vol, stripe):
+    """The program the cell `batch-rebuild-2lost` launches a round
+    (ISSUE 38): four volumes' [10, W] survivor tiles of the batch
+    rebuild's default width through reconstruct_batch_u32, shards 3 and
+    10 from survivors {0,1,2,4,...,9,11}, on the one-chip node's 1x1 mesh
+    (and the 2x2 a four-chip node would provision). The kernel keeps the
+    name benchmark/metrics/rebuild_swar_roofline.json matches, with two
+    output rows; a positionwise decode holds no collective."""
+    import re
+
+    lanes = ec_stream.DEFAULT_TILE_BYTES // 2 // 4
+    survivors, targets = (0, 1, 2, 4, 5, 6, 7, 8, 9, 11), (3, 10)
+    codec, sharding = _mesh_codec(topo, vol, stripe)
+    text = _compiled_text(
+        lambda x: codec.reconstruct_batch_u32(survivors, targets, x),
+        _u32((4, 10, lanes), sharding),
+    )
+    kernel = re.search(SWAR_EVENT, text)
+    assert kernel and f"u32[{4 // vol},2,{lanes // stripe}]" in kernel.group(0)
+    assert _scoped(text, "ec.swar")
+    assert "all-gather" not in text and "all-reduce" not in text
 
 
 def _relayouts(text: str) -> list[str]:
