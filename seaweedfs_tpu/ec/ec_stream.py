@@ -102,10 +102,12 @@ REBUILD_TILE_BYTES = 4 * DEFAULT_TILE_BYTES
 # (read queue + the dispatcher's hands) — 10 tiles at the defaults.
 _INFLIGHT = 3
 # The most staging memory the process keeps between operations
-# (_KeptRing). A ring is _INFLIGHT + writer_threads + 1 slots of the
+# (_KeptRing). A ring is _ring_slots() slots (12 at the defaults) of the
 # plan's slot_bytes: 12 x 10 MiB for a 1 MiB-tile encode, 12 x 40 for
-# a rebuild's 4 MiB tiles, 12 x 40 and 12 x 60 MiB for batches of four
-# and six volumes, all kept; a ring
+# a rebuild's 4 MiB tiles, 12 x 40 and 12 x 60 MiB for batch encodes of
+# four and six volumes, 12 x 80 MiB for a batch rebuild of four volumes
+# in rounds of 2 MiB a survivor (batch_rebuild_tile_bytes sizes its round
+# so that the ring stays under this), all kept; a ring
 # beyond this (256 volumes a call are 2.5 GiB a slot) is allocated and
 # freed by its operation.
 _RING_KEEP_BYTES = 1 << 30
@@ -200,6 +202,49 @@ class _StagingRing:
 # no CPU; w=3 still beat w=2 and w=8.
 DEFAULT_WRITER_THREADS = min(8, max(3, (os.cpu_count() or 2) + 1))
 DEFAULT_READER_THREADS = min(6, max(3, (os.cpu_count() or 2) // 2))
+
+
+def _ring_slots(writer_threads: int | None = None) -> int:
+    """Slots of an operation's staging ring: the dispatched-but-unfetched
+    window plus one buffer in hand a writer and one for the dispatcher."""
+    return _INFLIGHT + (writer_threads or DEFAULT_WRITER_THREADS) + 1
+
+
+# The smallest survivor span a round of the batch rebuild reads: the
+# 512 KiB every round was before the chip was asked (BENCH_r12, a
+# CPU-sandbox record), and still what the host arm takes.
+BATCH_REBUILD_MIN_TILE_BYTES = DEFAULT_TILE_BYTES // 2
+
+
+def batch_rebuild_tile_bytes(volumes: int, slots: int) -> int:
+    """Per-survivor bytes of one round of the mesh batch rebuild, from
+    what the driver can see: the volumes it stacks a round and the slots
+    of its ring. The largest power of two from BATCH_REBUILD_MIN_TILE_BYTES
+    to REBUILD_TILE_BYTES whose ring (slots x volumes x 10 x tile) the
+    process still keeps (_RING_KEEP_BYTES): at 12 slots 4 MiB for one or
+    two volumes, 2 MiB for three or four, 1 MiB for five to eight, and
+    512 KiB beyond, where no size in range fits.
+
+    A round is volumes x 10 preads whatever their size, and on a host
+    whose pools share one interpreter lock a pread's price is mostly the
+    call's: 0.97 ms at 512 KiB, 1.19 at 1 MiB, 1.42 at 2 MiB on a v5e's
+    host, so four volumes' 1,040 MiB of survivors cost the reader pool
+    2.01, 1.24 and 0.74 thread-seconds. Read through the cell
+    batch-rebuild-2lost (four volumes, twelve slots) at 512 KiB, 1, 2
+    and 4 MiB: 2.56, 3.53, 4.51 and 0.79 GB/s of volume repaired in 52,
+    26, 13 and 7 rounds. The last is what the bound is for: a 1.9 GiB
+    ring is over _RING_KEEP_BYTES, so every call allocates it and its
+    six readers fault 160 MiB slots in (the first round lands after
+    0.85 s, ring_fresh_bytes 2,013,265,920 a call). PERF.md section 6,
+    PR 39."""
+    tile = REBUILD_TILE_BYTES
+    while (
+        tile > BATCH_REBUILD_MIN_TILE_BYTES
+        and slots * volumes * DATA_SHARDS * tile > _RING_KEEP_BYTES
+    ):
+        tile //= 2
+    return tile
+
 
 _EOF = object()  # end-of-stream marker flowing through the queues
 _STOPPED = object()  # returned by _q_get when the pipeline aborted
@@ -672,7 +717,7 @@ class _Op:
         # every in-flight tile lives in one of these slots: the window
         # plus the buffers pool threads legitimately hold. The memory is
         # the process's where it is free (_KeptRing)
-        ring = _StagingRing(_INFLIGHT + writer_threads + 1, slot_bytes)
+        ring = _StagingRing(_ring_slots(writer_threads), slot_bytes)
         claims, claim_lock = iter(items), threading.Lock()
         fds: list[int] = []  # opened inside the try: no leak on ENOSPC
         reservation: _Reservation | None = None
@@ -2241,16 +2286,22 @@ def stream_rebuild_ec_files_batch(
     lists in base_file_names order; volumes with nothing missing
     return [].
 
-    `tile_bytes` defaults to DEFAULT_TILE_BYTES // 2 (512 KiB a
-    survivor row): a CPU-sandbox record's number (BENCH_r12), with no
-    chip reading behind it yet. The benchmark cell `batch-rebuild-2lost`
-    runs this driver on the chip (`rebuild_launches_per_gib`,
-    `rebuild_dispatcher_busy_pct`): a change of the tile is judged there.
+    `tile_bytes` is an override for tests and sweeps. Left None, the
+    mesh arm sizes each chunk's round from the chunk's volumes and the
+    ring the process keeps (batch_rebuild_tile_bytes: 2 MiB a survivor
+    row for four volumes, which the chip read at 4.5 GB/s of volume
+    repaired where 512 KiB read 2.6: PERF.md section 6, PR 39), and the
+    host arm takes BATCH_REBUILD_MIN_TILE_BYTES (512 KiB: a CPU-sandbox
+    record's number, BENCH_r12, for hosts without a chip). The benchmark cell
+    `batch-rebuild-2lost` runs the mesh arm on the chip
+    (`rebuild_launches_per_gib`, `read_s_per_gib`,
+    `ring_fresh_bytes_per_gib`): a change of the rule is judged there.
 
     `stats` gets the stage seconds of every group summed, `tiles` and
-    `survivor_bytes` summed, `survivors` / `targets` / `mesh` of the last
-    group, `batch_volumes` and `batch_groups` (damage signatures: decode
-    programs the call ran); each chunk's root span carries the same.
+    `survivor_bytes` summed, `survivors` / `targets` / `mesh` /
+    `tile_bytes` of the last group, `batch_volumes` and `batch_groups`
+    (damage signatures: decode programs the call ran); each chunk's root
+    span carries the same.
 
     `durable=True` fsyncs every rebuilt shard before returning; a
     failed chunk removes ALL its volumes' target files (the abort
@@ -2399,12 +2450,13 @@ def _rebuild_shape(tiles: int, survivors, targets, survivor_bytes: int) -> dict:
 
 
 def _report_batch_rebuild(
-    out: dict, sp, volumes: int, groups: int, shape: dict
+    out: dict, sp, volumes: int, groups: int, tile_bytes: int, shape: dict
 ) -> None:
     """A batch rebuild chunk's shape on its stats and on its root span,
     under the same names: the volumes it stacked, the call's damage
-    signatures, and _rebuild_shape's four."""
-    shape = {"batch_volumes": volumes, **shape}
+    signatures, the per-survivor bytes of a round (or work item) its arm
+    took, and _rebuild_shape's four."""
+    shape = {"batch_volumes": volumes, "tile_bytes": tile_bytes, **shape}
     out.update(shape)
     sp.annotate("batch_groups", groups)
     for key, value in shape.items():
@@ -2425,13 +2477,6 @@ def _rebuild_batch_chunk(
     for the root span."""
     from seaweedfs_tpu.ec.ec_files import to_ext
 
-    # local rebuilds want the fine tile (more in-flight preads to
-    # overlap, page-cache-friendly spans), and the batch arm is
-    # local-survivor-only by contract. The number rests on a CPU-sandbox
-    # record (BENCH_r12) and on no chip reading yet: the single-volume
-    # driver's tile went from this to REBUILD_TILE_BYTES once the chip
-    # was asked. The cell batch-rebuild-2lost is where this one is.
-    tile_bytes = tile_bytes or DEFAULT_TILE_BYTES // 2
     b = len(bases)
     sizes = [
         os.path.getsize(base + to_ext(survivors[0])) for base in bases
@@ -2455,10 +2500,19 @@ def _rebuild_batch_chunk(
                 ]
         return
     if host:
+        # the host arm's slots are [10, tile] a (volume, tile) work item
+        # and its number comes from hosts without a chip: the fine tile
+        # (more preads in flight, cache-resident spans) stays
         return _rebuild_batch_chunk_host(
-            bases, codec.rs, survivors, targets, sizes, outputs, tile_bytes,
+            bases, codec.rs, survivors, targets, sizes, outputs,
+            tile_bytes or BATCH_REBUILD_MIN_TILE_BYTES,
             stats, durable, want_crcs, reader_threads, writer_threads, groups,
         )
+    # the round's span follows from what the driver can see, the volumes
+    # it stacks and the ring the process keeps: no caller passes a size
+    tile_bytes = tile_bytes or batch_rebuild_tile_bytes(
+        b, _ring_slots(writer_threads)
+    )
     rounds = max(-(-size // tile_bytes) for size in sizes)
     step_of = [
         [
@@ -2541,7 +2595,7 @@ def _rebuild_batch_chunk(
     def report(out, sp, whole):
         out["mesh"] = {**codec.report(), "devices_per_round": held}
         sp.annotate("mesh", f"{vol_axis}x{stripe}")
-        _report_batch_rebuild(out, sp, b, groups, _rebuild_shape(
+        _report_batch_rebuild(out, sp, b, groups, tile_bytes, _rebuild_shape(
             rounds, survivors, targets, DATA_SHARDS * sum(sizes)
         ))
         if want_crcs and whole:
@@ -2638,7 +2692,7 @@ def _rebuild_batch_chunk_host(
 
     def report(out, sp, whole):
         out["codec_arm"] = "host"
-        _report_batch_rebuild(out, sp, b, groups, _rebuild_shape(
+        _report_batch_rebuild(out, sp, b, groups, tile_bytes, _rebuild_shape(
             len(items), survivors, targets, DATA_SHARDS * sum(sizes)
         ))
         if want_crcs and whole:

@@ -1145,8 +1145,11 @@ class VolumeServer:
             # the dispatcher's share of device_s / stage_s in a device stage
             "h2d_s", "launch_s",
             # a rebuild's shape (decode tiles dispatched, survivor and
-            # target shards, survivor bytes gathered) and its master lookup
+            # target shards, survivor bytes gathered) and its master lookup;
+            # the per-survivor bytes of a tile where the driver chose them
+            # (the batch rebuild)
             "tiles", "survivors", "targets", "survivor_bytes", "lookup_s",
+            "tile_bytes",
             # its rack gather: the survivors and bytes that crossed the
             # wire, the fetch pool's thread-seconds in those reads and,
             # inside them, their wait at the bandwidth arbiter; the

@@ -39,7 +39,7 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu import trace
-from seaweedfs_tpu.ec import ec_files
+from seaweedfs_tpu.ec import ec_files, ec_stream
 from seaweedfs_tpu.pb import master_pb2, rpc, volume_pb2
 from seaweedfs_tpu.server.master_server import MasterServer
 from seaweedfs_tpu.server.volume_server import VolumeServer
@@ -50,12 +50,24 @@ from seaweedfs_tpu.util.availability import free_port
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MIB = 1 << 20
 CELL, CONFIG, MIX = "batch-rebuild-2lost", "drive-loss-256m", "batch-rebuild-loop"
-SHARD_BYTES = MIB  # one stripe row: two rounds of the driver's 512 KiB tile
+SHARD_BYTES = MIB  # one stripe row
 DRIVE = [3, 10]  # the configuration's lost drive
 PHASE_FIELDS = ("head_s", "dispatch_span_s", "drain_s", "write_tail_s", "flush_s")
 WAIT_FIELDS = ("slot_wait_s", "read_q_wait_s", "first_tile_wait_s", "tile_wait_s",
                "dispatch_call_s", "window_wait_s", "work_wait_s", "latch_wait_s")
 NEW_FIELDS = ("h2d_s", "launch_s", "batch_groups", "lookup_s", "fell_through")
+
+
+def _tile(volumes: int) -> int:
+    """The mesh stage's tile for `volumes` stacked volumes by the driver's
+    own rule (ISSUE 39: sized from the chunk's volumes and the kept ring,
+    whatever the chip chose), under the node's default pools."""
+    return ec_stream.batch_rebuild_tile_bytes(volumes, ec_stream._ring_slots())
+
+
+def _rounds(volumes: int) -> int:
+    """Rounds the mesh stage makes of shard files of SHARD_BYTES."""
+    return -(-SHARD_BYTES // _tile(volumes))
 
 
 def _json(*parts: str) -> dict:
@@ -331,8 +343,10 @@ def test_every_volume_reads_back_after_the_three_calls(mended):
 
 def test_two_damage_signatures_are_two_groups_and_nobody_falls_through(mended, report, bench):
     assert (report["batch_volumes"], report["batch_groups"], report["fell_through"]) == (4, 2, 0)
-    # two rounds of 512 KiB a group; survivor bytes of all four volumes
-    assert report["tiles"] == 4 and report["survivor_bytes"] == 4 * 10 * SHARD_BYTES
+    # two groups of two volumes, each in the rounds of the driver's rule;
+    # survivor bytes of all four volumes
+    assert report["tiles"] == 2 * _rounds(2)
+    assert report["survivor_bytes"] == 4 * 10 * SHARD_BYTES
     assert report["survivors"] == 10 and report["targets"] in (1, 2)
     assert report["mesh"]["arm"] == "bit-matmul" and not report.get("fallback")
     assert _reports(bench, mended["mixed"], "rebuild") == []
@@ -348,8 +362,11 @@ def test_two_damage_signatures_are_two_groups_and_nobody_falls_through(mended, r
 
 def test_report_line_carries_the_split_the_lookups_and_the_waits(report):
     for field in NEW_FIELDS + WAIT_FIELDS + PHASE_FIELDS + (
-            "publish_s", "reserve_s", "reserve_done_s", "program_traces", "ring_fresh_bytes"):
+            "publish_s", "reserve_s", "reserve_done_s", "program_traces", "ring_fresh_bytes",
+            "tile_bytes"):
         assert field in report, field
+    # the size the rule chose for a group of two volumes (ISSUE 39)
+    assert report["tile_bytes"] == _tile(2)
     assert report["lookup_s"] > 0 and report["device_s"] > 0
     # as the mesh encode stage books them: the transfer inside the stage,
     # the launch all of device_s (each rounded to 1e-4 on the line)
@@ -372,7 +389,8 @@ def test_spans_of_the_batch_rebuild(mended, report):
     for root in roots:
         assert root["parent"] == handler[0]["span"]
         assert root["annot"]["batch_groups"] == "2" and root["annot"]["batch_volumes"] == "2"
-        assert (root["annot"]["tiles"], root["annot"]["survivors"]) == ("2", "10")
+        assert (root["annot"]["tiles"], root["annot"]["survivors"]) == (str(_rounds(2)), "10")
+        assert root["annot"]["tile_bytes"] == str(report["tile_bytes"])
         assert root["annot"]["survivor_bytes"] == str(2 * 10 * SHARD_BYTES)
         assert {"h2d_s", "launch_s"} | set(WAIT_FIELDS) <= set(root["stages_ms"])
     assert sorted(r["annot"]["targets"] for r in roots) == ["1", "2"]
@@ -387,7 +405,7 @@ def test_the_dispatcher_annotates_its_transfer_and_its_launch(mended):
     opened = mended["mixed"]["annotations"]
     h2d = [t for name, t in opened if name == "ec.h2d"]
     launch = [t for name, t in opened if name == "ec.launch"]
-    assert len(h2d) == len(launch) == 4  # two groups of two rounds
+    assert len(h2d) == len(launch) == 2 * _rounds(2)  # two groups' rounds
     # the ONE dispatcher is the handler's thread, no pool thread
     pools = {t for name, t in opened if name in ("ec.read", "ec.write", "ec.writeback")}
     assert len(set(h2d + launch)) == 1 and not set(h2d) & pools

@@ -293,15 +293,17 @@ def test_mesh_encode_batch_u32_crc_names(topo):
 @pytest.mark.parametrize("vol,stripe", [(1, 1), (2, 2)])
 def test_drive_loss_cell_decode_program_names(topo, vol, stripe):
     """The program the cell `batch-rebuild-2lost` launches a round
-    (ISSUE 38): four volumes' [10, W] survivor tiles of the batch
-    rebuild's default width through reconstruct_batch_u32, shards 3 and
-    10 from survivors {0,1,2,4,...,9,11}, on the one-chip node's 1x1 mesh
-    (and the 2x2 a four-chip node would provision). The kernel keeps the
+    (ISSUE 38): four volumes' [10, W] survivor tiles through
+    reconstruct_batch_u32, shards 3 and 10 from survivors
+    {0,1,2,4,...,9,11}, on the one-chip node's 1x1 mesh (and the 2x2 a
+    four-chip node would provision), at the width the driver's own rule
+    gives four volumes under the ring of the chip's host (ISSUE 39:
+    thirteen cores, eight writers, twelve slots). The kernel keeps the
     name benchmark/metrics/rebuild_swar_roofline.json matches, with two
     output rows; a positionwise decode holds no collective."""
     import re
 
-    lanes = ec_stream.DEFAULT_TILE_BYTES // 2 // 4
+    lanes = ec_stream.batch_rebuild_tile_bytes(4, ec_stream._ring_slots(8)) // 4
     survivors, targets = (0, 1, 2, 4, 5, 6, 7, 8, 9, 11), (3, 10)
     codec, sharding = _mesh_codec(topo, vol, stripe)
     text = _compiled_text(
