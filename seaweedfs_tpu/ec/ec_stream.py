@@ -48,13 +48,14 @@ from __future__ import annotations
 import contextlib
 import errno
 import functools
+import itertools
 import os
 import sys
 import queue
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -468,14 +469,21 @@ class _Survivors:
     """One thread's read-only fds of each volume's ten survivor shard
     files, opened when the thread first meets the volume and closed
     together: the batch rebuild arms' only way to survivor bytes, so the
-    truncation check and the repair accounting live here."""
+    truncation check and the repair accounting live here. Each volume
+    has its own survivor set (a pass mends every damage signature of
+    its call)."""
 
-    def __init__(self, bases: list[str], survivors: tuple[int, ...]):
+    def __init__(
+        self, bases: list[str], survivors_of: list[tuple[int, ...]]
+    ):
         from seaweedfs_tpu.ec.ec_files import to_ext
 
         # [volume][j]: the file of the volume's j-th survivor
-        self._paths = [[base + to_ext(s) for s in survivors] for base in bases]
-        self._survivors = survivors
+        self._paths = [
+            [base + to_ext(s) for s in survivors]
+            for base, survivors in zip(bases, survivors_of)
+        ]
+        self._survivors = survivors_of
         self._fds: dict[int, list[int]] = {}
         self._read = EC_REPAIR_BYTES_READ.labels("local")
 
@@ -503,7 +511,7 @@ class _Survivors:
             self._read.inc(got)
             if got != step:
                 raise ValueError(
-                    f"ec shard {self._survivors[j]} truncated: expected "
+                    f"ec shard {self._survivors[v][j]} truncated: expected "
                     f"{step} at {off} ({self._paths[v][j]})"
                 )
 
@@ -2011,23 +2019,17 @@ def _default_mesh_codec(batch: int):
 
 class _HostBatchCodec:
     """Marker codec routing the batch REBUILD driver to its host arm
-    (_rebuild_batch_chunk_host) on hosts whose only jax devices are
+    (_rebuild_batch_pass_host) on hosts whose only jax devices are
     CPU: the SWAR Pallas kernels run interpreted there, orders of
     magnitude under the host RS backends, so the batch win must come
     from the host side instead — ONE shared pipeline (one thread-pool
     spin-up, one staging ring, per-(volume, tile) work items) for the
-    whole group, where the serial path pays the driver's fixed cost
+    whole call, where the serial path pays the driver's fixed cost
     once per volume. Byte-identical to the per-volume path: same
     cached decode rows, same survivor order, bytewise GF math."""
 
     def __init__(self, rs):
-        import types
-
         self.rs = rs
-        # group-chunking code only reads devices.shape: (1, 1)
-        self.mesh = types.SimpleNamespace(
-            devices=np.empty((1, 1), dtype=object)
-        )
 
 
 def _mesh_width(codec, b: int, max_step: int) -> tuple[int, int, int]:
@@ -2271,13 +2273,16 @@ def stream_rebuild_ec_files_batch(
     parallel/mesh_codec.reconstruct_batch_u32 once.
 
     Volumes are grouped by their (survivors, targets) signature — each
-    group compiles one decode program; mixed-damage batches run one
-    group after another, still batched within each. Every survivor must
-    be LOCAL: the rack-gather/remote-reader and repair-session features
-    stay with the single-volume driver (callers with remote survivors
-    route there). Output bytes per volume are identical to
-    rebuild_ec_files (RS determinism over the same ascending survivor
-    choice).
+    group one decode program — and a mixed-damage call is still ONE
+    pass of the pipeline shell: its items are (chunk, round), group
+    after group, so the pools, the ring, the reservation and the
+    head-to-flush phases are paid once a call, and the program changes
+    once a chunk (a chunk is a group, or a WEED_EC_PIPELINE_BATCH slice
+    of one). Every survivor must be LOCAL: the rack-gather/remote-reader
+    and repair-session features stay with the single-volume driver
+    (callers with remote survivors route there). Output bytes per
+    volume are identical to rebuild_ec_files (RS determinism over the
+    same ascending survivor choice).
 
     want_crcs=True lands `shard_crcs` in stats: one {rebuilt shard id:
     whole-file CRC-32C} dict per volume, in base_file_names order
@@ -2287,24 +2292,28 @@ def stream_rebuild_ec_files_batch(
     return [].
 
     `tile_bytes` is an override for tests and sweeps. Left None, the
-    mesh arm sizes each chunk's round from the chunk's volumes and the
+    mesh arm sizes the round from the widest chunk's volumes and the
     ring the process keeps (batch_rebuild_tile_bytes: 2 MiB a survivor
     row for four volumes, which the chip read at 4.5 GB/s of volume
     repaired where 512 KiB read 2.6: PERF.md section 6, PR 39), and the
     host arm takes BATCH_REBUILD_MIN_TILE_BYTES (512 KiB: a CPU-sandbox
-    record's number, BENCH_r12, for hosts without a chip). The benchmark cell
-    `batch-rebuild-2lost` runs the mesh arm on the chip
+    record's number, BENCH_r12, for hosts without a chip). The benchmark cells
+    `batch-rebuild-2lost` (one signature) and `batch-rebuild-mixed`
+    (seven) run the mesh arm on the chip
     (`rebuild_launches_per_gib`, `read_s_per_gib`,
-    `ring_fresh_bytes_per_gib`): a change of the rule is judged there.
+    `ring_fresh_bytes_per_gib`, `batch_rebuild_passes_per_op`): a change
+    of the rule is judged there.
 
-    `stats` gets the stage seconds of every group summed, `tiles` and
-    `survivor_bytes` summed, `survivors` / `targets` / `mesh` /
-    `tile_bytes` of the last group, `batch_volumes` and `batch_groups`
-    (damage signatures: decode programs the call ran); each chunk's root
-    span carries the same.
+    `stats` gets the pass's books: its stage seconds, `tiles` (rounds
+    or work items of every chunk), `survivor_bytes`, `tile_bytes`,
+    `mesh` (the last chunk's), `survivors` and `targets` (per-volume
+    counts: the most targets of any volume), `batch_volumes`,
+    `batch_groups` (damage signatures: decode programs the call ran) and
+    `batch_passes` (1; 0 where there was nothing to stream); the ONE
+    root span carries the same.
 
     `durable=True` fsyncs every rebuilt shard before returning; a
-    failed chunk removes ALL its volumes' target files (the abort
+    failed call removes ALL its volumes' target files (the abort
     contract scrub relies on: no partial rebuilt shard survives)."""
     from seaweedfs_tpu.ec.ec_files import shard_presence, to_ext
 
@@ -2336,6 +2345,7 @@ def stream_rebuild_ec_files_batch(
         if stats is not None:
             stats["batch_volumes"] = len(base_file_names)
             stats["batch_groups"] = 0
+            stats["batch_passes"] = 0
             if want_crcs:
                 stats["shard_crcs"] = [{} for _ in base_file_names]
         return results
@@ -2379,59 +2389,44 @@ def stream_rebuild_ec_files_batch(
                 stats["fallback"] = "host"
                 stats["batch_volumes"] = len(base_file_names)
                 stats["batch_groups"] = len(groups)
+                # one single-volume pass a damaged volume
+                stats["batch_passes"] = len(sigs) - sigs.count(None)
                 if want_crcs:
                     stats["shard_crcs"] = all_crcs
             return results
 
     limit = pipeline_batch_limit()
-    crcs_by_vol: dict[int, dict] = {}
-    summed: dict[str, float] = {}  # stage seconds, program_traces
-    last_struct: dict = {}
-    for (survivors, targets), idxs in groups.items():
-        chunks = (
-            [idxs[i : i + limit] for i in range(0, len(idxs), limit)]
-            if limit
-            else [idxs]
-        )
-        for chunk in chunks:
-            chunk_stats: dict = {}
-            # each chunk self-provisions a mesh that fits ITS size when
-            # the caller passed none originally — but a caller codec is
-            # honored only if the chunk shards over its vol axis
-            chunk_codec = codec
-            if len(chunk) % codec.mesh.devices.shape[0]:
-                chunk_codec = _default_mesh_codec(len(chunk))
-            _rebuild_batch_chunk(
-                [base_file_names[i] for i in chunk],
-                chunk_codec, survivors, targets, tile_bytes, chunk_stats,
-                durable, want_crcs, reader_threads, writer_threads,
-                groups=len(groups),
-            )
-            for i in chunk:
-                results[i] = list(targets)
-            if want_crcs:
-                for i, crcs in zip(
-                    chunk, chunk_stats.get("shard_crcs") or []
-                ):
-                    crcs_by_vol[i] = crcs
-            for k, v in chunk_stats.items():
-                if isinstance(v, float):
-                    summed[k] = round(summed.get(k, 0.0) + v, 4)
-                elif k in (
-                    "program_traces", "ring_fresh_bytes", "tiles",
-                    "survivor_bytes",
-                ):
-                    summed[k] = summed.get(k, 0) + v
-                elif k != "shard_crcs":
-                    last_struct[k] = v
+    # group-major: a group's volumes in chunks of at most `limit`, each
+    # chunk one decode program over its rounds, all of them ONE pass
+    chunks = [
+        (idxs[i : i + (limit or len(idxs))], survivors, targets)
+        for (survivors, targets), idxs in groups.items()
+        for i in range(0, len(idxs), limit or len(idxs))
+    ]
+    pass_stats: dict = {}
+    _rebuild_batch_pass(
+        [
+            ([base_file_names[i] for i in idxs], survivors, targets)
+            for idxs, survivors, targets in chunks
+        ],
+        codec, tile_bytes, pass_stats, durable, want_crcs,
+        reader_threads, writer_threads, groups=len(groups),
+    )
+    for idxs, _, targets in chunks:
+        for i in idxs:
+            results[i] = list(targets)
     if stats is not None:
-        stats.update(summed)
-        stats.update(last_struct)
+        # the pass's volumes in its order -> base_file_names order
+        crcs = dict(zip(
+            [i for idxs, _, _ in chunks for i in idxs],
+            pass_stats.pop("shard_crcs", None) or [],
+        ))
+        stats.update(pass_stats)
         stats["batch_volumes"] = len(base_file_names)
         stats["batch_groups"] = len(groups)
         if want_crcs:
             stats["shard_crcs"] = [
-                crcs_by_vol.get(i, {}) for i in range(len(base_file_names))
+                crcs.get(i, {}) for i in range(len(base_file_names))
             ]
     return results
 
@@ -2452,100 +2447,155 @@ def _rebuild_shape(tiles: int, survivors, targets, survivor_bytes: int) -> dict:
 def _report_batch_rebuild(
     out: dict, sp, volumes: int, groups: int, tile_bytes: int, shape: dict
 ) -> None:
-    """A batch rebuild chunk's shape on its stats and on its root span,
-    under the same names: the volumes it stacked, the call's damage
-    signatures, the per-survivor bytes of a round (or work item) its arm
-    took, and _rebuild_shape's four."""
-    shape = {"batch_volumes": volumes, "tile_bytes": tile_bytes, **shape}
+    """A batch rebuild pass's shape on its stats and on its root span,
+    under the same names: the volumes it stacked, the one pass it made
+    of them, the call's damage signatures, the per-survivor bytes of a
+    round (or work item) its arm took, and _rebuild_shape's four."""
+    shape = {
+        "batch_volumes": volumes, "batch_passes": 1,
+        "tile_bytes": tile_bytes, **shape,
+    }
     out.update(shape)
     sp.annotate("batch_groups", groups)
     for key, value in shape.items():
         sp.annotate(key, value)
 
 
-def _rebuild_batch_chunk(
-    bases: list[str], codec, survivors: tuple[int, ...],
-    targets: tuple[int, ...], tile_bytes, stats, durable, want_crcs,
+class _BatchChunk(NamedTuple):
+    """One (survivors, targets)-homogeneous chunk of a mesh pass: its
+    volumes are the pass's v0 .. v0 + b - 1, stacked [b, 10, width] a
+    round and decoded by ONE program of `codec`. step_of[r][v] is the
+    bytes volume v has in round r (0: done, its output discarded)."""
+
+    v0: int
+    b: int
+    survivors: tuple[int, ...]
+    targets: tuple[int, ...]
+    codec: object
+    width: int
+    step_of: list[list[int]]
+    opens_group: bool  # its signature is not the previous chunk's
+
+
+def _rebuild_batch_pass(
+    chunks: list[tuple[list[str], tuple[int, ...], tuple[int, ...]]],
+    codec, tile_bytes, stats, durable, want_crcs,
     reader_threads, writer_threads, groups: int,
 ) -> None:
-    """One (survivors, targets)-homogeneous chunk through the mesh:
-    the rebuild-side mirror of _stream_batch_chunk. Reads [k, step]
-    survivor tiles per volume into a [B, k, W] staging slot, runs
-    reconstruct_batch_u32 once per round, pwrites the rebuilt target
-    rows. Same abort contract: any failure removes every volume's
-    target files. `groups` is the call's count of damage signatures,
-    for the root span."""
+    """Every chunk of a call, (bases, survivors, targets) each, through
+    ONE pass of the shell: the rebuild-side mirror of _stream_batch_chunk
+    over items (chunk, round), in chunk order, so the decode program
+    changes once a chunk. Each round reads [k, step] survivor tiles per
+    volume of its chunk into a [b, k, W] staging slot (the slot is sized
+    for the widest chunk), runs the chunk's reconstruct_batch_u32 once,
+    and pwrites the rebuilt target rows. One reservation and one latch
+    for every output file of the call, and the call's abort contract:
+    any failure removes every target file of every volume. `groups` is
+    the call's count of damage signatures, for the root span. Stats get
+    the volumes' rebuilt-shard CRCs in the pass's volume order."""
     from seaweedfs_tpu.ec.ec_files import to_ext
 
-    b = len(bases)
+    bases = [base for chunk_bases, _, _ in chunks for base in chunk_bases]
+    survivors_of = [s for chunk_bases, s, _ in chunks for _ in chunk_bases]
+    targets_of = [t for chunk_bases, _, t in chunks for _ in chunk_bases]
     sizes = [
-        os.path.getsize(base + to_ext(survivors[0])) for base in bases
+        os.path.getsize(base + to_ext(survivors[0]))
+        for base, survivors in zip(bases, survivors_of)
     ]
     outputs = [
         (base + to_ext(t), size)
-        for base, size in zip(bases, sizes)
+        for base, size, targets in zip(bases, sizes, targets_of)
         for t in targets
     ]
+    # where each volume's target files start among the outputs (and fds)
+    out_at = list(itertools.accumulate(map(len, targets_of), initial=0))
     host = isinstance(codec, _HostBatchCodec)
     if not any(sizes):
         # all-empty shard sets: rebuilt targets are empty files too
         _create_empty([path for path, _ in outputs], durable)
         if stats is not None:
-            stats["batch_volumes"] = b
+            stats["batch_volumes"] = len(bases)
+            stats["batch_passes"] = 0
             if host:
                 stats["codec_arm"] = "host"
             if want_crcs:
                 stats["shard_crcs"] = [
-                    {t: 0 for t in targets} for _ in bases
+                    dict.fromkeys(targets, 0) for targets in targets_of
                 ]
         return
     if host:
         # the host arm's slots are [10, tile] a (volume, tile) work item
         # and its number comes from hosts without a chip: the fine tile
         # (more preads in flight, cache-resident spans) stays
-        return _rebuild_batch_chunk_host(
-            bases, codec.rs, survivors, targets, sizes, outputs,
-            tile_bytes or BATCH_REBUILD_MIN_TILE_BYTES,
+        return _rebuild_batch_pass_host(
+            bases, codec.rs, survivors_of, targets_of, sizes, outputs,
+            out_at, tile_bytes or BATCH_REBUILD_MIN_TILE_BYTES,
             stats, durable, want_crcs, reader_threads, writer_threads, groups,
         )
-    # the round's span follows from what the driver can see, the volumes
-    # it stacks and the ring the process keeps: no caller passes a size
+    # the round's span follows from what the driver can see, the widest
+    # chunk it stacks and the ring the process keeps: no caller passes a
+    # size
     tile_bytes = tile_bytes or batch_rebuild_tile_bytes(
-        b, _ring_slots(writer_threads)
+        max(len(chunk_bases) for chunk_bases, _, _ in chunks),
+        _ring_slots(writer_threads),
     )
-    rounds = max(-(-size // tile_bytes) for size in sizes)
-    step_of = [
-        [
-            max(0, min(tile_bytes, sizes[v] - r * tile_bytes))
-            for v in range(b)
+    plans: list[_BatchChunk] = []
+    v0 = 0
+    for chunk_bases, survivors, targets in chunks:
+        b = len(chunk_bases)
+        chunk_sizes = sizes[v0 : v0 + b]
+        step_of = [
+            [max(0, min(tile_bytes, size - r * tile_bytes)) for size in chunk_sizes]
+            for r in range(max(-(-size // tile_bytes) for size in chunk_sizes))
         ]
-        for r in range(rounds)
-    ]
-    vol_axis, stripe, width = _mesh_width(
-        codec, b, max(step for row in step_of for step in row)
-    )
-    round_crcs: list = [None] * rounds
-    held = vol_axis * stripe  # see _stream_batch_chunk
+        # a caller's codec is honoured where the chunk shards over its
+        # vol axis; else the chunk takes a mesh that fits its size
+        chunk_codec = codec
+        if b % codec.mesh.devices.shape[0]:
+            chunk_codec = _default_mesh_codec(b)
+        _, _, width = _mesh_width(
+            chunk_codec, b, max((s for row in step_of for s in row), default=0)
+        )
+        plans.append(_BatchChunk(
+            v0, b, survivors, targets, chunk_codec, width, step_of,
+            not plans or (plans[-1].survivors, plans[-1].targets)
+            != (survivors, targets),
+        ))
+        v0 += b
+    held = min(p.codec.mesh.devices.size for p in plans)  # see _stream_batch_chunk
+    items = [(c, r) for c, p in enumerate(plans) for r in range(len(p.step_of))]
+    round_crcs: list[list] = [[None] * len(p.step_of) for p in plans]
     op = _Op("ec_stream.rebuild_batch", True, _DEVICE_BUSY)
 
-    def fill(src, r, buf):
-        buf3 = buf[: b * DATA_SHARDS * width].reshape(b, DATA_SHARDS, width)
-        for v in range(b):
-            step = step_of[r][v]
+    def fill(src, item, buf):
+        c, r = item
+        p = plans[c]
+        buf3 = buf[: p.b * DATA_SHARDS * p.width].reshape(
+            p.b, DATA_SHARDS, p.width
+        )
+        for v, step in enumerate(p.step_of[r]):
             if step:  # else volume done: output discarded
-                src.read(v, r * tile_bytes, buf3[v, :, :step])
+                src.read(p.v0 + v, r * tile_bytes, buf3[v, :, :step])
         return buf3
 
-    def dispatch(r, buf3):
+    def dispatch(item, buf3):
         nonlocal held
+        c, r = item
+        p = plans[c]
         t0 = time.perf_counter()
         with trace.annotation("ec.h2d"):
-            vols = codec.shard_volumes(buf3.view(np.uint32))
+            vols = p.codec.shard_volumes(buf3.view(np.uint32))
         th = time.perf_counter()
-        held = min(held, codec.devices_holding(vols))
+        held = min(held, p.codec.devices_holding(vols))
+        # where the program changes: the first launch of each group
+        group = (
+            trace.annotation("ec.group")
+            if p.opens_group and r == 0
+            else contextlib.nullcontext()
+        )
         t1 = time.perf_counter()
-        with trace.annotation("ec.launch"):
-            handle = codec.reconstruct_batch_u32(survivors, targets, vols)
+        with group, trace.annotation("ec.launch"):
+            handle = p.codec.reconstruct_batch_u32(p.survivors, p.targets, vols)
         t2 = time.perf_counter()
         op.book("stage_s", t1 - t0)
         op.book("device_s", t2 - t1)
@@ -2555,60 +2605,72 @@ def _rebuild_batch_chunk(
         op.book("launch_s", t2 - t1)
         return handle
 
-    def fetch(r, buf3, handle):
+    def fetch(item, buf3, handle):
         import jax
 
+        p = plans[item[0]]
         with trace.annotation("ec.writeback"):
             return (
                 np.asarray(jax.device_get(handle))
                 .view(np.uint8)
-                .reshape(b, len(targets), width)
+                .reshape(p.b, len(p.targets), p.width)
             )
 
-    def checksum(r, buf3, rebuilt):
+    def checksum(item, buf3, rebuilt):
         # no fused CRC tier for reconstruct: host table CRC the rebuilt
         # rows
         from seaweedfs_tpu.util.crc import crc32c
 
-        round_crcs[r] = [
+        c, r = item
+        p = plans[c]
+        round_crcs[c][r] = [
             [
                 crc32c(np.ascontiguousarray(rebuilt[v][t, :step]).tobytes())
-                for t in range(len(targets))
+                for t in range(len(p.targets))
             ]
             if step
             else None
-            for v, step in enumerate(step_of[r])
+            for v, step in enumerate(p.step_of[r])
         ]
 
-    def write(fds, r, buf3, rebuilt):
-        for v, step in enumerate(step_of[r]):
+    def write(fds, item, buf3, rebuilt):
+        c, r = item
+        p = plans[c]
+        for v, step in enumerate(p.step_of[r]):
             if not step:
                 continue
-            for t in range(len(targets)):
+            at = out_at[p.v0 + v]
+            for t in range(len(p.targets)):
                 _pwrite_full(
-                    fds[v * len(targets) + t],
+                    fds[at + t],
                     np.ascontiguousarray(rebuilt[v][t, :step]),
                     r * tile_bytes,
                 )
                 EC_REPAIR_BYTES_WRITTEN.inc(step)
 
     def report(out, sp, whole):
-        out["mesh"] = {**codec.report(), "devices_per_round": held}
-        sp.annotate("mesh", f"{vol_axis}x{stripe}")
-        _report_batch_rebuild(out, sp, b, groups, tile_bytes, _rebuild_shape(
-            rounds, survivors, targets, DATA_SHARDS * sum(sizes)
+        last = plans[-1].codec
+        out["mesh"] = {**last.report(), "devices_per_round": held}
+        sp.annotate("mesh", "x".join(map(str, last.mesh.devices.shape)))
+        _report_batch_rebuild(out, sp, len(bases), groups, tile_bytes, _rebuild_shape(
+            len(items), plans[0].survivors, max(targets_of, key=len),
+            DATA_SHARDS * sum(sizes),
         ))
         if want_crcs and whole:
-            out["shard_crcs"] = _fold_round_crcs(
-                b, targets, step_of, round_crcs
-            )
+            out["shard_crcs"] = [
+                crcs
+                for c, p in enumerate(plans)
+                for crcs in _fold_round_crcs(
+                    p.b, p.targets, p.step_of, round_crcs[c]
+                )
+            ]
 
     op.run(
-        nbytes=sum(sizes) * max(1, len(targets)),
-        items=list(range(rounds)),
-        slot_bytes=b * DATA_SHARDS * width,
+        nbytes=sum(s * len(t) for s, t in zip(sizes, targets_of)),
+        items=items,
+        slot_bytes=max(p.b * DATA_SHARDS * p.width for p in plans),
         outputs=outputs,
-        opened=lambda: _Survivors(bases, survivors),
+        opened=lambda: _Survivors(bases, survivors_of),
         fill=fill,
         dispatch=dispatch,
         fetch=fetch,
@@ -2629,35 +2691,37 @@ def _rebuild_batch_chunk(
 _HOST_INLINE_TILES = 16
 
 
-def _rebuild_batch_chunk_host(
-    bases: list[str], rs, survivors: tuple[int, ...],
-    targets: tuple[int, ...], sizes: list[int],
-    outputs: list[tuple[str, int]], tile_bytes: int, stats, durable,
-    want_crcs, reader_threads, writer_threads, groups: int,
+def _rebuild_batch_pass_host(
+    bases: list[str], rs, survivors_of: list[tuple[int, ...]],
+    targets_of: list[tuple[int, ...]], sizes: list[int],
+    outputs: list[tuple[str, int]], out_at: list[int], tile_bytes: int,
+    stats, durable, want_crcs, reader_threads, writer_threads, groups: int,
 ) -> None:
     """Host arm of the batch rebuild: one shared pipeline whose work
     items are per-(volume, tile) survivor gathers, decoded in the
-    writer pool with the group's single cached decode-rows matrix.
+    writer pool with each signature's single cached decode-rows matrix.
     Slots stay at the single-volume driver's [k, tile] size (cache-
     resident on small hosts — an all-volumes-per-round slot measurably
-    loses CPU to memory traffic), and the stream crosses volume
-    boundaries without the per-volume spawn/drain the serial path
-    pays. Same abort contract as the mesh arm.
+    loses CPU to memory traffic), and the stream crosses volume and
+    signature boundaries without the per-volume spawn/drain the serial
+    path pays. Same abort contract as the mesh arm.
 
     At or below _HOST_INLINE_TILES items there are no pools at all: one
     staging buffer, one pass over the work list on the caller's thread.
     Many-small-volumes repair is latency-bound on fixed costs, so the
     win there is paying ONE set of them for the whole batch and none of
     the pipeline's per-handoff scheduler wakeups."""
-    b = len(bases)
     # flat (volume, offset) work list: the pipeline streams straight
     # through volume boundaries, no drain between them
     items = [
         (v, off)
-        for v in range(b)
+        for v in range(len(bases))
         for off in range(0, sizes[v], tile_bytes)
     ]
-    rows = rs.decode_rows(tuple(survivors), tuple(targets))
+    decode = {
+        sig: rs.decode_rows(*sig) for sig in set(zip(survivors_of, targets_of))
+    }
+    rows = [decode[sig] for sig in zip(survivors_of, targets_of)]
     # (volume, offset, step, [crc per target]); append is GIL-atomic,
     # order restored by sorting at fold time
     crc_parts: list[tuple[int, int, int, list[int]]] = []
@@ -2670,21 +2734,21 @@ def _rebuild_batch_chunk_host(
         return tile
 
     def fetch(item, tile, _handle):
-        return rs._apply(rows, tile)
+        return rs._apply(rows[item[0]], tile)
 
     def checksum(item, tile, rebuilt):
         from seaweedfs_tpu.util.crc import crc32c
 
         crc_parts.append((*item, tile.shape[1], [
             crc32c(np.ascontiguousarray(rebuilt[t]).tobytes())
-            for t in range(len(targets))
+            for t in range(len(targets_of[item[0]]))
         ]))
 
     def write(fds, item, tile, rebuilt):
         v, off = item
-        for t in range(len(targets)):
+        for t in range(len(targets_of[v])):
             _pwrite_full(
-                fds[v * len(targets) + t],
+                fds[out_at[v] + t],
                 np.ascontiguousarray(rebuilt[t]),
                 off,
             )
@@ -2692,19 +2756,21 @@ def _rebuild_batch_chunk_host(
 
     def report(out, sp, whole):
         out["codec_arm"] = "host"
-        _report_batch_rebuild(out, sp, b, groups, tile_bytes, _rebuild_shape(
-            len(items), survivors, targets, DATA_SHARDS * sum(sizes)
+        _report_batch_rebuild(out, sp, len(bases), groups, tile_bytes, _rebuild_shape(
+            len(items), survivors_of[0], max(targets_of, key=len),
+            DATA_SHARDS * sum(sizes),
         ))
         if want_crcs and whole:
-            out["shard_crcs"] = _fold_host_batch_crcs(b, targets, crc_parts)
+            out["shard_crcs"] = _fold_host_batch_crcs(targets_of, crc_parts)
 
+    nbytes = sum(s * len(t) for s, t in zip(sizes, targets_of))
     if len(items) > _HOST_INLINE_TILES:
         return _Op("ec_stream.rebuild_batch").run(
-            nbytes=sum(sizes) * max(1, len(targets)),
+            nbytes=nbytes,
             items=items,
             slot_bytes=DATA_SHARDS * tile_bytes,
             outputs=outputs,
-            opened=lambda: _Survivors(bases, survivors),
+            opened=lambda: _Survivors(bases, survivors_of),
             fill=fill,
             dispatch=lambda item, tile: None,  # the writers decode
             fetch=fetch,
@@ -2723,8 +2789,8 @@ def _rebuild_batch_chunk_host(
     fds: list[int] = []
     ok = False
     with trace.span(
-        "ec_stream.rebuild_batch", nbytes=sum(sizes) * max(1, len(targets))
-    ) as sp, _Survivors(bases, survivors) as src:
+        "ec_stream.rebuild_batch", nbytes=nbytes
+    ) as sp, _Survivors(bases, survivors_of) as src:
         try:
             for path, size in outputs:
                 fds.append(
@@ -2765,15 +2831,15 @@ def _rebuild_batch_chunk_host(
 
 
 def _fold_host_batch_crcs(
-    b: int, targets: tuple[int, ...],
+    targets_of: list[tuple[int, ...]],
     crc_parts: list[tuple[int, int, int, list[int]]],
 ) -> list[dict[int, int]]:
     """Per-volume {rebuilt shard id: whole-file CRC} folded from the
     writer pool's per-tile records in offset order."""
     from seaweedfs_tpu.util.crc import crc32c_combine
 
-    out = [dict.fromkeys(targets, 0) for _ in range(b)]
+    out = [dict.fromkeys(targets, 0) for targets in targets_of]
     for v, off, step, crcs in sorted(crc_parts):
-        for t, tid in enumerate(targets):
+        for t, tid in enumerate(targets_of[v]):
             out[v][tid] = crc32c_combine(out[v][tid], crcs[t], step)
     return out

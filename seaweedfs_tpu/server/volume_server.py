@@ -1136,7 +1136,8 @@ class VolumeServer:
         ... flush_s) partition wall_s, and publish_s follows it."""
         keys = (
             "driver", "arms", "mesh", "mesh_devices", "fallback",
-            "codec_arm", "batch_volumes", "batch_groups", "fell_through",
+            "codec_arm", "batch_volumes", "batch_groups", "batch_passes",
+            "fell_through",
             "read_s", "stage_s", "device_s",
             "writeback_s", "compute_s", "write_s", "encode_s", "wall_s",
             # serial phases of the operation on the handler's thread
@@ -1402,8 +1403,8 @@ class VolumeServer:
 
         ONE `ec.batch_rebuild` report line a call, written after the
         publish whatever the mix was: the batch driver's books
-        (`batch_volumes`, `batch_groups`, the rebuild's shape, `h2d_s` /
-        `launch_s`, the waits), `lookup_s`, the seconds of the
+        (`batch_volumes`, `batch_groups`, `batch_passes`, the rebuild's
+        shape, `h2d_s` / `launch_s`, the waits), `lookup_s`, the seconds of the
         per-volume master lookups that sorted the volumes, and
         `fell_through`, the volumes sent down the single-volume path
         (each leaves an `ec.rebuild` line of its own; `batch_volumes` 0

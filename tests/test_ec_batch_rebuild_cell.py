@@ -343,8 +343,9 @@ def test_every_volume_reads_back_after_the_three_calls(mended):
 
 def test_two_damage_signatures_are_two_groups_and_nobody_falls_through(mended, report, bench):
     assert (report["batch_volumes"], report["batch_groups"], report["fell_through"]) == (4, 2, 0)
-    # two groups of two volumes, each in the rounds of the driver's rule;
-    # survivor bytes of all four volumes
+    assert report["batch_passes"] == 1
+    # two groups of two volumes, each in the rounds of the driver's rule,
+    # in one pass; survivor bytes of all four volumes
     assert report["tiles"] == 2 * _rounds(2)
     assert report["survivor_bytes"] == 4 * 10 * SHARD_BYTES
     assert report["survivors"] == 10 and report["targets"] in (1, 2)
@@ -373,7 +374,7 @@ def test_report_line_carries_the_split_the_lookups_and_the_waits(report):
     assert report["launch_s"] == pytest.approx(report["device_s"], abs=2e-4)
     assert report["h2d_s"] <= report["stage_s"] + 2e-4
     assert report["h2d_s"] + report["launch_s"] <= report["device_s"] + report["stage_s"] + 2e-4
-    # the two groups' phases, summed, are the two drivers' walls, summed
+    # the one pass's phases partition its wall
     assert sum(report[f] for f in PHASE_FIELDS) == pytest.approx(report["wall_s"], abs=7e-4)
 
 
@@ -382,33 +383,39 @@ def test_spans_of_the_batch_rebuild(mended, report):
     handler = [s for s in spans if s["name"] == "volume.ec_rebuild_batch"]
     roots = [s for s in spans if s["name"] == "ec_stream.rebuild_batch"]
     publish = [s for s in spans if s["name"] == "ec.publish"]
-    assert len(handler) == len(publish) == 1 and len(roots) == 2  # a root a group
+    # ONE root for the two groups: the call is one pass of the shell
+    assert len(handler) == len(publish) == len(roots) == 1
     assert handler[0]["trace"] == "00000000000000ab" and handler[0]["parent"] == "000000cd"
     assert handler[0]["annot"]["fell_through"] == "0"
     assert float(handler[0]["annot"]["lookup_s"]) == report["lookup_s"]
-    for root in roots:
-        assert root["parent"] == handler[0]["span"]
-        assert root["annot"]["batch_groups"] == "2" and root["annot"]["batch_volumes"] == "2"
-        assert (root["annot"]["tiles"], root["annot"]["survivors"]) == (str(_rounds(2)), "10")
-        assert root["annot"]["tile_bytes"] == str(report["tile_bytes"])
-        assert root["annot"]["survivor_bytes"] == str(2 * 10 * SHARD_BYTES)
-        assert {"h2d_s", "launch_s"} | set(WAIT_FIELDS) <= set(root["stages_ms"])
-    assert sorted(r["annot"]["targets"] for r in roots) == ["1", "2"]
+    (root,) = roots
+    assert root["parent"] == handler[0]["span"]
+    assert (root["annot"]["batch_groups"], root["annot"]["batch_passes"],
+            root["annot"]["batch_volumes"]) == ("2", "1", "4")
+    assert (root["annot"]["tiles"], root["annot"]["survivors"]) == (str(2 * _rounds(2)), "10")
+    # per-volume counts: the most targets of any volume of the pass
+    assert root["annot"]["targets"] == "2"
+    assert root["annot"]["tile_bytes"] == str(report["tile_bytes"])
+    assert root["annot"]["survivor_bytes"] == str(4 * 10 * SHARD_BYTES)
+    assert {"h2d_s", "launch_s"} | set(WAIT_FIELDS) <= set(root["stages_ms"])
     for field in ("h2d_s", "launch_s"):
-        assert sum(r["stages_ms"][field] for r in roots) == pytest.approx(
-            report[field] * 1e3, abs=0.21)
-    # handler, publish, and a root with five phases a group: no span per round
-    assert len(spans) == 2 + 2 * 6, sorted(s["name"] for s in spans)
+        assert root["stages_ms"][field] == pytest.approx(report[field] * 1e3, abs=0.11)
+    # handler, publish, and a root with its five phases: no span per
+    # group or per round
+    assert len(spans) == 2 + 6, sorted(s["name"] for s in spans)
 
 
 def test_the_dispatcher_annotates_its_transfer_and_its_launch(mended):
     opened = mended["mixed"]["annotations"]
     h2d = [t for name, t in opened if name == "ec.h2d"]
     launch = [t for name, t in opened if name == "ec.launch"]
+    group = [t for name, t in opened if name == "ec.group"]
     assert len(h2d) == len(launch) == 2 * _rounds(2)  # two groups' rounds
+    # one `ec.group` a group, around its first launch: where the program changes
+    assert len(group) == 2
     # the ONE dispatcher is the handler's thread, no pool thread
     pools = {t for name, t in opened if name in ("ec.read", "ec.write", "ec.writeback")}
-    assert len(set(h2d + launch)) == 1 and not set(h2d) & pools
+    assert len(set(h2d + launch + group)) == 1 and not set(h2d) & pools
 
 
 def test_a_shard_mounted_elsewhere_falls_through_beside_the_batch(mended, bench):
@@ -507,9 +514,9 @@ def test_manifest_entries_of_the_cell(config):
     assert sum(1 for w in manifest["workloads"] if w["chips"] == 4) == 1
     listing = {m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", [])}
     assert set(METRICS) | set(JOINED) <= listing
-    for name in METRICS:
+    for name in METRICS:  # this cell's, and the cells that joined them later
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL], name
+        assert entry["workloads"][0] == CELL, name
     # the SWAR kernel's own share of the roofline is NOT this cell's: the
     # mesh decode program re-lays its input out into on-chip memory first
     # (`%copy ... S(1)`), the kernel reads it from there, and the HBM floor
@@ -539,8 +546,11 @@ def test_configuration_is_batch_256ms_volumes_short_of_one_drives_shards(config)
 def test_metric_file_and_manifest_agree(name, bench, report):
     metric = bench.readers.load_metric(name)
     entry = next(m for m in _json("BENCHMARK.json")["per_layer"] if m["name"] == name)
-    for key in ("name", "unit", "better", "layer", "moves", "source", "workloads"):
+    for key in ("name", "unit", "better", "layer", "moves", "source"):
         assert metric[key] == entry[key], key
+    # a cell joins a metric by the manifest's list alone: the file's copy
+    # is the list as the metric came
+    assert metric["workloads"] == entry["workloads"][: len(metric["workloads"])] == [CELL]
     assert (entry["moves"], entry["layer"]) == ("ec_gbps", "stream driver")
     for spec in metric["num"] + metric.get("den", []):
         bench.readers.term(spec, _observed([report]))  # raises on a term no reader knows
