@@ -73,30 +73,31 @@ TOTAL_SHARDS = locate.TOTAL_SHARDS
 LARGE_BLOCK_SIZE = locate.LARGE_BLOCK_SIZE
 SMALL_BLOCK_SIZE = locate.SMALL_BLOCK_SIZE
 
-# Per-shard bytes per pipelined tile. 1 MiB x 10 shards = 10 MiB of
-# host buffer per ring slot (on the encode path, small-tier rows fold
-# into one super-tile — see stream_write's reader). Re-swept for the
-# staging-ring driver on this rig (BENCH_r12): finer tiles give the
-# reader pool more in-flight preads to overlap against compute, and
-# 1 MiB beat 4 MiB 1.27 vs 0.81 GB/s on the disk-backed scratch while
-# matching it on tmpfs (512 KiB was within noise of 1 MiB on both;
-# 8 MiB lost everywhere). TPU dispatch amortization keeps the floor at
-# 1 MiB — a [10, 1 MiB] tile is still 16x the SWAR minimum stream.
+# Per-shard bytes per round of the batch encode (and the unit the batch
+# rebuild's smallest round is counted in): 1 MiB x 10 shards = 10 MiB of
+# host buffer per volume and ring slot. From BENCH_r12, a sweep of the
+# staging-ring driver on a host with no chip (1 MiB beat 4 MiB 1.27 vs
+# 0.81 GB/s on a disk-backed file system and matched it on tmpfs); no
+# chip has been asked about the batch encode's round yet.
 DEFAULT_TILE_BYTES = 1024 * 1024
-# Per-shard bytes per tile of a single-volume rebuild, whether its
-# survivors are local files or streamed from other servers: [10, 4 MiB]
-# in, 40 MiB a ring slot. The ONE dispatcher thread pays about a
-# millisecond to copy a tile to the device and another to launch it
-# whatever the tile's size (0.90 + 0.83 ms at 512 KiB a survivor, 1.2 +
-# 1.2 ms at 4 MiB on a v5e), so a repair of 103 MiB shard files costs
-# that thread 0.36 s in 206 tiles of 512 KiB and 0.07 s in 27 of 4 MiB.
-# The reader pool's ten preads a tile move 3.7 GB/s a thread in 4 MiB
-# spans against 1.1 in 512 KiB ones: 0.28 thread-seconds a repair, 0.05 s
-# of wall over six readers, against 1.02 and 0.17. Read through the cell
-# rebuild-1data at 512 KiB, 1, 2 and 4 MiB (2.08, 3.23, 4.60 and 5.51 GB/s
-# of volume repaired): PERF.md section 6, PR 35. A rack gather wants the
-# same size for a reason of its own: one RPC per remote survivor and tile.
-REBUILD_TILE_BYTES = 4 * DEFAULT_TILE_BYTES
+# Per-shard bytes per tile of a single-volume encode or rebuild, whether
+# a rebuild's survivors are local files or streamed from other servers:
+# [10, 4 MiB] in, 40 MiB a ring slot, 480 MiB a kept ring. The ONE
+# dispatcher thread pays about a millisecond to copy a tile to the
+# device and another to launch it whatever the tile's size (0.90 + 0.83
+# ms at 512 KiB a shard, 1.2 + 1.2 ms at 4 MiB on a v5e), so the 103 rows
+# of a 1 GiB volume cost that thread 0.36 s in 206 tiles of 512 KiB and
+# 0.07 s in 27 of 4 MiB (25 of four rows, one of two, one of one: an
+# encode sends a run of rows as ONE tile, stream_write_ec_files). The
+# reader pool's reads move 3.7 GB/s a thread in 4 MiB spans against 1.1
+# in 512 KiB ones. Read through the cell rebuild-1data at 512 KiB, 1, 2
+# and 4 MiB (2.08, 3.23, 4.60 and 5.51 GB/s of volume repaired) and
+# through encode-1g at 2, 4 and 8 MiB (3.87, 4.45 and 2.98 GB/s of
+# volume encoded, where one call a 1 MiB row read 2.8; at 8 MiB a fetch
+# waits for 80 MiB of H2D): PERF.md section 6. A rack gather wants the
+# same size for a reason of its own: one fetch per remote survivor and
+# tile.
+VOLUME_TILE_BYTES = 4 * DEFAULT_TILE_BYTES
 # Dispatched-but-unfetched tiles queued toward the writer pool (the
 # report line's pipeline_depth). Live host-tile bound: _INFLIGHT queued
 # + one per writer thread (being fetched/written) + reader_threads + 2
@@ -104,8 +105,8 @@ REBUILD_TILE_BYTES = 4 * DEFAULT_TILE_BYTES
 _INFLIGHT = 3
 # The most staging memory the process keeps between operations
 # (_KeptRing). A ring is _ring_slots() slots (12 at the defaults) of the
-# plan's slot_bytes: 12 x 10 MiB for a 1 MiB-tile encode, 12 x 40 for
-# a rebuild's 4 MiB tiles, 12 x 40 and 12 x 60 MiB for batch encodes of
+# plan's slot_bytes: 12 x 40 MiB for the 4 MiB tiles of a single-volume
+# encode or rebuild, 12 x 40 and 12 x 60 MiB for batch encodes of
 # four and six volumes, 12 x 80 MiB for a batch rebuild of four volumes
 # in rounds of 2 MiB a survivor (batch_rebuild_tile_bytes sizes its round
 # so that the ring stays under this), all kept; a ring
@@ -221,7 +222,7 @@ def batch_rebuild_tile_bytes(volumes: int, slots: int) -> int:
     """Per-survivor bytes of one round of the mesh batch rebuild, from
     what the driver can see: the volumes it stacks a round and the slots
     of its ring. The largest power of two from BATCH_REBUILD_MIN_TILE_BYTES
-    to REBUILD_TILE_BYTES whose ring (slots x volumes x 10 x tile) the
+    to VOLUME_TILE_BYTES whose ring (slots x volumes x 10 x tile) the
     process still keeps (_RING_KEEP_BYTES): at 12 slots 4 MiB for one or
     two volumes, 2 MiB for three or four, 1 MiB for five to eight, and
     512 KiB beyond, where no size in range fits.
@@ -238,7 +239,7 @@ def batch_rebuild_tile_bytes(volumes: int, slots: int) -> int:
     six readers fault 160 MiB slots in (the first round lands after
     0.85 s, ring_fresh_bytes 2,013,265,920 a call). PERF.md section 6,
     PR 39."""
-    tile = REBUILD_TILE_BYTES
+    tile = VOLUME_TILE_BYTES
     while (
         tile > BATCH_REBUILD_MIN_TILE_BYTES
         and slots * volumes * DATA_SHARDS * tile > _RING_KEEP_BYTES
@@ -413,41 +414,45 @@ def _preallocate(fd: int, size: int) -> None:
 
 
 def _pwrite_full(fd: int, buf, offset: int) -> None:
-    """Positioned write of the whole buffer (pwritev can short-write on
-    signals / rlimits; a silent short write would corrupt the shard)."""
-    _pwritev_full(fd, [buf], offset)
-
-
-def _pwritev_full(fd: int, bufs, offset: int) -> None:
-    """Positioned gathered write of every buffer, restarting cleanly
-    across short writes. One syscall lands a super-tile's whole run of
-    per-row blocks for a shard (buffers need not be contiguous in
-    memory — they ARE contiguous in the shard file)."""
-    mvs = [memoryview(b).cast("B") for b in bufs]
+    """Positioned write of the whole buffer, restarting cleanly across
+    short writes (pwritev can short-write on signals / rlimits; a silent
+    short write would corrupt the shard)."""
+    mv = memoryview(buf).cast("B")
     written = 0
-    while mvs:
-        w = os.pwritev(fd, mvs, offset + written)
+    while written < len(mv):
+        w = os.pwritev(fd, [mv[written:]], offset + written)
         if w <= 0:
             raise OSError(errno.EIO, f"short pwritev at {offset + written}")
         written += w
-        while mvs and w >= len(mvs[0]):
-            w -= len(mvs[0])
-            mvs.pop(0)
-        if mvs and w:
-            mvs[0] = mvs[0][w:]
 
 
 def _pread_into(fd: int, view, offset: int) -> int:
     """Positioned read filling `view` (a writable uint8 buffer); stops
     early only at EOF. Returns bytes read."""
-    mv = memoryview(view).cast("B")
-    got = 0
-    n = len(mv)
-    while got < n:
-        r = os.preadv(fd, [mv[got:]], offset + got)
+    return _preadv_into(fd, [view], offset)
+
+
+# The most buffers one preadv takes (Linux's IOV_MAX)
+_IOV_MAX = 1024
+
+
+def _preadv_into(fd: int, views: list, offset: int) -> int:
+    """Positioned scatter read filling `views` (writable uint8 buffers)
+    in order from ONE run of the file at `offset`, restarting cleanly
+    across short reads; stops early only at EOF. One syscall for up to
+    _IOV_MAX buffers. Returns bytes read."""
+    mvs = [memoryview(v).cast("B") for v in views]
+    got = j = 0
+    while j < len(mvs):
+        r = os.preadv(fd, mvs[j : j + _IOV_MAX], offset + got)
         if r == 0:
             break
         got += r
+        while j < len(mvs) and r >= len(mvs[j]):
+            r -= len(mvs[j])
+            j += 1
+        if r:
+            mvs[j] = mvs[j][r:]
     return got
 
 
@@ -1027,22 +1032,28 @@ def stream_write_ec_files(
     docs/ANALYSIS.md v3: the writer pool's pwritev stream is otherwise
     entirely page-cache-resident when the .ecx lands).
 
-    parity_fn([10, step] u8 host tile) must *dispatch* the parity
+    parity_fn([10, W] u8 host tile) must *dispatch* the parity
     computation and return an opaque handle immediately; fetch_fn turns
-    the handle into a [4, step] u8 numpy array — or a
-    ([4, step] u8, [14] CRC-32C) pair when the stage computed fused
+    the handle into a [4, W] u8 numpy array — or a
+    ([4, W] u8, [14] CRC-32C) pair when the stage computed fused
     shard CRCs (blocking; called concurrently from the writer pool, so
-    both must be thread-safe). The defaults run the SWAR kernel on the
-    attached TPU. The indirection keeps the pipeline logic testable on
-    CPU hosts (tests inject a numpy parity_fn and still exercise
-    tiling/offsets/write paths).
+    both must be thread-safe). Row i of a tile is shard i's next W bytes
+    (shard-major): a run of `rows` full rows of the .dat is ONE tile of
+    W = rows x block, and a sub-block tile of the large tier one of W =
+    its step. The defaults run the SWAR kernel on the attached TPU. The
+    indirection keeps the pipeline logic testable on CPU hosts (tests
+    inject a numpy parity_fn and still exercise tiling/offsets/write
+    paths).
 
     want_crcs=True lands a 14-entry `shard_crcs` list in `stats`: the
     standard CRC-32C of every finished shard FILE, folded from the
     per-tile CRCs the stage pair returns (util/crc.crc32c_combine).
     Tiles whose stage pair declined the fused CRC (injected test fns,
-    non-power-of-two tails) are checksummed host-side in the writer
-    pool and charged to compute_s — the contract holds either way.
+    widths the fused kernel does not take) are checksummed host-side in
+    the writer pool and charged to compute_s — the contract holds either
+    way. `tiles` (the stage calls the dispatcher made) and `tile_bytes`
+    (the per-shard bytes a tile may carry) land in `stats` and on the
+    root span.
 
     Host staging buffers live in a _StagingRing of
     _INFLIGHT + writer_threads + 1 slots (the dispatched-but-unfetched
@@ -1057,160 +1068,93 @@ def stream_write_ec_files(
     )
     if device_stage:
         parity_fn, fetch_fn = _tpu_encode_fns(want_crcs=want_crcs, book=op.book)
-    tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
+    tile_bytes = tile_bytes or VOLUME_TILE_BYTES
 
     dat_path = base_file_name + ".dat"
     dat_size = os.path.getsize(dat_path)
-    from seaweedfs_tpu.ec.ec_files import iter_ec_tiles, to_ext
+    from seaweedfs_tpu.ec.ec_files import to_ext
 
     # tiles and their shard-file output offsets, precomputed: each tile
     # contributes exactly `width` bytes per shard in generation order,
-    # so positioned writes land it wherever it finishes. Consecutive
-    # FULL-ROW tiles (the whole small-block tier once tile_bytes ≥
-    # small_block_size) merge into SUPER-TILES of up to tile_bytes per
-    # shard: one contiguous .dat read, one codec call, and one pwritev
-    # per shard then carry `rows` rows each — per-row 1 MiB granularity
-    # drowned the pipeline in syscall + GIL round-trips.
-    tiles: list[tuple[int, int, int, int, int]] = []  # (row_off, block, batch_off, step, rows)
-    for row_off, block, batch_off, step in iter_ec_tiles(
+    # so positioned writes land it wherever it finishes
+    tiles = _encode_tiles(
         dat_size, tile_bytes, large_block_size, small_block_size
-    ):
-        if tiles and batch_off == 0 and step == block:
-            p_off, p_block, p_batch, p_step, p_rows = tiles[-1]
-            if (
-                p_batch == 0
-                and p_step == p_block == block
-                and p_off + p_rows * block * DATA_SHARDS == row_off
-                and (p_rows + 1) * block <= tile_bytes
-            ):
-                tiles[-1] = (p_off, p_block, 0, p_step, p_rows + 1)
-                continue
-        tiles.append((row_off, block, batch_off, step, 1))
-    out_offs, shard_bytes = [], 0
-    for _, _, _, step, rows in tiles:
-        out_offs.append(shard_bytes)
-        shard_bytes += step * rows
+    )
+    widths = [step * rows for _, _, _, step, rows in tiles]
+    out_offs = list(itertools.accumulate(widths, initial=0))
+    shard_bytes = out_offs.pop()
     # per-tile shard CRCs, filled by the writer pool (index writes are
     # GIL-atomic), folded into whole-file CRCs after the join
     tile_crcs: list = [None] * len(tiles)
+    launches = 0
 
     def fill(src, k, buf):
-        # one flat [rows, 10, step] ring-slot prefix per tile, preadv
-        # straight into it (no bytes objects, no shared seek position
-        # across the pool), zero-padded past EOF like read_dat_tile —
-        # and only spans the .dat does not cover pay the memset. NO
-        # reshuffling into shard order: the codec consumes contiguous
-        # per-row [10, step] views and the writer gather-writes each
-        # shard's run of blocks with one iovec pwritev, so the bytes are
-        # copied exactly once between disk reads and writes.
+        # one [10, width] ring-slot prefix per tile, read straight into
+        # it (no bytes objects, no shared seek position across the
+        # pool), zero-padded past EOF like read_dat_tile. The codec
+        # takes it as it lies and the writers land each of its rows with
+        # one pwrite, so the bytes are copied exactly once between disk
+        # reads and writes.
         (fd,) = src
         row_off, block, batch_off, step, rows = tiles[k]
-        flat = buf[: rows * DATA_SHARDS * step]
+        tile = buf[: DATA_SHARDS * widths[k]].reshape(DATA_SHARDS, widths[k])
         if batch_off == 0 and step == block:
-            # full rows are CONTIGUOUS in the .dat: one read covers the
-            # whole super-tile
-            n = max(0, min(len(flat), dat_size - row_off))
-            if n < len(flat):
-                flat[n:] = 0
-            if n:
-                got = _pread_into(fd, flat[:n], row_off)
-                if got < n:  # truncated .dat: pad like classic
-                    flat[got:n] = 0
+            _read_rows_into(fd, dat_size, row_off, step, tile)
         else:
-            # sub-block tile of the large tier: rows == 1, shard blocks
-            # are strided through the .dat
             _read_tile_into(
-                fd, dat_size, row_off, block, batch_off, step,
-                flat.reshape(DATA_SHARDS, step),
+                fd, dat_size, row_off, block, batch_off, step, tile
             )
-        return flat
+        return tile
 
-    def dispatch(k, flat):
-        _, _, _, step, rows = tiles[k]
+    def dispatch(k, tile):
+        nonlocal launches
         t0 = time.perf_counter()
-        # staging: each [10, step] view is contiguous in the ring slot,
-        # so the injected stage contract (and the TPU H2D) sees an
-        # ordinary tile
-        views = [
-            flat[
-                r * DATA_SHARDS * step : (r + 1) * DATA_SHARDS * step
-            ].reshape(DATA_SHARDS, step)
-            for r in range(rows)
-        ]
-        t1 = time.perf_counter()
-        # one async parity dispatch per row
-        handles = [parity_fn(v) for v in views]
-        t2 = time.perf_counter()
-        op.book("stage_s", t1 - t0)
-        op.book("device_s", t2 - t1)
-        return handles
+        handle = parity_fn(tile)  # one async dispatch a tile
+        op.book("device_s", time.perf_counter() - t0)
+        launches += 1
+        return handle
 
-    def fetch(k, flat, handles):
-        """([rows] parity [4, step], [rows] 14 CRCs or None)."""
-        parities, crc_rows = [], []
-        for h in handles:
-            got = fetch_fn(h)
-            if isinstance(got, tuple):
-                parities.append(got[0])
-                crc_rows.append(got[1])
-            else:
-                parities.append(got)
-                crc_rows.append(None)
-        return parities, crc_rows
+    def fetch(k, tile, handle):
+        """(parity [4, width], 14 CRCs or None)."""
+        got = fetch_fn(handle)
+        return got if isinstance(got, tuple) else (got, None)
 
-    def checksum(k, flat, result):
-        # rows for which the stage declined the fused CRC (injected
-        # pair / unsupported shape): table-CRC the written bytes here
+    def checksum(k, tile, result):
+        # a tile for which the stage declined the fused CRC (injected
+        # pair / unsupported width): table-CRC the written bytes here
         from seaweedfs_tpu.util.crc import crc32c
 
-        step = tiles[k][3]
-        parities, crc_rows = result
-        for r, c in enumerate(crc_rows):
-            if c is not None:
-                continue
-            row0 = r * DATA_SHARDS * step
-            crc_rows[r] = [
-                crc32c(flat[row0 + i * step : row0 + (i + 1) * step].tobytes())
-                for i in range(DATA_SHARDS)
-            ] + [
-                crc32c(np.ascontiguousarray(parities[r][p]).tobytes())
-                for p in range(PARITY_SHARDS)
+        parity, crcs = result
+        if crcs is None:
+            crcs = [crc32c(row.tobytes()) for row in tile] + [
+                crc32c(np.ascontiguousarray(row).tobytes()) for row in parity
             ]
-        tile_crcs[k] = crc_rows
+        tile_crcs[k] = crcs
 
-    def write(fds, k, flat, result):
-        _, _, _, step, rows = tiles[k]
-        parities, off = result[0], out_offs[k]
+    def write(fds, k, tile, result):
+        parity, off = result[0], out_offs[k]
         for i in range(DATA_SHARDS):
-            _pwritev_full(
-                fds[i],
-                [
-                    flat[
-                        (r * DATA_SHARDS + i) * step : (r * DATA_SHARDS + i + 1)
-                        * step
-                    ]
-                    for r in range(rows)
-                ],
-                off,
-            )
+            _pwrite_full(fds[i], tile[i], off)
         for p in range(PARITY_SHARDS):
-            _pwritev_full(
-                fds[DATA_SHARDS + p],
-                [np.ascontiguousarray(parities[r][p]) for r in range(rows)],
-                off,
+            _pwrite_full(
+                fds[DATA_SHARDS + p], np.ascontiguousarray(parity[p]), off
             )
 
     def report(out, sp, whole):
         out["driver"] = _driver_name(device_stage)
         if hasattr(parity_fn, "arms"):
             out["arms"] = dict(parity_fn.arms)
+        out["tiles"] = launches
+        out["tile_bytes"] = tile_bytes
+        sp.annotate("tiles", launches)
+        sp.annotate("tile_bytes", tile_bytes)
         if want_crcs and whole:
-            out["shard_crcs"] = _fold_encode_crcs(tiles, tile_crcs)
+            out["shard_crcs"] = _fold_encode_crcs(widths, tile_crcs)
 
     op.run(
         nbytes=dat_size,
         items=list(range(len(tiles))),
-        slot_bytes=DATA_SHARDS * tile_bytes,
+        slot_bytes=DATA_SHARDS * max(widths, default=tile_bytes),
         outputs=[
             (base_file_name + to_ext(i), shard_bytes)
             for i in range(TOTAL_SHARDS)
@@ -1230,21 +1174,58 @@ def stream_write_ec_files(
     )
 
 
-def _fold_encode_crcs(tiles: list, tile_crcs: list) -> list[int]:
-    """Whole-shard-file CRC-32C per shard from the per-tile row CRCs:
-    fold in tile/row generation order with crc32c_combine (tiles land
-    on disk in ANY order — positioned writes — but the fold is over
-    the recorded CRCs, so completion order is irrelevant here too)."""
+def _fold_encode_crcs(widths: list[int], tile_crcs: list) -> list[int]:
+    """Whole-shard-file CRC-32C per shard from the per-tile CRCs: fold
+    in tile generation order with crc32c_combine (tiles land on disk in
+    ANY order — positioned writes — but the fold is over the recorded
+    CRCs, so completion order is irrelevant here too)."""
     from seaweedfs_tpu.util.crc import crc32c_combine
 
     crcs = [0] * TOTAL_SHARDS
-    for k, (_, _, _, step, rows) in enumerate(tiles):
-        per_rows = tile_crcs[k]
-        for r in range(rows):
-            row = per_rows[r]
-            for i in range(TOTAL_SHARDS):
-                crcs[i] = crc32c_combine(crcs[i], int(row[i]), step)
+    for width, tile in zip(widths, tile_crcs):
+        for i in range(TOTAL_SHARDS):
+            crcs[i] = crc32c_combine(crcs[i], int(tile[i]), width)
     return crcs
+
+
+def _encode_tiles(
+    dat_size: int, tile_bytes: int, large: int, small: int
+) -> list[tuple[int, int, int, int, int]]:
+    """(row_off, block, batch_off, step, rows) tiles of the single-volume
+    encode, in generation order. A run of consecutive FULL rows (the
+    whole small-block tier once tile_bytes >= small_block_size) is cut
+    into tiles of a power-of-two count of rows, the largest first, at
+    most tile_bytes // block a tile: 103 rows of 1 MiB under 4 MiB tiles
+    are 25 x 4 + 2 + 1, so every tile's width is a power of two where the
+    block is, the lane counts the fused CRC takes (crc_kernel.
+    crc_supported). A sub-block tile of the large tier is one tile of
+    rows == 1, as ec_files.iter_ec_tiles yields it."""
+    from seaweedfs_tpu.ec.ec_files import iter_ec_tiles
+
+    runs: list[list[int]] = []
+    for row_off, block, batch_off, step in iter_ec_tiles(
+        dat_size, tile_bytes, large, small
+    ):
+        prev = runs[-1] if runs else None
+        if (
+            prev is not None
+            and batch_off == 0 and step == block
+            and prev[2] == 0 and prev[3] == prev[1] == block
+            and prev[0] + prev[4] * block * DATA_SHARDS == row_off
+        ):
+            prev[4] += 1  # the next full row, contiguous in the .dat
+        else:
+            runs.append([row_off, block, batch_off, step, 1])
+    tiles = []
+    for row_off, block, batch_off, step, rows in runs:
+        most = max(1, tile_bytes // block)
+        most = 1 << (most.bit_length() - 1)
+        while rows:
+            n = min(most, 1 << (rows.bit_length() - 1))
+            tiles.append((row_off, block, batch_off, step, n))
+            row_off += n * block * DATA_SHARDS
+            rows -= n
+    return tiles
 
 
 # --- rebuild driver ---------------------------------------------------------
@@ -1317,7 +1298,7 @@ def stream_rebuild_ec_files(
     )
     if device_stage:
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs, book=op.book)
-    tile_bytes = tile_bytes or REBUILD_TILE_BYTES
+    tile_bytes = tile_bytes or VOLUME_TILE_BYTES
     remote_readers = dict(remote_readers or {})
     fold_spans = device_stage and want_crcs
 
@@ -1847,6 +1828,33 @@ def _tpu_rebuild_fns(want_crcs: bool, book: Callable[[str, float], None]):
 
     rebuild_fn.arms = arms
     return rebuild_fn, _fetch
+
+
+def _read_rows_into(
+    fd: int, dat_size: int, row_off: int, step: int, dest: np.ndarray
+) -> None:
+    """Fill dest [10, rows x step] with `rows` whole rows of the .dat from
+    row_off, shard-major: row r's block i lands at dest[i, r*step :
+    (r+1)*step], so each row of dest is one shard's run of blocks. The
+    rows lie contiguous in the .dat, so ONE preadv whose buffers list
+    those places in file order reads them all; zero-padded past EOF (and
+    past a truncated .dat's end) like read_dat_tile."""
+    rows = dest.shape[1] // step
+    places = [
+        dest[i, r * step : (r + 1) * step]
+        for r in range(rows)
+        for i in range(DATA_SHARDS)
+    ]
+    n = max(0, min(len(places) * step, dat_size - row_off))
+    full, part = divmod(n, step)
+    got = _preadv_into(
+        fd, places[:full] + ([places[full][:part]] if part else []), row_off
+    ) if n else 0
+    full, part = divmod(got, step)
+    if full < len(places):
+        places[full][part:] = 0
+        for place in places[full + 1 :]:
+            place[:] = 0
 
 
 def _read_tile_into(

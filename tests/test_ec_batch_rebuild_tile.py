@@ -50,7 +50,7 @@ def test_rule_takes_the_largest_tile_whose_ring_is_kept(volumes, expected):
     tile = ec_stream.batch_rebuild_tile_bytes(volumes, SLOTS)
     assert tile == expected and tile in SIZES
     assert SIZES[0] == ec_stream.BATCH_REBUILD_MIN_TILE_BYTES
-    assert SIZES[-1] == ec_stream.REBUILD_TILE_BYTES
+    assert SIZES[-1] == ec_stream.VOLUME_TILE_BYTES
     fits = [t for t in SIZES
             if SLOTS * volumes * ec_stream.DATA_SHARDS * t <= ec_stream._RING_KEEP_BYTES]
     assert tile == (max(fits) if fits else SIZES[0])

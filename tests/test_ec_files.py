@@ -449,7 +449,7 @@ class TestStreamDrivers:
             rng.integers(0, 256, 300_000, dtype=np.uint8).tobytes()
         )
         calls = {"n": 0}
-        real_pwritev = ec_stream._pwritev_full
+        real_pwritev = ec_stream._pwrite_full
 
         def flaky_pwritev(fd, bufs, offset):
             calls["n"] += 1
@@ -457,7 +457,7 @@ class TestStreamDrivers:
                 raise OSError(errno.ENOSPC, "No space left on device")
             return real_pwritev(fd, bufs, offset)
 
-        monkeypatch.setattr(ec_stream, "_pwritev_full", flaky_pwritev)
+        monkeypatch.setattr(ec_stream, "_pwrite_full", flaky_pwritev)
         # leaks are counted by the pipeline's thread names and by this
         # test's own directory: an xdist worker's other threads and
         # sockets come and go

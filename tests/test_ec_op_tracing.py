@@ -232,13 +232,13 @@ def test_drain_ends_at_the_last_fetch_not_at_the_join(tmp_path, monkeypatch):
     """The writers leave the latest fetch-return sample in one slot and
     the handler's thread reads it after the join: slow shard writes lie
     in write_tail_s, a slow fetch in drain_s."""
-    real = ec_stream._pwritev_full
+    real = ec_stream._pwrite_full
 
     def slow_write(fd, views, offset):
         time.sleep(0.02)
         real(fd, views, offset)
 
-    monkeypatch.setattr(ec_stream, "_pwritev_full", slow_write)
+    monkeypatch.setattr(ec_stream, "_pwrite_full", slow_write)
     stats = _encode_single(tmp_path, host_pair=True, rows=1)
     assert stats["write_tail_s"] >= 0.2  # 14 writes of the last tile
     assert stats["drain_s"] < stats["write_tail_s"]

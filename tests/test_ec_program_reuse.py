@@ -276,7 +276,7 @@ def test_aborted_operation_leaves_the_programs_usable(tmp_path, monkeypatch):
         raise OSError(errno.ENOSPC, "No space left on device")
 
     with monkeypatch.context() as mp:
-        mp.setattr(ec_stream, "_pwritev_full", no_space)
+        mp.setattr(ec_stream, "_pwrite_full", no_space)
         with pytest.raises(OSError, match="No space left"):
             _encode(base)
     assert not os.path.exists(base + ec_files.to_ext(0))

@@ -121,7 +121,7 @@ def test_default_tile_of_a_local_rebuild(stage, fresh_ring, tmp_path):
     """No tile_bytes and no remote_readers: the module's local default,
     whichever stage applies the matrix (ISSUE 35 keeps one size for the
     device stage and for host codecs)."""
-    tile = ec_stream.REBUILD_TILE_BYTES
+    tile = ec_stream.VOLUME_TILE_BYTES
     shard_bytes = 2 * tile + tile // 2 + tile // 4 + 12
     base = str(tmp_path / "d_35")
     _write_shard_set(base, shard_bytes, seed=3501)

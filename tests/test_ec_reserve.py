@@ -107,7 +107,7 @@ DRIVERS = {
 }
 # the function through which each driver's reader pool reads its input
 READ_FN = {
-    "single": "_pread_into", "batch": "_read_tile_into",
+    "single": "_preadv_into", "batch": "_read_tile_into",
     "rebuild": "_pread_into", "rebuild_batch": "_pread_into",
 }
 NAMES = {

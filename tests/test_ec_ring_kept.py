@@ -266,7 +266,7 @@ def test_concurrent_operations_use_disjoint_memory(tmp_path, monkeypatch):
     assert not ec_stream_threads() and not fds_under(tmp_path)
 
 
-READ_FN = {"single": "_pread_into", "batch": "_read_tile_into"}
+READ_FN = {"single": "_preadv_into", "batch": "_read_tile_into"}
 
 
 @pytest.mark.parametrize("driver", sorted(READ_FN))
@@ -305,7 +305,7 @@ def test_the_aborted_operations_own_line_still_carries_the_field(
     vols = _Volumes(tmp_path, "v", SIZES[:1], seed=91)
     first = vols.single()
     monkeypatch.setattr(
-        ec_stream, "_pwritev_full",
+        ec_stream, "_pwrite_full",
         lambda *a: (_ for _ in ()).throw(OSError(28, "disk full")),
     )
     stats: dict = {}

@@ -67,7 +67,7 @@ def rack(tmp_path_factory):
     servers = {}
     previous = set_arbiter(BandwidthArbiter(total_bytes_s=64e9))
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(ec_stream, "REBUILD_TILE_BYTES", TILE)
+        mp.setattr(ec_stream, "VOLUME_TILE_BYTES", TILE)
         try:
             for name, ids in PLACEMENT.items():
                 vs = servers[name] = VolumeServer(

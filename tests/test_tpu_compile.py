@@ -4,10 +4,13 @@ Interpret mode and the CPU bit-matmul arm cannot see what the TPU's
 compiler refuses (tiling, VMEM, partitioning), and a chip run costs
 budget. The compiler is installed here and compiles for a chip that is
 described, not attached — so each program the stream and mesh drivers
-dispatch is lowered and compiled for "TPU v5 lite" at the width it
-really runs at (DEFAULT_TILE_BYTES = 1 MiB per shard row = 262144 u32
-lanes). Nothing executes: a compile that passes is not a chip run
-(chip_smoke.py is).
+dispatch is lowered and compiled for "TPU v5 lite" at the widths it
+really runs at: the batch drivers' round of DEFAULT_TILE_BYTES = 1 MiB
+a shard (262144 u32 lanes), and the single-volume drivers' tiles of
+VOLUME_TILE_BYTES = 4 MiB a shard (1048576 lanes) with the shorter
+power-of-two tiles a volume's last rows go as (524288 and 262144).
+Nothing executes: a compile that passes is not a chip run (chip_smoke.py
+is).
 
 The topology is described inside a module-scoped fixture — never at
 import, in a skipif or in a parametrize argument — so every xdist
@@ -20,7 +23,7 @@ import pytest
 
 from seaweedfs_tpu.ec import ec_stream
 
-TILE_LANES = 262144  # ec_stream.DEFAULT_TILE_BYTES // 4
+TILE_LANES = ec_stream.DEFAULT_TILE_BYTES // 4  # 262144
 
 
 @pytest.fixture(scope="module")
@@ -100,12 +103,19 @@ def test_swar_apply(one_chip, lanes):
     assert "tpu_custom_call" in text
 
 
-def test_encode_u32_crc(one_chip, on_tpu):
+# lanes of the single-volume encode's tiles: a run of four 1 MiB rows at
+# the default, and the runs of two and one its last rows go as
+# (ec_stream._encode_tiles)
+ENCODE_LANES = [ec_stream.VOLUME_TILE_BYTES // (4 * k) for k in (1, 2, 4)]
+
+
+@pytest.mark.parametrize("lanes", ENCODE_LANES)
+def test_encode_u32_crc(one_chip, on_tpu, lanes):
     """The stream encode driver's fused stage (ec_stream._tpu_encode_fns)."""
     from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
 
     kern = TpuCodecKernels()
-    text = _compiled_text(kern.encode_u32_crc, _u32((10, TILE_LANES), one_chip))
+    text = _compiled_text(kern.encode_u32_crc, _u32((10, lanes), one_chip))
     assert "tpu_custom_call" in text
 
 
@@ -230,7 +240,8 @@ def _scoped(text: str, scope: str) -> bool:
     return re.search(rf'op_name="[^"]*/{re.escape(scope)}/', text) is not None
 
 
-def test_encode_u32_crc_names(one_chip, on_tpu):
+@pytest.mark.parametrize("lanes", ENCODE_LANES)
+def test_encode_u32_crc_names(one_chip, on_tpu, lanes):
     """The compiled single-volume program: the kernel keeps the name the
     accepted roofline metric matches, and its three parts carry the
     scopes in their op_name metadata (the fusions' own names,
@@ -240,7 +251,7 @@ def test_encode_u32_crc_names(one_chip, on_tpu):
     from seaweedfs_tpu.ec.codec_tpu import TpuCodecKernels
 
     text = _compiled_text(
-        TpuCodecKernels().encode_u32_crc, _u32((10, TILE_LANES), one_chip)
+        TpuCodecKernels().encode_u32_crc, _u32((10, lanes), one_chip)
     )
     assert re.search(SWAR_EVENT, text)
     for scope in ("ec.swar", "ec.layout", "ec.crc_fold"):
@@ -249,7 +260,7 @@ def test_encode_u32_crc_names(one_chip, on_tpu):
 
 # lanes of a tile of the local rebuild's default (ISSUE 35) and of the two
 # power-of-two spans its tail goes to the device as (ec_stream._pow2_spans)
-REPAIR_LANES = [ec_stream.REBUILD_TILE_BYTES // (4 * k) for k in (1, 2, 4)]
+REPAIR_LANES = [ec_stream.VOLUME_TILE_BYTES // (4 * k) for k in (1, 2, 4)]
 
 
 @pytest.mark.parametrize("lanes", REPAIR_LANES)
@@ -348,8 +359,9 @@ def test_fused_programs_hold_no_gather(topo, one_chip, on_tpu):
 # --- the kept programs themselves ----------------------------------------------
 
 
+@pytest.mark.parametrize("lanes", ENCODE_LANES)
 def test_kept_encode_program_compiles_and_keeps_its_names(
-    one_chip, on_tpu, monkeypatch
+    one_chip, on_tpu, monkeypatch, lanes
 ):
     """The very jit object the stream encode driver launches
     (ec_stream._device_programs, taken under the chip's key): it lowers
@@ -363,7 +375,7 @@ def test_kept_encode_program_compiles_and_keeps_its_names(
     monkeypatch.setattr(ec_stream, "_KEPT", {})
     traces0 = codec_tpu.program_traces()
     program = ec_stream._device_programs().encode_u32_crc
-    shape = _u32((10, TILE_LANES), one_chip)
+    shape = _u32((10, lanes), one_chip)
     text = program.lower(shape).compile().as_text()
     assert codec_tpu.program_traces() - traces0 == 1
     assert text.startswith("HloModule jit_encode_u32_crc")
