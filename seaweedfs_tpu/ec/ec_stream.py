@@ -73,12 +73,11 @@ TOTAL_SHARDS = locate.TOTAL_SHARDS
 LARGE_BLOCK_SIZE = locate.LARGE_BLOCK_SIZE
 SMALL_BLOCK_SIZE = locate.SMALL_BLOCK_SIZE
 
-# Per-shard bytes per round of the batch encode (and the unit the batch
-# rebuild's smallest round is counted in): 1 MiB x 10 shards = 10 MiB of
-# host buffer per volume and ring slot. From BENCH_r12, a sweep of the
-# staging-ring driver on a host with no chip (1 MiB beat 4 MiB 1.27 vs
-# 0.81 GB/s on a disk-backed file system and matched it on tmpfs); no
-# chip has been asked about the batch encode's round yet.
+# The unit the stream drivers' tiles are counted in: 1 MiB a shard,
+# upstream's small block. A single-volume tile is four of it
+# (VOLUME_TILE_BYTES); the batch drivers' round rule
+# (batch_round_tile_bytes) floors at half of it, which is also the round
+# of the host batch rebuild (BATCH_REBUILD_MIN_TILE_BYTES).
 DEFAULT_TILE_BYTES = 1024 * 1024
 # Per-shard bytes per tile of a single-volume encode or rebuild, whether
 # a rebuild's survivors are local files or streamed from other servers:
@@ -106,10 +105,11 @@ _INFLIGHT = 3
 # The most staging memory the process keeps between operations
 # (_KeptRing). A ring is _ring_slots() slots (12 at the defaults) of the
 # plan's slot_bytes: 12 x 40 MiB for the 4 MiB tiles of a single-volume
-# encode or rebuild, 12 x 40 and 12 x 60 MiB for batch encodes of
-# four and six volumes, 12 x 80 MiB for a batch rebuild of four volumes
-# in rounds of 2 MiB a survivor (batch_rebuild_tile_bytes sizes its round
-# so that the ring stays under this), all kept; a ring
+# encode or rebuild, 12 x 80 and 12 x 60 MiB for batch encodes of four
+# volumes in rounds of 2 MiB a shard and of six in rounds of 1 MiB, 12 x
+# 80 MiB for a batch rebuild of four volumes in rounds of 2 MiB a
+# survivor (batch_round_tile_bytes sizes both batch drivers' rounds so
+# that the ring stays under this), all kept; a ring
 # beyond this (256 volumes a call are 2.5 GiB a slot) is allocated and
 # freed by its operation.
 _RING_KEEP_BYTES = 1 << 30
@@ -212,22 +212,26 @@ def _ring_slots(writer_threads: int | None = None) -> int:
     return _INFLIGHT + (writer_threads or DEFAULT_WRITER_THREADS) + 1
 
 
-# The smallest survivor span a round of the batch rebuild reads: the
-# 512 KiB every round was before the chip was asked (BENCH_r12, a
-# CPU-sandbox record), and still what the host arm takes.
+# The smallest per-shard span a round of a batch driver reads: the
+# 512 KiB every batch rebuild round was before the chip was asked
+# (BENCH_r12, a CPU-sandbox record), and still what the host arm of the
+# batch rebuild takes.
 BATCH_REBUILD_MIN_TILE_BYTES = DEFAULT_TILE_BYTES // 2
 
 
-def batch_rebuild_tile_bytes(volumes: int, slots: int) -> int:
-    """Per-survivor bytes of one round of the mesh batch rebuild, from
-    what the driver can see: the volumes it stacks a round and the slots
-    of its ring. The largest power of two from BATCH_REBUILD_MIN_TILE_BYTES
-    to VOLUME_TILE_BYTES whose ring (slots x volumes x 10 x tile) the
+def batch_round_tile_bytes(volumes: int, slots: int) -> int:
+    """Per-shard bytes a volume's share of one round of a mesh batch
+    driver may carry (a survivor's span in the batch rebuild, a data
+    shard's run of blocks in the batch encode), from what the driver can
+    see: the volumes it stacks a round and the slots of its ring. The
+    largest power of two from BATCH_REBUILD_MIN_TILE_BYTES to
+    VOLUME_TILE_BYTES whose ring (slots x volumes x 10 x tile) the
     process still keeps (_RING_KEEP_BYTES): at 12 slots 4 MiB for one or
     two volumes, 2 MiB for three or four, 1 MiB for five to eight, and
     512 KiB beyond, where no size in range fits.
 
-    A round is volumes x 10 preads whatever their size, and on a host
+    A batch rebuild's round is volumes x 10 preads whatever their size
+    (a batch encode's is one preadv a volume), and on a host
     whose pools share one interpreter lock a pread's price is mostly the
     call's: 0.97 ms at 512 KiB, 1.19 at 1 MiB, 1.42 at 2 MiB on a v5e's
     host, so four volumes' 1,040 MiB of survivors cost the reader pool
@@ -1096,14 +1100,8 @@ def stream_write_ec_files(
         # one pwrite, so the bytes are copied exactly once between disk
         # reads and writes.
         (fd,) = src
-        row_off, block, batch_off, step, rows = tiles[k]
         tile = buf[: DATA_SHARDS * widths[k]].reshape(DATA_SHARDS, widths[k])
-        if batch_off == 0 and step == block:
-            _read_rows_into(fd, dat_size, row_off, step, tile)
-        else:
-            _read_tile_into(
-                fd, dat_size, row_off, block, batch_off, step, tile
-            )
+        _read_encode_tile(fd, dat_size, tiles[k], tile)
         return tile
 
     def dispatch(k, tile):
@@ -1226,6 +1224,21 @@ def _encode_tiles(
             row_off += n * block * DATA_SHARDS
             rows -= n
     return tiles
+
+
+def _read_encode_tile(
+    fd: int, dat_size: int, tile: tuple[int, int, int, int, int],
+    dest: np.ndarray,
+) -> None:
+    """Fill dest [10, rows x step] with one tile of _encode_tiles, read
+    from the .dat: a run of full rows with ONE preadv, shard-major
+    (_read_rows_into), a sub-block tile of the large tier one read a
+    shard (_read_tile_into)."""
+    row_off, block, batch_off, step, _rows = tile
+    if batch_off == 0 and step == block:
+        _read_rows_into(fd, dat_size, row_off, step, dest)
+    else:
+        _read_tile_into(fd, dat_size, row_off, block, batch_off, step, dest)
 
 
 # --- rebuild driver ---------------------------------------------------------
@@ -1861,13 +1874,11 @@ def _read_tile_into(
     fd: int, dat_size: int, row_off: int, block: int, batch_off: int,
     step: int, dest: np.ndarray,
 ) -> None:
-    """Fill dest [10, step] (ring-slot views) with one volume's tile of
-    the .dat, zero-padded past EOF — the single home of the striping
-    math, for the batch reader and for the single-volume reader's
-    sub-block tiles. Per-row reads even for full rows: the batch
-    reader's dest rows are strided views of its slot, so there is no
-    contiguous span to coalesce into one pread here (the single-volume
-    reader takes its full rows in one read of its own)."""
+    """Fill dest [10, step] (ring-slot views) with one volume's sub-block
+    tile of the .dat, zero-padded past EOF: one read a shard, since a
+    sub-block tile's ten blocks lie a block apart in the .dat. Both
+    encode drivers take their runs of full rows with one preadv instead
+    (_read_rows_into), strided rows of a slot included."""
     for i in range(DATA_SHARDS):
         row = dest[i]
         off = row_off + i * block + batch_off
@@ -1912,10 +1923,21 @@ def stream_write_ec_files_batch(
     unbatched. WEED_EC_PIPELINE_BATCH caps volumes per dispatch round
     (ring memory = slots x batch x 10 x tile bytes).
 
+    A round holds, for each volume, one tile of the single-volume
+    encode's cut (_encode_tiles): a run of full rows of a power-of-two
+    count, laid out shard-major, read with ONE preadv and written with
+    ONE pwrite a shard file. `tile_bytes` is the most per-shard bytes a
+    volume's tile may carry; left None it follows from the volumes a
+    round stacks and the ring the process keeps (batch_round_tile_bytes:
+    2 MiB, two rows of 1 MiB, for four volumes under twelve slots; 1 MiB
+    for six). `tiles` (the rounds the dispatcher sent, summed over the
+    WEED_EC_PIPELINE_BATCH chunks) and `tile_bytes` land in `stats` and
+    on the root span.
+
     want_crcs=True lands `shard_crcs` in stats: one 14-entry CRC-32C
-    list per volume (fused on-mesh for full-width rounds — including
-    the stripe-axis CRC composition collective — host table CRC for
-    the short tail round)."""
+    list per volume (fused on-mesh where a volume's tile fills the
+    round's width — including the stripe-axis CRC composition
+    collective — host table CRC for a narrower tile)."""
     if not base_file_names:
         return
     limit = pipeline_batch_limit()
@@ -1947,7 +1969,7 @@ def stream_write_ec_files_batch(
                     if isinstance(v, float):
                         # stage seconds accumulate across chunks
                         stats[k] = round(stats.get(k, 0.0) + v, 4)
-                    elif k in ("program_traces", "ring_fresh_bytes"):
+                    elif k in ("program_traces", "ring_fresh_bytes", "tiles"):
                         stats[k] = stats.get(k, 0) + v
                     elif k != "shard_crcs":
                         # structural fields (pipeline_depth, mesh,
@@ -2060,17 +2082,20 @@ def _stream_batch_chunk(
     bases: list[str], codec, tile_bytes, large_block_size, small_block_size,
     stats, durable, want_crcs, reader_threads, writer_threads,
 ) -> None:
-    from seaweedfs_tpu.ec.ec_files import (
-        iter_ec_tiles, shard_file_size, to_ext,
-    )
+    from seaweedfs_tpu.ec.ec_files import shard_file_size, to_ext
 
-    tile_bytes = tile_bytes or DEFAULT_TILE_BYTES
     b = len(bases)
+    # the round's per-shard bytes a volume follow from what the driver
+    # can see, the volumes it stacks and the ring the process keeps, by
+    # the batch rebuild's rule: no caller passes a size
+    tile_bytes = tile_bytes or batch_round_tile_bytes(
+        b, _ring_slots(writer_threads)
+    )
     sizes = [os.path.getsize(base + ".dat") for base in bases]
+    # each volume's tiles as the single-volume encode cuts them: runs of
+    # full rows in power-of-two counts, read shard-major with ONE preadv
     tiles = [
-        list(
-            iter_ec_tiles(size, tile_bytes, large_block_size, small_block_size)
-        )
+        _encode_tiles(size, tile_bytes, large_block_size, small_block_size)
         for size in sizes
     ]
     rounds = max((len(ts) for ts in tiles), default=0)
@@ -2083,42 +2108,43 @@ def _stream_batch_chunk(
         if stats is not None and want_crcs:
             stats["shard_crcs"] = [[0] * TOTAL_SHARDS for _ in bases]
         return
-    vol_axis, stripe, width = _mesh_width(
-        codec, b, max(step for ts in tiles for _, _, _, step in ts)
-    )
-    # fused CRC needs power-of-two lanes per device (crc_kernel); the
-    # tail rounds (step < width) are host-checksummed regardless
-    fused_crc = want_crcs and codec.crc_supported(width)
-    step_of = [
-        [(ts[r][3] if r < len(ts) else 0) for ts in tiles]
+    # per-shard bytes of each volume's tile a round (rows x step; 0 once
+    # the volume is done)
+    widths = [
+        [(ts[r][3] * ts[r][4] if r < len(ts) else 0) for ts in tiles]
         for r in range(rounds)
     ]
+    vol_axis, stripe, width = _mesh_width(
+        codec, b, max(w for row in widths for w in row)
+    )
+    # fused CRC needs power-of-two lanes per device (crc_kernel); a
+    # volume's narrower tile (w < width) is host-checksummed regardless
+    fused_crc = want_crcs and codec.crc_supported(width)
     out_offs = []  # [rounds][volume] output offset
     acc = [0] * b
     for r in range(rounds):
         out_offs.append(list(acc))
         for v in range(b):
-            acc[v] += step_of[r][v]
+            acc[v] += widths[r][v]
     round_crcs: list = [None] * rounds
     # fewest mesh devices that held part of a round's batch: the
     # sharding can silently land everything on device 0
     held = vol_axis * stripe
+    launches = 0
     op = _Op("ec_stream.encode_batch", True, _DEVICE_BUSY)
 
     def fill(fds, r, buf):
         buf3 = buf[: b * DATA_SHARDS * width].reshape(b, DATA_SHARDS, width)
         for v in range(b):
             if r >= len(tiles[v]):
-                continue  # volume done: zero-step, output discarded
-            row_off, block, batch_off, step = tiles[v][r]
-            _read_tile_into(
-                fds[v], sizes[v], row_off, block, batch_off, step,
-                buf3[v, :, :step],
+                continue  # volume done: zero-width, output discarded
+            _read_encode_tile(
+                fds[v], sizes[v], tiles[v][r], buf3[v, :, : widths[r][v]]
             )
         return buf3
 
     def dispatch(r, buf3):
-        nonlocal held
+        nonlocal held, launches
         t0 = time.perf_counter()
         # staging: the u32 lane view is free host-side; device_put
         # lays the batch out P('vol', None, 'stripe') over the mesh
@@ -2139,6 +2165,7 @@ def _stream_batch_chunk(
         # the transfer is part of the stage; the launch is all of device_s
         op.book("h2d_s", th - t0)
         op.book("launch_s", t2 - t1)
+        launches += 1
         return handle
 
     def fetch(r, buf3, handle):
@@ -2163,36 +2190,37 @@ def _stream_batch_chunk(
         parity, crcs = result
         vol_crcs: list = [None] * b
         for v in range(b):
-            step = step_of[r][v]
-            if not step:
+            w = widths[r][v]
+            if not w:
                 continue
-            if crcs is not None and step == width:
+            if crcs is not None and w == width:
                 vol_crcs[v] = [int(c) for c in crcs[v]]
             else:
-                # tail round: the fused CRC would cover the padded
+                # a narrower tile: the fused CRC would cover the padded
                 # width; table-CRC the written bytes
                 vol_crcs[v] = [
-                    crc32c(buf3[v, i, :step].tobytes())
+                    crc32c(buf3[v, i, :w].tobytes())
                     for i in range(DATA_SHARDS)
                 ] + [
-                    crc32c(np.ascontiguousarray(parity[v, p, :step]).tobytes())
+                    crc32c(np.ascontiguousarray(parity[v, p, :w]).tobytes())
                     for p in range(PARITY_SHARDS)
                 ]
         round_crcs[r] = vol_crcs
 
     def write(fds, r, buf3, result):
+        # ONE pwrite a shard file a round: the volume's rows x step bytes
         parity = result[0]
         for v in range(b):
-            step = step_of[r][v]
-            if not step:
+            w = widths[r][v]
+            if not w:
                 continue
             off, first = out_offs[r][v], v * TOTAL_SHARDS
             for i in range(DATA_SHARDS):
-                _pwrite_full(fds[first + i], buf3[v, i, :step], off)
+                _pwrite_full(fds[first + i], buf3[v, i, :w], off)
             for p in range(PARITY_SHARDS):
                 _pwrite_full(
                     fds[first + DATA_SHARDS + p],
-                    np.ascontiguousarray(parity[v, p, :step]),
+                    np.ascontiguousarray(parity[v, p, :w]),
                     off,
                 )
 
@@ -2202,16 +2230,20 @@ def _stream_batch_chunk(
         # the same count as a number of its own: a metric term reads a
         # top-level field, not a nested dict
         out["mesh_devices"] = held
+        out["tiles"] = launches
+        out["tile_bytes"] = tile_bytes
         if want_crcs and whole:
             out["shard_crcs"] = [
                 list(crcs.values())
                 for crcs in _fold_round_crcs(
-                    b, range(TOTAL_SHARDS), step_of, round_crcs
+                    b, range(TOTAL_SHARDS), widths, round_crcs
                 )
             ]
         sp.annotate("mesh", f"{vol_axis}x{stripe}")
         sp.annotate("mesh_devices", held)
         sp.annotate("batch_volumes", b)
+        sp.annotate("tiles", launches)
+        sp.annotate("tile_bytes", tile_bytes)
 
     op.run(
         nbytes=sum(sizes),
@@ -2301,7 +2333,7 @@ def stream_rebuild_ec_files_batch(
 
     `tile_bytes` is an override for tests and sweeps. Left None, the
     mesh arm sizes the round from the widest chunk's volumes and the
-    ring the process keeps (batch_rebuild_tile_bytes: 2 MiB a survivor
+    ring the process keeps (batch_round_tile_bytes: 2 MiB a survivor
     row for four volumes, which the chip read at 4.5 GB/s of volume
     repaired where 512 KiB read 2.6: PERF.md section 6, PR 39), and the
     host arm takes BATCH_REBUILD_MIN_TILE_BYTES (512 KiB: a CPU-sandbox
@@ -2543,7 +2575,7 @@ def _rebuild_batch_pass(
     # the round's span follows from what the driver can see, the widest
     # chunk it stacks and the ring the process keeps: no caller passes a
     # size
-    tile_bytes = tile_bytes or batch_rebuild_tile_bytes(
+    tile_bytes = tile_bytes or batch_round_tile_bytes(
         max(len(chunk_bases) for chunk_bases, _, _ in chunks),
         _ring_slots(writer_threads),
     )

@@ -62,7 +62,7 @@ def _tile(volumes: int) -> int:
     """The mesh stage's tile for `volumes` stacked volumes by the driver's
     own rule (ISSUE 39: sized from the chunk's volumes and the kept ring,
     whatever the chip chose), under the node's default pools."""
-    return ec_stream.batch_rebuild_tile_bytes(volumes, ec_stream._ring_slots())
+    return ec_stream.batch_round_tile_bytes(volumes, ec_stream._ring_slots())
 
 
 def _rounds(volumes: int) -> int:

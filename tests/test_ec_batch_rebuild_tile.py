@@ -1,7 +1,7 @@
 """The batch rebuild's round tile (ISSUE 39): the mesh arm of
 `stream_rebuild_ec_files_batch` sizes the span a survivor `pread` moves
 from the volumes it stacks a round and the staging ring the process
-keeps (`ec_stream.batch_rebuild_tile_bytes`), where it took the 512 KiB
+keeps (`ec_stream.batch_round_tile_bytes`), where it took the 512 KiB
 of a CPU-sandbox sweep whatever the batch. The rule as a pure function;
 the mesh arm at every size the rule can choose, through a MeshCodec over
 CPU devices, held to `rebuild_ec_files`' bytes and CRCs; the kept ring
@@ -47,7 +47,7 @@ def test_rule_takes_the_largest_tile_whose_ring_is_kept(volumes, expected):
     """At twelve slots, the numbers ISSUE 39 and the comments state; and
     what makes them so: a power of two in [512 KiB, 4 MiB], the largest
     whose ring the process keeps, never below 512 KiB where none fits."""
-    tile = ec_stream.batch_rebuild_tile_bytes(volumes, SLOTS)
+    tile = ec_stream.batch_round_tile_bytes(volumes, SLOTS)
     assert tile == expected and tile in SIZES
     assert SIZES[0] == ec_stream.BATCH_REBUILD_MIN_TILE_BYTES
     assert SIZES[-1] == ec_stream.VOLUME_TILE_BYTES
@@ -62,7 +62,7 @@ def test_rule_reads_the_slots_of_the_callers_ring():
     assert ec_stream._ring_slots(2) == ec_stream._INFLIGHT + 3
     assert ec_stream._ring_slots() == ec_stream._ring_slots(
         ec_stream.DEFAULT_WRITER_THREADS)
-    assert ec_stream.batch_rebuild_tile_bytes(4, ec_stream._ring_slots(2)) == 4 * MIB
+    assert ec_stream.batch_round_tile_bytes(4, ec_stream._ring_slots(2)) == 4 * MIB
 
 
 # --- the mesh arm at every size the rule can choose ---------------------------------
@@ -134,7 +134,7 @@ def _check(bases: list[str], stats: dict, want: list[dict], crcs: list[dict]) ->
 ], ids=["1vol-4MiB", "3vol-2MiB", "4vol-2MiB-2x2", "5vol-1MiB", "9vol-512KiB"])
 def test_mesh_arm_at_the_rules_size_is_rebuild_ec_files_byte_for_byte(
         volumes, mesh, tmp_path):
-    tile = ec_stream.batch_rebuild_tile_bytes(volumes, SLOTS)
+    tile = ec_stream.batch_round_tile_bytes(volumes, SLOTS)
     shard_bytes = _shard_bytes(volumes, tile)
     bases = _volumes(tmp_path, shard_bytes, seed=390 + volumes)
     want, crcs = _reference(bases)
@@ -184,7 +184,7 @@ def test_second_four_volume_call_at_the_default_runs_on_the_kept_ring(tmp_path):
     shard_bytes = [35 * SMALL, 34 * SMALL, 33 * SMALL, 21 * SMALL]
     bases = _volumes(tmp_path, shard_bytes, seed=139)
     want, crcs = _reference(bases)
-    chosen = ec_stream.batch_rebuild_tile_bytes(4, ec_stream._ring_slots())
+    chosen = ec_stream.batch_round_tile_bytes(4, ec_stream._ring_slots())
     seen = []
     for _ in range(2):
         stats: dict = {}

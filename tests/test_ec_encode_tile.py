@@ -205,7 +205,11 @@ def test_report_line_feeds_the_launch_metric(tmp_path, bench_harness):
     for key in ("name", "unit", "better", "layer", "moves", "source", "workloads"):
         assert metric[key] == entry[key], key
     assert entry["workloads"] == ["encode-1g"] and entry["moves"] == "ec_gbps"
-    assert manifest["per_layer"][-1] == entry
+    # appended after every metric before it; the batch encode's rounds
+    # metric follows it
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-2:] == [name, "batch_encode_rounds_per_gib"]
+    assert manifest["per_layer"][-2] == entry
     assert (metric["num"], metric["den"]) == (["report:tiles"], ["window:gib"])
 
     base = str(tmp_path / "v")

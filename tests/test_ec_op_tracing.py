@@ -716,8 +716,16 @@ def test_report_line_and_metrics_carry_ring_fresh_bytes(
     reports, before, after = _same_verb_twice(
         node, node_log, verb, volumes, "weed_ec_ring_fresh_bytes_total"
     )
-    # whole slots of [volumes, 10, 1 MiB] each
-    slot = volumes * 10 * ec_stream.DEFAULT_TILE_BYTES
+    # whole slots of [volumes, 10, W] each: W a run of whole rows of at
+    # most the tile a driver takes (VOLUME_TILE_BYTES alone, the batch
+    # round rule's for two volumes), here each volume's one 1 MiB row
+    tile = (
+        ec_stream.VOLUME_TILE_BYTES
+        if verb == "generate"
+        else ec_stream.batch_round_tile_bytes(volumes, ec_stream._ring_slots())
+    )
+    assert reports[0]["tile_bytes"] == tile
+    slot = volumes * 10 * min(tile, ec_stream.SMALL_BLOCK_SIZE)
     assert reports[0]["ring_fresh_bytes"] >= 2 * slot
     assert reports[0]["ring_fresh_bytes"] % slot == 0
     assert reports[1]["ring_fresh_bytes"] == 0

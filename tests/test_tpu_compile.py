@@ -5,8 +5,11 @@ compiler refuses (tiling, VMEM, partitioning), and a chip run costs
 budget. The compiler is installed here and compiles for a chip that is
 described, not attached — so each program the stream and mesh drivers
 dispatch is lowered and compiled for "TPU v5 lite" at the widths it
-really runs at: the batch drivers' round of DEFAULT_TILE_BYTES = 1 MiB
-a shard (262144 u32 lanes), and the single-volume drivers' tiles of
+really runs at: the batch drivers' rounds, whose per-shard bytes a
+volume ec_stream.batch_round_tile_bytes sizes from the volumes a round
+stacks and the kept ring (2 MiB, 524288 u32 lanes, for four volumes
+under twelve slots; 1 MiB, 262144 lanes, for six), and the single-volume
+drivers' tiles of
 VOLUME_TILE_BYTES = 4 MiB a shard (1048576 lanes) with the shorter
 power-of-two tiles a volume's last rows go as (524288 and 262144).
 Nothing executes: a compile that passes is not a chip run (chip_smoke.py
@@ -214,6 +217,23 @@ def test_mesh_encode_batch_u32_crc(topo, vol, stripe):
     assert ("all-gather" in text) == (stripe > 1)
 
 
+@pytest.mark.parametrize("vol,stripe", [(1, 1), (4, 1)])
+def test_mesh_encode_batch_u32_crc_two_row_round(topo, vol, stripe):
+    """The batch encode's round for four volumes at the width the
+    driver's rule gives them under the ring of the chip's host (thirteen
+    cores, eight writers, twelve slots): two 1 MiB rows a volume, on the
+    one-chip node's 1x1 mesh and the 4x1 a four-chip node provisions for
+    four volumes; no stripe axis, so no collective."""
+    lanes = ec_stream.batch_round_tile_bytes(4, ec_stream._ring_slots(8)) // 4
+    assert lanes == 2 * TILE_LANES
+    codec, sharding = _mesh_codec(topo, vol, stripe)
+    text = _compiled_text(
+        codec.encode_batch_u32_crc, _u32((4, 10, lanes), sharding)
+    )
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text
+
+
 @pytest.mark.parametrize("vol,stripe", [(2, 2), (4, 1)])
 def test_mesh_verify_batch_u32(topo, vol, stripe):
     """The fused verify kernel; the stripe-axis psum is an all-reduce."""
@@ -314,7 +334,7 @@ def test_drive_loss_cell_decode_program_names(topo, vol, stripe):
     output rows; a positionwise decode holds no collective."""
     import re
 
-    lanes = ec_stream.batch_rebuild_tile_bytes(4, ec_stream._ring_slots(8)) // 4
+    lanes = ec_stream.batch_round_tile_bytes(4, ec_stream._ring_slots(8)) // 4
     survivors, targets = (0, 1, 2, 4, 5, 6, 7, 8, 9, 11), (3, 10)
     codec, sharding = _mesh_codec(topo, vol, stripe)
     text = _compiled_text(
