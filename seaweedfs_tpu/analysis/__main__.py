@@ -2,8 +2,8 @@
 
 Runs every checker over the package tree and exits 0 only when the
 tree is clean (no unsuppressed findings — and no suppression missing
-its mandatory reason). This is the same gate `bench.py --check` and
-`make lint` drive; docs/ANALYSIS.md is the catalog.
+its mandatory reason). tests/test_weedlint.py runs it in tier-1;
+docs/ANALYSIS.md is the catalog.
 
     python -m seaweedfs_tpu.analysis                   # all checkers
     python -m seaweedfs_tpu.analysis --rules contracts,lifecycle

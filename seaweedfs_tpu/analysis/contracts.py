@@ -828,18 +828,14 @@ def _load_docs(repo_root: str) -> dict[str, str]:
 
 
 def _load_extra_sources(repo_root: str) -> dict[str, str]:
-    """bench.py and tests/conftest.py read WEED_* vars and reference
-    metric names; they are part of the operational contract surface."""
-    out: dict[str, str] = {}
-    for rel in ("bench.py", os.path.join("tests", "conftest.py")):
-        try:
-            with open(
-                os.path.join(repo_root, rel), "r", encoding="utf-8"
-            ) as f:
-                out[rel] = f.read()
-        except OSError:
-            continue
-    return out
+    """tests/conftest.py reads WEED_* vars and references metric names;
+    it is part of the operational contract surface."""
+    rel = os.path.join("tests", "conftest.py")
+    try:
+        with open(os.path.join(repo_root, rel), "r", encoding="utf-8") as f:
+            return {rel: f.read()}
+    except OSError:
+        return {}
 
 
 def _parse_all(sources: dict[str, str]) -> dict[str, ast.Module]:
@@ -1195,10 +1191,10 @@ def check(
     findings += _check_env(reg)
     findings += _check_flags(reg)
     findings += _check_deadline(reg)
-    # findings anchored outside the package (docs, bench.py,
-    # tests/conftest.py) need those texts in the suppression scan, or
-    # the documented `# weedlint: ignore[...]` escape hatch silently
-    # does nothing for them
+    # findings anchored outside the package (docs, tests/conftest.py)
+    # need those texts in the suppression scan, or the documented
+    # `# weedlint: ignore[...]` escape hatch silently does nothing for
+    # them
     for rel, text in {**(docs or {}), **(extra or {})}.items():
         index.sources.setdefault(rel, text)
     return findings, index, reg

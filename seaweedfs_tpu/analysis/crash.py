@@ -1021,8 +1021,8 @@ def run_handoff_delivery(budget: int | None = None,
 
 def run_broken_publish(budget: int | None = None,
                        seed: int | None = None) -> CrashReport:
-    """Positive control (the planted bug bench --check must DETECT on
-    every run): a tmp+rename publish with NO fsync of the bytes. The
+    """Positive control (the planted bug tests/test_crash.py must DETECT
+    on every run): a tmp+rename publish with NO fsync of the bytes. The
     enumerator must produce at least one legal state where the rename
     landed but the data did not — an empty/torn file under the final
     name."""

@@ -206,9 +206,8 @@ def check_shm_atomics(
     is a data race the compiler may tear, cache, or reorder at will.
     Structural, statement-granular: a statement touching `*slot` /
     `slot[...]` / `weed_shm.tat[...]` must name `__atomic_*` and an
-    `__ATOMIC_` order. `source` overrides the tree's serve.c so the
-    planted-bug arm (bench --check race leg) can prove the rule fires
-    on a plain-store mutant."""
+    `__ATOMIC_` order. `source` overrides the tree's serve.c so a
+    planted-bug test can prove the rule fires on a plain-store mutant."""
     if source is None:
         try:
             with open(

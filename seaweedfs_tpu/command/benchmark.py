@@ -27,7 +27,7 @@ class LatencyStats:
         self.failed = 0
         self.start = time.perf_counter()
         # run_benchmark stamps phase end after the worker joins so
-        # programmatic callers (bench.py http) can compute req/s from
+        # programmatic callers can compute req/s from
         # the phase wall, not report() time
         self.ended: float | None = None
 

@@ -235,7 +235,7 @@ class ReedSolomon:
         (ec/crc_kernel.py): every stage pair hands the writer pool
         (shard bytes, crc) pairs so nothing downstream re-reads the
         bytes to checksum them. Byte- and CRC-identical to the device
-        pairs (enforced by tests and bench --check)."""
+        pairs (enforced by tests)."""
         from seaweedfs_tpu.util.crc import crc32c
 
         parity = self._apply(self.parity_rows, stacked)

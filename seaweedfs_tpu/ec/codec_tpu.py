@@ -609,8 +609,7 @@ class TpuCodecKernels:
 
     Holds the encode bit-matrix on device; decode bit-matrices are
     built host-side per survivor set (cached) and shipped once per
-    rebuild. Used by the streaming encoder, bench.py and the graft
-    entry points.
+    rebuild. Used by the streaming encoder and the graft entry points.
     """
 
     def __init__(self, data_shards: int = 10, parity_shards: int = 4):

@@ -39,8 +39,7 @@ where the SWAR kernel beside it takes 0.03 (PERF.md section 6, PR 31).
 
 Everything is ordinary XLA (int8 matmul + bit packing, the
 apply_matrix_bits idiom) — no Pallas, so it lowers on CPU and TPU with
-bit-identical results to util/crc.crc32c, which the tests and the
-bench --check pipeline-identity smoke enforce.
+bit-identical results to util/crc.crc32c, which the tests enforce.
 
 Shape contract: lane counts must be a power of two (every stream tile
 the drivers dispatch is; odd tails fall back to the host table CRC in

@@ -88,14 +88,12 @@ def _use_stream_driver(rs: ReedSolomon) -> bool:
     """Route to the pipelined ec_stream driver when the codec would run
     on an attached TPU anyway — output bytes are identical; the stream
     driver overlaps disk IO, H2D, kernel, and D2H instead of
-    round-tripping synchronously per batch. WEED_EC_PIPELINE=0 (the
-    pipeline kill switch) forces the serial classic loop wholesale."""
+    round-tripping synchronously per batch."""
     if rs._backend_name != "tpu":
         return False
-    from seaweedfs_tpu.ec import ec_stream
     from seaweedfs_tpu.ec.codec_tpu import _on_tpu
 
-    return ec_stream.pipeline_enabled() and _on_tpu()
+    return _on_tpu()
 
 
 def _stream_host_codec(rs: ReedSolomon) -> bool:
@@ -104,13 +102,8 @@ def _stream_host_codec(rs: ReedSolomon) -> bool:
     and pwritev writer pools overlap disk IO with the C encode, and the
     flush-free raw-fd writes drop the serial close tail the classic
     loop pays. The numpy "cpu" backend stays on the classic loop — it
-    is the bit-exact reference the others are judged against. The
-    WEED_EC_PIPELINE=0 kill switch overrides here too."""
-    if rs._backend_name != "native":
-        return False
-    from seaweedfs_tpu.ec import ec_stream
-
-    return ec_stream.pipeline_enabled()
+    is the bit-exact reference the others are judged against."""
+    return rs._backend_name == "native"
 
 
 def iter_ec_tiles(dat_size: int, tile: int, large: int, small: int):
@@ -174,7 +167,7 @@ def write_ec_files(
     buffer_size=None lets each driver pick its default (4 MiB classic
     IO batches; 4 MiB pipelined tiles on TPU/native hosts). A `stats` dict
     collects per-phase busy seconds so e2e throughput numbers stay
-    attributable (bench.py stream): the classic loop reports
+    attributable: the classic loop reports
     read_s/encode_s/write_s; the pipelined stream driver reports
     read_s/stage_s/device_s/writeback_s/compute_s/write_s plus its
     pipeline depth (overlapped stages — each pool's busy seconds).
@@ -182,8 +175,7 @@ def write_ec_files(
     want_crcs=True lands `shard_crcs` (14 whole-file CRC-32C values)
     in `stats` on every driver: fused into the device pass on the
     pipelined paths, a running table CRC on the classic loop — the
-    value contract is identical, so the WEED_EC_PIPELINE=0 kill switch
-    changes nothing callers can observe but speed."""
+    value contract is identical."""
     rs = rs or new_encoder()
     if rs.data_shards != DATA_SHARDS or rs.parity_shards != PARITY_SHARDS:
         raise ValueError("shard-file layout is fixed at RS(10,4)")
@@ -312,162 +304,23 @@ def write_ec_files_batch(
     goroutine-per-volume encode fan-out (command_ec_encode.go:153),
     lifted to SPMD.
 
-    The production arm is the PIPELINED driver
-    (ec_stream.stream_write_ec_files_batch): staging-ring overlap of
-    reads, H2D, the mesh program, D2H and shard writes, with fused
-    per-shard CRCs when want_crcs. WEED_EC_PIPELINE=0 restores this
-    serial per-round loop wholesale — byte-identical, no overlap, and
-    the same durable contract (durable=True fsyncs every shard file
-    before returning on BOTH arms, so the BatchGenerate verb's .ecx
-    publish ordering holds regardless of the kill switch).
-
-    Shapes stay static across rounds (finished volumes contribute zero
-    tiles that are discarded) so each driver compiles its program
-    once."""
+    The driver is ec_stream.stream_write_ec_files_batch: staging-ring
+    overlap of reads, H2D, the mesh program, D2H and shard writes, with
+    fused per-shard CRCs when want_crcs. durable=True fsyncs every
+    shard file before returning, so the BatchGenerate verb's .ecx
+    publish ordering holds."""
     from seaweedfs_tpu.ec import ec_stream
 
-    if not base_file_names:
-        return
-    if ec_stream.pipeline_enabled():
-        ec_stream.stream_write_ec_files_batch(
-            base_file_names,
-            codec=codec,
-            tile_bytes=tile_bytes,
-            large_block_size=large_block_size,
-            small_block_size=small_block_size,
-            stats=stats,
-            durable=durable,
-            want_crcs=want_crcs,
-        )
-        return
-    if codec is None:
-        # same self-provisioning recipe as the pipelined arm: the vol
-        # axis sized to gcd(batch, devices) so any batch shards cleanly
-        codec = ec_stream._default_mesh_codec(len(base_file_names))
-    tile_bytes = tile_bytes or DEFAULT_BUFFER_SIZE
-    for block in (large_block_size, small_block_size):
-        if block % tile_bytes != 0 and tile_bytes % block != 0:
-            raise ValueError("tile size must tile the block sizes")
-
-    b = len(base_file_names)
-    stripe = codec.mesh.devices.shape[1]
-    if b % codec.mesh.devices.shape[0]:
-        raise ValueError(
-            f"batch of {b} volumes does not shard over the mesh's "
-            f"{codec.mesh.devices.shape[0]}-way 'vol' axis"
-        )
-    tiles: list[list] = []
-    dats = []
-    sizes = []
-    outs = []
-    try:
-        for base in base_file_names:
-            size = os.path.getsize(base + ".dat")
-            sizes.append(size)
-            dats.append(open(base + ".dat", "rb"))
-            outs.append(
-                [open(base + to_ext(i), "wb") for i in range(TOTAL_SHARDS)]
-            )
-            tiles.append(
-                list(
-                    iter_ec_tiles(
-                        size, tile_bytes, large_block_size, small_block_size
-                    )
-                )
-            )
-        if not any(tiles):
-            # all .dat files empty: 14 empty shards each, done —
-            # durably, when asked: the verb's .ecx publish must never
-            # outlive shard files a crash can drop
-            if durable:
-                for fs in outs:
-                    for f in fs:
-                        os.fsync(f.fileno())
-            if stats is not None and want_crcs:
-                stats["shard_crcs"] = [[0] * TOTAL_SHARDS for _ in range(b)]
-            return
-        # one static tile width for every round: the max step, rounded
-        # so the u32 lane count splits over the stripe axis in whole
-        # SWAR-friendly chunks (1024 lanes per device minimum)
-        max_step = max(step for ts in tiles for _, _, _, step in ts)
-        gran = 4 * 1024 * stripe
-        width = -(-max_step // gran) * gran
-        rounds = max(len(ts) for ts in tiles)
-        batch = np.zeros((b, DATA_SHARDS, width), dtype=np.uint8)
-        crcs = [[0] * TOTAL_SHARDS for _ in range(b)]
-        from seaweedfs_tpu.util.crc import crc32c
-
-        for r in range(rounds):
-            batch[:] = 0
-            steps = [0] * b
-            for v in range(b):
-                if r >= len(tiles[v]):
-                    continue  # volume done: zero tile, output discarded
-                row_off, block, batch_off, step = tiles[v][r]
-                batch[v, :, :step] = read_dat_tile(
-                    dats[v], sizes[v], row_off, block, batch_off, step
-                )
-                steps[v] = step
-            parity = np.asarray(
-                codec.encode_batch_u32(
-                    codec.shard_volumes(batch.view(np.uint32))
-                )
-            ).view(np.uint8)
-            for v in range(b):
-                step = steps[v]
-                if not step:
-                    continue
-                for i in range(DATA_SHARDS):
-                    chunk = batch[v, i, :step].tobytes()
-                    outs[v][i].write(chunk)
-                    if want_crcs:
-                        crcs[v][i] = crc32c(chunk, crcs[v][i])
-                for i in range(PARITY_SHARDS):
-                    chunk = parity[v, i, :step].tobytes()
-                    outs[v][DATA_SHARDS + i].write(chunk)
-                    if want_crcs:
-                        crcs[v][DATA_SHARDS + i] = crc32c(
-                            chunk, crcs[v][DATA_SHARDS + i]
-                        )
-        if stats is not None and want_crcs:
-            stats["shard_crcs"] = crcs
-        if durable:
-            # same contract as the pipelined arm: a durable batch
-            # encode must not return until the shard bytes are on disk
-            # (success path only — a failed fsync fails the encode)
-            for fs in outs:
-                for f in fs:
-                    f.flush()
-                    os.fsync(f.fileno())
-    except BaseException:
-        # abort contract, matching the pipelined arm: no partial (or
-        # written-but-unsynced, when the durable fsync failed) shard
-        # set may survive for ANY volume — shard_presence counts any
-        # existing .ecNN as a valid shard, so leftovers would read as
-        # complete volumes to a later rebuild/scrub
-        for fs in outs:
-            for f in fs:
-                try:
-                    f.close()
-                except OSError:
-                    pass
-        for base in base_file_names:
-            for i in range(TOTAL_SHARDS):
-                try:
-                    os.remove(base + to_ext(i))
-                except OSError:
-                    pass
-        raise
-    finally:
-        for f in dats:
-            f.close()
-        for fs in outs:
-            for f in fs:
-                if not f.closed:
-                    try:
-                        f.close()
-                    except OSError:
-                        pass
+    ec_stream.stream_write_ec_files_batch(
+        base_file_names,
+        codec=codec,
+        tile_bytes=tile_bytes,
+        large_block_size=large_block_size,
+        small_block_size=small_block_size,
+        stats=stats,
+        durable=durable,
+        want_crcs=want_crcs,
+    )
 
 
 def rebuild_ec_files(
@@ -602,43 +455,19 @@ def rebuild_ec_files_batch(
     volume. Every survivor must be local (the remote rack-gather path
     stays per-volume).
 
-    WEED_EC_PIPELINE=0 restores a serial per-volume rebuild_ec_files
-    loop wholesale — byte-identical output, same durable contract.
     Returns the rebuilt id lists in input order; want_crcs lands
     `shard_crcs` in stats as one {rebuilt id: whole-file CRC-32C} dict
-    per volume on both arms."""
+    per volume."""
     from seaweedfs_tpu.ec import ec_stream
 
-    if not base_file_names:
-        return []
-    if ec_stream.pipeline_enabled():
-        return ec_stream.stream_rebuild_ec_files_batch(
-            base_file_names,
-            codec=codec,
-            tile_bytes=tile_bytes,
-            stats=stats,
-            durable=durable,
-            want_crcs=want_crcs,
-        )
-    results = []
-    all_crcs = []
-    for base in base_file_names:
-        s: dict = {}
-        results.append(
-            rebuild_ec_files(
-                base,
-                buffer_size=tile_bytes,
-                durable=durable,
-                stats=s,
-                want_crcs=want_crcs,
-            )
-        )
-        all_crcs.append(s.get("shard_crcs") or {})
-    if stats is not None:
-        stats["batch_volumes"] = len(base_file_names)
-        if want_crcs:
-            stats["shard_crcs"] = all_crcs
-    return results
+    return ec_stream.stream_rebuild_ec_files_batch(
+        base_file_names,
+        codec=codec,
+        tile_bytes=tile_bytes,
+        stats=stats,
+        durable=durable,
+        want_crcs=want_crcs,
+    )
 
 
 # --- .ecx sorted index ------------------------------------------------------

@@ -118,15 +118,6 @@ _RING_KEEP_BYTES = 1 << 30
 _SLOT_ALIGN = 4096
 
 
-def pipeline_enabled() -> bool:
-    """Kill switch for the whole device-resident pipeline plane:
-    WEED_EC_PIPELINE=0 routes every encode/rebuild back through the
-    serial classic drivers in ec_files.py wholesale (byte-identical;
-    regression-tested) — the operator lever when a pipeline bug is
-    suspected in production."""
-    return os.environ.get("WEED_EC_PIPELINE", "1") != "0"
-
-
 def pipeline_batch_limit() -> int:
     """Max volumes per mesh dispatch round on the batched encode path
     (WEED_EC_PIPELINE_BATCH, 0 = whole batch in one program). Caps
@@ -198,10 +189,10 @@ class _StagingRing:
 # (preadv/pwritev), GIL-released C codec calls, or blocking device
 # fetches, so a few of them keep the disks busy even on small hosts —
 # but every extra thread costs GIL churn. Re-swept with the staging
-# ring (BENCH_r12): the reader pool is the disk's IO queue, and a
-# floor of 3 readers beat the old 2 even on a 1-CPU-quota host
-# (1.24 vs 1.17 GB/s at the 1 MiB tile) because blocked preads cost
-# no CPU; w=3 still beat w=2 and w=8.
+# ring (`git show 484f53f:BENCH_r12.json`): the reader pool is the
+# disk's IO queue, and a floor of 3 readers beat the old 2 even on a
+# 1-CPU-quota host (1.24 vs 1.17 GB/s at the 1 MiB tile) because
+# blocked preads cost no CPU; w=3 still beat w=2 and w=8.
 DEFAULT_WRITER_THREADS = min(8, max(3, (os.cpu_count() or 2) + 1))
 DEFAULT_READER_THREADS = min(6, max(3, (os.cpu_count() or 2) // 2))
 
@@ -214,8 +205,8 @@ def _ring_slots(writer_threads: int | None = None) -> int:
 
 # The smallest per-shard span a round of a batch driver reads: the
 # 512 KiB every batch rebuild round was before the chip was asked
-# (BENCH_r12, a CPU-sandbox record), and still what the host arm of the
-# batch rebuild takes.
+# (`git show 484f53f:BENCH_r12.json`, a CPU-sandbox record), and still
+# what the host arm of the batch rebuild takes.
 BATCH_REBUILD_MIN_TILE_BYTES = DEFAULT_TILE_BYTES // 2
 
 
@@ -2337,8 +2328,9 @@ def stream_rebuild_ec_files_batch(
     row for four volumes, which the chip read at 4.5 GB/s of volume
     repaired where 512 KiB read 2.6: PERF.md section 6, PR 39), and the
     host arm takes BATCH_REBUILD_MIN_TILE_BYTES (512 KiB: a CPU-sandbox
-    record's number, BENCH_r12, for hosts without a chip). The benchmark cells
-    `batch-rebuild-2lost` (one signature) and `batch-rebuild-mixed`
+    record's number, `git show 484f53f:BENCH_r12.json`, for hosts
+    without a chip). The benchmark cells `batch-rebuild-2lost` (one
+    signature) and `batch-rebuild-mixed`
     (seven) run the mesh arm on the chip
     (`rebuild_launches_per_gib`, `read_s_per_gib`,
     `ring_fresh_bytes_per_gib`, `batch_rebuild_passes_per_op`): a change

@@ -24,8 +24,7 @@ replayed for every tile:
    of once per use.
 
 Both rewrites are exact — XOR reassociation and GF(2)-linearity hold
-bitwise — so scheduled output is byte-identical to the naive chain
-(bench.py --check A/Bs the two arms).
+bitwise — so scheduled output is byte-identical to the naive chain.
 
 The compiler is shared by the numpy backend (ec/codec.py wraps its
 apply with the per-matrix program cache here) and the SWAR Pallas

@@ -1,5 +1,5 @@
-/* LD_PRELOAD syscall-wrapper counter for the serving-edge bench
- * (bench.py serve-floor, docs/SERVING.md).
+/* LD_PRELOAD syscall-wrapper counter for the serving edge
+ * (docs/SERVING.md).
  *
  * The container ships no strace/perf, so the syscall-floor breakdown
  * is measured by interposing the libc wrappers the C serving loop

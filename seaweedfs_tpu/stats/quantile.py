@@ -1,8 +1,7 @@
 """Shared quantile estimators.
 
-One implementation for every consumer that ranks latencies: the bench
-stage breakdowns (bench.py `trace` / `migration` / `scrub` configs),
-the telemetry plane's ring TSDB (cluster-wide p99 from scraped
+One implementation for every consumer that ranks latencies: the
+telemetry plane's ring TSDB (cluster-wide p99 from scraped
 histogram buckets), and weedload's log-bucketed latency histograms.
 Before this module each site hand-rolled its own `sorted()[int(n*p)]`
 with subtly different clamping — the estimators must agree or the

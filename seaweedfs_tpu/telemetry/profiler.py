@@ -2,12 +2,11 @@
 
 Generalizes util/profiling.CpuProfile (one-shot, instrumenting, whole-
 run) into an always-on statistical sampler cheap enough for production
-serving (the <=1% bound is enforced by bench.py's `load` config): a
-single background thread wakes every WEED_PROF_MS milliseconds, grabs
-`sys._current_frames()` (one C call), walks each thread's frame chain,
-and bumps a counter keyed by the stack tuple. No per-call hooks, no
-sys.setprofile — the serving path is never instrumented, only observed
-while the sampler briefly holds the GIL.
+serving: a single background thread wakes every WEED_PROF_MS
+milliseconds, grabs `sys._current_frames()` (one C call), walks each
+thread's frame chain, and bumps a counter keyed by the stack tuple. No
+per-call hooks, no sys.setprofile — the serving path is never
+instrumented, only observed while the sampler briefly holds the GIL.
 
 Cost engineering: frame-walk labels are interned per code object
 (id(code) → "module:qualname" built once), so a tick is N_threads ×

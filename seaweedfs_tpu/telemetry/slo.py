@@ -20,8 +20,7 @@ Budgets are exported every cycle as `weed_slo_burn_rate{objective,
 window}` and `weed_slo_budget_remaining{objective}`; the engine also
 emits the SLO SCORECARD — availability, accepted p99.9, retry
 amplification, MTTR, bytes-moved-per-rebuilt-byte, and a per-objective
-verdict — the object `bench.py chaos --soak` consumes as the standing
-regression gate (ROADMAP "production-day soak").
+verdict (ROADMAP "production-day soak").
 
 `WEED_SLO=0` disables the engine (the collector then runs exactly the
 pre-weedscope rule set); window/threshold knobs: `WEED_SLO_FAST_S`,

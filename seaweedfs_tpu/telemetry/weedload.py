@@ -1,12 +1,12 @@
 """weedload: multi-PROCESS closed-loop load harness.
 
-The in-process http tracker (bench.py `http`, BENCH_r06 caveat) shares
-the GIL with the servers it measures — it cannot see cross-process
-tail latency, which is exactly where the ROADMAP tail-latency work
-lives. weedload runs every worker as its own OS process against a real
-cluster over real sockets and reports p50/p99/p99.9 from log-bucketed
-histograms, so it is the measurement substrate for hedging/admission
-experiments.
+An in-process http tracker (the caveat of
+`git show 484f53f:BENCH_r06.json`) shares the GIL with the servers it
+measures — it cannot see cross-process tail latency, which is exactly
+where the ROADMAP tail-latency work lives. weedload runs every worker
+as its own OS process against a real cluster over real sockets and
+reports p50/p99/p99.9 from log-bucketed histograms, so it is the
+measurement substrate for hedging/admission experiments.
 
 Coordinated-omission safety: each worker is closed-loop (next request
 issues only after the previous completes) but paces against a fixed

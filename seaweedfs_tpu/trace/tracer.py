@@ -96,7 +96,7 @@ def enabled() -> bool:
 
 
 def set_enabled(on: bool) -> None:
-    """Runtime kill switch (bench.py A/B arms toggle this in-process)."""
+    """Runtime kill switch."""
     global _ENABLED
     _ENABLED = bool(on)
 
@@ -107,8 +107,7 @@ def sample_every() -> int:
 
 def set_sample_every(n: int) -> None:
     """`-traceSample N`: head-sample 1-in-N headerless mini-loop roots
-    (1 = every request). The overhead knob for hot fleets — see the
-    bench `trace` config's sampled arm."""
+    (1 = every request). The overhead knob for hot fleets."""
     global _sample_every
     _sample_every = max(1, int(n))
 

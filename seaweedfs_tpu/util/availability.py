@@ -2,10 +2,10 @@
 while a cluster transition (EC migration, rebalance, vacuum) runs
 underneath, recording every latency and every failure.
 
-Used by tests/test_migration.py and bench.py's `migration` config to
-exercise BASELINE config 5 — the reference's claim that the ec.encode
-pipeline's ordering (shards mounted before the volume is deleted,
-volume_grpc_erasure_coding.go:25-36) keeps reads green throughout.
+Used by tests/test_migration.py to exercise BASELINE config 5 — the
+reference's claim that the ec.encode pipeline's ordering (shards
+mounted before the volume is deleted, volume_grpc_erasure_coding.go:25-36)
+keeps reads green throughout.
 """
 
 from __future__ import annotations
@@ -175,8 +175,7 @@ def start_cluster(
 ):
     """Boot 1 master + one VolumeServer per dir (rack{i%2} layout) and
     wait until every node has registered. Returns (master, servers);
-    caller stops them. Shared by tests/test_migration.py's fixture and
-    bench.py's migration config so both measure the same cluster shape.
+    caller stops them. Shared by the tests' cluster fixtures.
     `master_kwargs` feeds MasterServer (e.g. telemetry_interval for the
     cluster-telemetry tests)."""
     from seaweedfs_tpu.server.master_server import MasterServer

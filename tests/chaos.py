@@ -1,6 +1,6 @@
 """Cluster wiring for the weedchaos scenario suite (docs/CHAOS.md).
 
-Shared by tests/test_chaos.py and bench.py's chaos config: builders
+Used by tests/test_chaos.py: builders
 for raft-HA master groups and proxied volume servers, an EC volume
 seeded over the wire, and the write/read workloads the invariant
 checkers audit. Everything here drives REAL servers over real

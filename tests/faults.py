@@ -22,8 +22,8 @@ class SlowReplicaProxy(ChaosProxy):
     Point a client's replica url at `proxy.addr` instead of the real
     volume server and every byte the server sends back is held for the
     delay before forwarding — the injected-slow-replica fault the
-    hedged-read A/B (bench.py qos, BENCH_r09) and the hedge tests
-    drive. Requests pass through untouched, so the server does all its
+    hedged-read A/B (`git show 484f53f:BENCH_r09.json`) and the hedge
+    tests drive. Requests pass through untouched, so the server does all its
     normal work; only the client-observed latency inflates. `delay_s`
     is mutable mid-run (`proxy.delay_s = 0` = transparent).
 

@@ -336,6 +336,42 @@ class TestChaosProxyUnit:
             lst.close()
 
 
+class TestPartitionOnALiveMaster:
+    def test_deadlined_call_fails_fast_then_heals(self):
+        """A planted partition in front of a live master is DETECTED (a
+        deadlined call through it fails within the budget, it never
+        parks) and HEALED (the same call succeeds after heal())."""
+        from seaweedfs_tpu.server.master_server import MasterServer
+
+        master = MasterServer(
+            port=free_port(), volume_size_limit_mb=64, vacuum_interval=0
+        )
+        master.start()
+        proxy = ChaosProxy(f"127.0.0.1:{master.port}")
+        try:
+            status, _, _ = op.http_call(
+                "GET", f"{proxy.addr}/dir/status", timeout=5
+            )
+            assert status == 200
+            proxy.partition()
+            t0 = time.perf_counter()
+            with pytest.raises((TimeoutError, OSError)):
+                op.http_call(
+                    "GET", f"{proxy.addr}/dir/status", timeout=5,
+                    deadline=dl_mod.Deadline.after(0.5),
+                )
+            # the budget, not a parked socket, ended the call
+            assert time.perf_counter() - t0 < 3.0
+            proxy.heal()
+            status, _, _ = op.http_call(
+                "GET", f"{proxy.addr}/dir/status", timeout=5
+            )
+            assert status == 200
+        finally:
+            proxy.stop()
+            master.stop()
+
+
 # ---------------------------------------------------------------------------
 # DiskChaos units
 

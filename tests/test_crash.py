@@ -271,9 +271,8 @@ class TestEnumerator:
             assert states[-1].digest() == full[-1].digest()
 
     def test_planted_broken_publish_is_detected(self):
-        """The dynamic positive control (also the bench --check crash
-        smoke): an unsynced tmp+rename publish MUST yield at least one
-        violating crash state."""
+        """The dynamic positive control: an unsynced tmp+rename publish
+        MUST yield at least one violating crash state."""
         rep = crash.run_broken_publish(budget=64)
         assert rep.violations, "enumerator went blind: planted bug missed"
 

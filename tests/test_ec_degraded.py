@@ -303,8 +303,8 @@ class TestDegradedRead:
         store.close()
 
     def test_serial_fallback_gone_from_hot_path(self):
-        # planted-regression guard (also in bench --check): the old
-        # per-call ThreadPoolExecutor gather must never come back
+        # planted-regression guard: the old per-call
+        # ThreadPoolExecutor gather must never come back
         import inspect
 
         from seaweedfs_tpu.ec import ec_volume

@@ -1,10 +1,10 @@
-"""Device-resident EC streaming pipeline (ISSUE 15, docs/CODEC.md):
-staging ring, fused CRC32-C, mesh batch arm, kill switch, stage
+"""Device-resident EC streaming pipeline (docs/CODEC.md): staging
+ring, fused CRC32-C, mesh batch arm, routing by backend, stage
 accounting, and tile-cache scan resistance.
 
-Everything runs on the CPU backend (tier-1 is JAX_PLATFORMS=cpu), so
-byte- and CRC-identity assertions here are exactly what the bench
---check pipeline_identity smoke enforces in production."""
+Everything runs on the CPU backend (tier-1 is JAX_PLATFORMS=cpu): the
+stream drivers are held byte- and CRC-identical to the classic loop,
+which the numpy `cpu` backend takes as the reference."""
 
 import os
 
@@ -38,15 +38,12 @@ def _shards(base: str) -> list[bytes]:
 
 
 def _write_classic(base: str, rs, want_crcs=False, stats=None):
-    """The serial reference driver, forced via the kill switch."""
-    os.environ["WEED_EC_PIPELINE"] = "0"
-    try:
-        ec_files.write_ec_files(
-            base, rs=rs, large_block_size=LARGE, small_block_size=SMALL,
-            stats=stats, want_crcs=want_crcs,
-        )
-    finally:
-        os.environ.pop("WEED_EC_PIPELINE", None)
+    """The serial reference driver: the classic loop, which write_ec_files
+    takes for the numpy `cpu` backend."""
+    ec_files.write_ec_files(
+        base, rs=rs, large_block_size=LARGE, small_block_size=SMALL,
+        stats=stats, want_crcs=want_crcs,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -282,28 +279,35 @@ class TestPipelinedEncode:
         # read queue (one per reader), write queue, the ring's free list
         assert queues == [2, ec_stream._INFLIGHT, 0]
 
-    def test_kill_switch_routes_serial(self, tmp_path, monkeypatch):
-        """WEED_EC_PIPELINE=0 restores the classic loop wholesale:
-        routing predicates decline, the classic stats shape comes
-        back, and bytes + CRCs are unchanged."""
+    def test_kill_switch_routes_serial(self, tmp_path):
+        """Routing reads the backend and nothing else: a `native` codec
+        goes through the stream driver, the numpy `cpu` codec takes the
+        classic loop (the reference), and both give the same bytes and
+        CRCs."""
         rs = new_encoder(backend="cpu")
-        rs._backend_name = "native"  # pretend: routing looks at the name
-        monkeypatch.setenv("WEED_EC_PIPELINE", "0")
         assert not ec_files._stream_host_codec(rs)
         assert not ec_files._use_stream_driver(rs)
-        base = str(tmp_path / "v")
-        _make_dat(base, 10 * SMALL * 2 + 123)
-        stats: dict = {}
-        ec_files.write_ec_files(
-            base, rs=rs, large_block_size=LARGE, small_block_size=SMALL,
-            stats=stats, want_crcs=True,
-        )
-        assert "encode_s" in stats  # the classic driver's bucket
-        assert "device_s" not in stats
-        for i, sb in enumerate(_shards(base)):
-            assert stats["shard_crcs"][i] == crc32c(sb)
-        monkeypatch.delenv("WEED_EC_PIPELINE")
-        assert ec_files._stream_host_codec(rs)
+        native = new_encoder(backend="cpu")
+        native._backend_name = "native"  # pretend: routing looks at the name
+        assert ec_files._stream_host_codec(native)
+        assert not ec_files._use_stream_driver(native)
+        classic, piped = str(tmp_path / "c"), str(tmp_path / "p")
+        data = _make_dat(classic, 10 * SMALL * 2 + 123)
+        with open(piped + ".dat", "wb") as f:
+            f.write(data)
+        cstats: dict = {}
+        pstats: dict = {}
+        for base, codec, stats in ((classic, rs, cstats), (piped, native, pstats)):
+            ec_files.write_ec_files(
+                base, rs=codec, large_block_size=LARGE, small_block_size=SMALL,
+                stats=stats, want_crcs=True,
+            )
+        assert "encode_s" in cstats  # the classic driver's bucket
+        assert "device_s" not in cstats
+        assert "pipeline_depth" in pstats  # the stream driver ran
+        for i, (cb, pb) in enumerate(zip(_shards(classic), _shards(piped))):
+            assert cb == pb, f"shard {i}"
+            assert cstats["shard_crcs"][i] == pstats["shard_crcs"][i] == crc32c(cb)
 
 
 # ---------------------------------------------------------------------------
@@ -418,15 +422,15 @@ class TestMeshBatchPipeline:
                 assert os.path.getsize(base + ec_files.to_ext(i)) == 0
         assert stats["shard_crcs"] == [[0] * 14, [0] * 14]
 
-    def test_routing_via_write_ec_files_batch(self, tmp_path, monkeypatch):
-        """ec_files.write_ec_files_batch routes to the pipelined arm by
-        default and the classic per-round loop under the kill switch —
-        same bytes either way."""
+    def test_routing_via_write_ec_files_batch(self, tmp_path):
+        """ec_files.write_ec_files_batch is the pipelined batch driver:
+        the same bytes and CRCs as the classic write_ec_files on the
+        numpy `cpu` backend."""
         rs = new_encoder(backend="cpu")
         piped = str(tmp_path / "p")
-        killed = str(tmp_path / "k")
+        classic = str(tmp_path / "c")
         data = _make_dat(piped, 10 * SMALL * 2 + 55)
-        with open(killed + ".dat", "wb") as f:
+        with open(classic + ".dat", "wb") as f:
             f.write(data)
         st_p: dict = {}
         ec_files.write_ec_files_batch(
@@ -434,16 +438,12 @@ class TestMeshBatchPipeline:
             stats=st_p, want_crcs=True,
         )
         assert "pipeline_depth" in st_p  # pipelined arm ran
-        monkeypatch.setenv("WEED_EC_PIPELINE", "0")
-        st_k: dict = {}
-        ec_files.write_ec_files_batch(
-            [killed], large_block_size=LARGE, small_block_size=SMALL,
-            stats=st_k, want_crcs=True,
-        )
-        assert "pipeline_depth" not in st_k  # classic arm ran
-        for gb, wb in zip(_shards(piped), _shards(killed)):
+        st_c: dict = {}
+        _write_classic(classic, rs, want_crcs=True, stats=st_c)
+        assert "encode_s" in st_c  # classic loop ran
+        for gb, wb in zip(_shards(piped), _shards(classic)):
             assert gb == wb
-        assert st_p["shard_crcs"] == st_k["shard_crcs"]
+        assert list(st_p["shard_crcs"][0]) == list(st_c["shard_crcs"])
 
     def test_mesh_fused_crc_with_stripe_collective(self):
         """encode_batch_u32_crc on a vol×stripe mesh: the stripe-axis

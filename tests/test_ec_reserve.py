@@ -270,7 +270,6 @@ def test_enospc_from_a_reservation_fails_before_any_write(
     for base in bases:
         ref = base + "-classic"
         os.link(base + ".dat", ref + ".dat")
-        monkeypatch.setenv("WEED_EC_PIPELINE", "0")
         ec_files.write_ec_files(
             ref, rs=rs, large_block_size=LARGE, small_block_size=SMALL
         )

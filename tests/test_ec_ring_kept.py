@@ -48,14 +48,10 @@ def _classic(base: str, nbytes: int, seed: int) -> list[bytes]:
         f.write(rng.integers(0, 256, nbytes, dtype=np.uint8).tobytes())
     ref = os.path.join(os.path.dirname(base), "classic-" + os.path.basename(base))
     shutil.copy(base + ".dat", ref + ".dat")
-    os.environ["WEED_EC_PIPELINE"] = "0"
-    try:
-        ec_files.write_ec_files(
-            ref, rs=new_encoder(backend="cpu"), large_block_size=LARGE,
-            small_block_size=SMALL,
-        )
-    finally:
-        os.environ.pop("WEED_EC_PIPELINE", None)
+    ec_files.write_ec_files(
+        ref, rs=new_encoder(backend="cpu"), large_block_size=LARGE,
+        small_block_size=SMALL,
+    )
     return [
         open(ref + ec_files.to_ext(i), "rb").read()
         for i in range(ec_files.TOTAL_SHARDS)

@@ -11,7 +11,7 @@ either declines or matches the pure-Python path byte for byte on
 
 Runs under the sanitizer builds too: WEED_NATIVE_SAN=asan plus the
 LD_PRELOAD recipe from `_build.asan_preload_env()` turns this sweep
-into the heap-corruption gate `bench.py --check` drives.
+into a heap-corruption gate.
 """
 
 from __future__ import annotations

@@ -281,30 +281,3 @@ class TestStageNameIdentity:
         assert write_path.WRITE_STAGES == (
             "parse", "assemble", "crc", "pwrite", "reply"
         )
-
-
-class TestBenchCheckSmoke:
-    def test_bench_check(self):
-        """`bench.py --check` — the CI smoke that builds the ext and
-        pushes one write through both paths — must pass in-tree."""
-        import os
-        import subprocess
-        import sys
-
-        # inner marker: from inside tier-1 the smoke only needs the
-        # one-write C/Python identity leg — the weedlint and sanitizer
-        # legs of --check run their own tests (test_weedlint.py,
-        # test_fuzz_corpus.py) and would recurse/slow the suite here
-        env = dict(
-            os.environ, JAX_PLATFORMS="cpu", WEED_BENCH_CHECK_INNER="1"
-        )
-        proc = subprocess.run(
-            [sys.executable, "bench.py", "--check"],
-            capture_output=True,
-            text=True,
-            timeout=180,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            env=env,
-        )
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-        assert '"ok": true' in proc.stdout, proc.stdout

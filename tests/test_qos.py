@@ -5,8 +5,8 @@ loser cancellation, counters), per-client admission control (503 +
 Retry-After, client retry honor, -serveProcs budget split), group
 commit (byte identity, flush reduction, crash consistency), and
 queue-depth-aware assignment (heartbeat fields → p2c pick), plus the
-vid_map circuit breaker and the weedload extensions that drive the
-BENCH_r09 A/Bs.
+vid_map circuit breaker and the weedload extensions that drove the
+A/Bs of `git show 484f53f:BENCH_r09.json`.
 """
 
 from __future__ import annotations
@@ -851,6 +851,56 @@ class TestQosCluster:
             finally:
                 for s in servers:
                     s.stop()
+                master.stop()
+
+    def test_hedge_wins_against_a_stalled_live_replica(self, monkeypatch):
+        """A replicated write on a live two-server cluster, read back
+        with the first replica stalled behind a SlowReplicaProxy: the
+        hedge fires to the second replica and wins with the right
+        bytes."""
+        from seaweedfs_tpu.util.availability import start_cluster
+
+        monkeypatch.setenv("WEED_QOS_HEDGE_MS", "40")
+        with tempfile.TemporaryDirectory() as d:
+            master, servers = start_cluster(
+                [tempfile.mkdtemp(dir=d), tempfile.mkdtemp(dir=d)]
+            )
+            m = f"127.0.0.1:{master.port}"
+            proxy = None
+            try:
+                payload = b"qos-check\x00\xff" * 64
+                with urllib.request.urlopen(
+                    f"http://{m}/dir/assign?replication=010", timeout=10
+                ) as r:
+                    a = json.load(r)
+                urllib.request.urlopen(
+                    urllib.request.Request(
+                        f"http://{a['url']}/{a['fid']}", data=payload,
+                        method="POST",
+                        headers={"Content-Type": "application/octet-stream"},
+                    ),
+                    timeout=10,
+                ).close()
+                vid = a["fid"].partition(",")[0]
+                with urllib.request.urlopen(
+                    f"http://{m}/dir/lookup?volumeId={vid}", timeout=10
+                ) as r:
+                    urls = [loc["url"] for loc in json.load(r)["locations"]]
+                assert len(urls) >= 2, urls
+                proxy = SlowReplicaProxy(urls[0], delay_s=0.5)
+                stats: dict = {}
+                data, _ = hedge.download(
+                    [f"{proxy.addr}/{a['fid']}", f"{urls[1]}/{a['fid']}"],
+                    key=vid, stats=stats,
+                )
+                assert data == payload
+                assert stats.get("fired", 0) >= 1
+                assert stats.get("won", 0) >= 1
+            finally:
+                if proxy is not None:
+                    proxy.stop()
+                for vs in servers:
+                    vs.stop()
                 master.stop()
 
     def test_group_commit_on_live_write_path(self):

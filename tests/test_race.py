@@ -152,6 +152,22 @@ class TestGcraModelCheck:
         )
         assert any("double-spend" in v for v in rep.violations)
 
+    def test_shm_atomics_rule_flags_a_plain_store_mutant(self):
+        """The ctier rule that guards serve.c's GCRA slot sees the same
+        bug class in C: a plain-store mutant of weed_shm_admit's CAS is
+        flagged, and the shipped serve.c is clean."""
+        from seaweedfs_tpu.analysis import ctier
+
+        with open(os.path.join(ctier._NATIVE_DIR, "serve.c"), encoding="utf-8") as f:
+            src = f.read()
+        mutant = src.replace(
+            "if (__atomic_compare_exchange_n(slot, &tat, base + T, 0,",
+            "if ((*slot = base + T) && (0,", 1,
+        )
+        assert mutant != src, "the CAS this control mutates moved"
+        assert ctier.check_shm_atomics(source=mutant)
+        assert not ctier.check_shm_atomics(source=src)
+
 
 # ---------------------------------------------------------------------------
 # the real bucket: SIGKILL a sibling mid-update (weedcrash idiom)

@@ -3,8 +3,7 @@
 A checker that silently goes blind is worse than no checker — every
 rule here gets a positive control (a synthetic tree with a planted
 bug the rule MUST flag) and the real tree gets the negative control
-(`python -m seaweedfs_tpu.analysis` exits 0, which is also the
-acceptance gate bench.py --check drives).
+(`python -m seaweedfs_tpu.analysis` exits 0).
 """
 
 from __future__ import annotations
@@ -535,14 +534,14 @@ class TestContracts:
         assert "WEED_NATIVE_POST" in reg.env_documented
 
     def test_extra_source_findings_are_suppressible(self):
-        """Review regression: findings anchored in bench.py /
-        tests/conftest.py / docs must be reachable by the suppression
+        """Review regression: findings anchored in tests/conftest.py /
+        docs must be reachable by the suppression
         scan — check() merges those texts into index.sources so an
         inline `# weedlint: ignore[...]` there actually works."""
         from seaweedfs_tpu.analysis import contracts
 
         _findings, idx, _reg = contracts.check()
-        assert "bench.py" in idx.sources
+        assert "tests/conftest.py" in idx.sources
         assert "OPERATIONS.md" in idx.sources
 
     def test_dead_seed_metric_families_stay_gone(self):

@@ -402,6 +402,91 @@ class TestCapsules:
         assert calls == []  # WEED_SCOPE=0: no auto-capture side effects
 
 
+class TestCapsuleOnALiveCluster:
+    def test_forced_slo_breach_captures_on_every_node(self, tmp_path):
+        """The SLO burn-rate rule fires on a live cluster and the
+        alert-triggered capsule lands durably on every implicated node:
+        the leader's manifest lists blackbox, traces, profile, /metrics,
+        the TSDB window and the cluster verdict. The breach is forced:
+        the in-process cluster shares this process's metric registry,
+        so one 10 s observation between two scrape cycles burns both
+        windows of a seconds-scale latency objective."""
+        import urllib.request
+
+        from seaweedfs_tpu.stats.metrics import HTTP_REQUEST_HISTOGRAM
+        from seaweedfs_tpu.telemetry import ClusterCollector
+        from seaweedfs_tpu.telemetry import capsule as capsule_mod
+        from seaweedfs_tpu.util.availability import start_cluster
+
+        (tmp_path / "vol").mkdir()
+        capsule_mod.set_dir(str(tmp_path / "capsules"))
+        master, servers = start_cluster([str(tmp_path / "vol")])
+        lead_node = f"{master.host}:{master.port}"
+        try:
+            forced = slo_mod.SLOObjective(
+                "check-forced-breach", "latency", 0.999,
+                family="weed_http_request_seconds", threshold_s=0.5,
+            )
+            collector = ClusterCollector(
+                master, interval=0.5,
+                slo_objectives=[forced], slo_fast_s=30.0, slo_slow_s=60.0,
+            )
+            master.telemetry = collector
+            master._wire_capsules()
+            # light real traffic so the blackbox/trace sections have events
+            with urllib.request.urlopen(
+                f"http://127.0.0.1:{servers[0].port}/debug/traces?n=8",
+                timeout=10,
+            ) as r:
+                r.read()
+            # cycle 1's own /metrics GET births the request-histogram
+            # series; cycle 2 rings their baseline; the slow observation
+            # then shows as an increase in cycle 3 and fires
+            collector.collect_once()
+            collector.collect_once()
+            HTTP_REQUEST_HISTOGRAM.observe(10.0, "volume", "GET")
+            collector.collect_once()
+            assert any(
+                a["Alert"] == "slo_burn_rate"
+                and a["Target"] == "check-forced-breach"
+                for a in collector.alerts.firing()
+            )
+            # the CaptureCoordinator runs off-thread: a local capture on
+            # the leader plus /capsule/capture on every up peer
+            caps: list[dict] = []
+            deadline = time.time() + 20.0
+            while time.time() < deadline:
+                caps = [
+                    c for c in capsule_mod.list_capsules()
+                    if c.get("Trigger") == "alert"
+                ]
+                if len({c.get("Node") for c in caps}) >= 2:
+                    break
+                time.sleep(0.25)
+            assert len({c.get("Node") for c in caps}) >= 2, caps
+            [lead] = [c for c in caps if c.get("Node") == lead_node]
+            ok_names = {f["Name"] for f in lead["Files"] if f.get("Ok")}
+            assert {
+                "blackbox.json", "traces.json", "profile.txt",
+                "metrics.txt", "tsdb.json", "cluster.json",
+            } <= ok_names
+            bb = json.loads(
+                capsule_mod.read_file(lead["Id"], "blackbox.json") or b"{}"
+            )
+            assert bb.get("tail") or bb.get("ok")
+            mtxt = capsule_mod.read_file(lead["Id"], "metrics.txt") or b""
+            assert "weed_slo_burn_rate" in mtxt.decode()
+            tsdb = json.loads(
+                capsule_mod.read_file(lead["Id"], "tsdb.json") or b"{}"
+            )
+            assert tsdb.get("Targets")
+        finally:
+            for vs in servers:
+                vs.stop()
+            master.stop()
+            capsule_mod.set_dir("")
+
+
 # ----------------------------------------------------------------------
 # collector: sticky scrape targets with a dead-node TTL (satellite 1)
 
