@@ -444,6 +444,8 @@ def rebuild_ec_files_batch(
     stats: dict | None = None,
     durable: bool = False,
     want_crcs: bool = False,
+    remote_readers: list[dict] | None = None,
+    remote_report=None,
 ) -> list[list[int]]:
     """Regenerate missing .ec files for N volumes, batched: volumes
     sharing a (survivors, targets) signature ride ONE sharded mesh
@@ -452,8 +454,10 @@ def rebuild_ec_files_batch(
     parallel/mesh_codec.reconstruct_batch_u32) — the BatchRebuild
     verb's driver, so the RepairScheduler amortizes dispatch latency
     over concurrent small-volume rebuilds instead of paying it per
-    volume. Every survivor must be local (the remote rack-gather path
-    stays per-volume).
+    volume. `remote_readers` (one {shard id: read_into} map a volume)
+    and `remote_report` are the readers of survivors held by other
+    servers and their books, as the driver takes them: a rack loss
+    rides the same batch.
 
     Returns the rebuilt id lists in input order; want_crcs lands
     `shard_crcs` in stats as one {rebuilt id: whole-file CRC-32C} dict
@@ -467,6 +471,8 @@ def rebuild_ec_files_batch(
         stats=stats,
         durable=durable,
         want_crcs=want_crcs,
+        remote_readers=remote_readers,
+        remote_report=remote_report,
     )
 
 

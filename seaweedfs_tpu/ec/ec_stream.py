@@ -33,10 +33,12 @@ A driver is a plan and its stage bodies, handed to _Op.run:
 
 Only the [4, N] parity crosses device->host on encode: the ten data
 shard files are byte copies of the blocks read from the .dat, written
-straight from the ring slot. The rebuild driver also takes REMOTE
+straight from the ring slot. Both rebuild drivers also take REMOTE
 survivor readers (`remote_readers`: shard id -> read_into(offset,
-row of the ring slot)), which is how VolumeEcShardsRebuild overlaps a
-rack-wide gather with reconstruction.
+row of the ring slot), one map a volume in the batch driver) and
+gather through one helper (_Gather), which is how VolumeEcShardsRebuild
+and VolumeEcShardsBatchRebuild overlap a rack-wide gather with
+reconstruction.
 
 Role match: the 256 KB-batch loops at reference
 weed/storage/erasure_coding/ec_encoder.go:188-225 (encodeDatFile) and
@@ -465,55 +467,169 @@ def _opened(paths: list[str]):
             os.close(fd)
 
 
-class _Survivors:
-    """One thread's read-only fds of each volume's ten survivor shard
-    files, opened when the thread first meets the volume and closed
-    together: the batch rebuild arms' only way to survivor bytes, so the
-    truncation check and the repair accounting live here. Each volume
-    has its own survivor set (a pass mends every damage signature of
-    its call)."""
+def _survivor_choice(
+    base: str, present: list[bool], missing: list[int], remote: dict
+) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """(survivors, targets) of one volume's rebuild, for both rebuild
+    drivers. A shard readable remotely (a key of `remote`) exists in the
+    cluster: it can serve as a survivor but must not be rebuilt here.
+    Local survivors first (free reads), topped up from remote holders;
+    the decode matrix keeps the chosen set in ascending order, and any
+    10-of-14 subset reconstructs identical bytes. At least one survivor
+    must be local: its file size fixes the tile walk."""
+    targets = tuple(i for i in missing if i not in remote)
+    local_ids = [i for i, p in enumerate(present) if p]
+    remote_ids = sorted(i for i in remote if not present[i])
+    if targets and len(local_ids) + len(remote_ids) < DATA_SHARDS:
+        raise ValueError(
+            f"too few shard files to rebuild {base}: "
+            f"{len(local_ids) + len(remote_ids)} of {DATA_SHARDS}"
+        )
+    if targets and not local_ids:
+        raise ValueError(
+            f"rebuild of {base} needs at least one local survivor (its "
+            "size fixes the shard length)"
+        )
+    return tuple(sorted((local_ids + remote_ids)[:DATA_SHARDS])), targets
+
+
+class _Gather:
+    """The survivor bytes of one rebuild into its ring slots, for both
+    rebuild drivers: the one place rebuild bytes cross a disk or the
+    network, so the truncation check and the repair accounting live
+    here. Volume v's survivors are `survivors_of[v]`, each read from its
+    local shard file or, where `remote_of[v]` has a reader for it (shard
+    id -> read_into(offset, dest) -> bytes received, `dest` the row of
+    the slot the span belongs in: the reader fills it where it lies),
+    fetched from the server that holds it. A count short of the row is
+    a truncated survivor.
+
+    `opened()` is what ONE reader thread holds: read-only fds, opened
+    as it first meets a volume (positioned reads, no seek state shared
+    across the pool), and a fetch pool of min(remote survivors, 10)
+    threads, so that a tile's remote spans fan out instead of costing
+    one round trip each, one after the other (a lone remote survivor is
+    read on the reader's own thread). Each remote fetch is one
+    `ec.remote_read` annotation on the thread that makes it, booked
+    under remote_read_s through `book`; every survivor byte read is
+    counted local or remote on weed_ec_repair_bytes_read_total.
+    `books()`, once the pools are joined, gives the report line's
+    remote fields: survivor_bytes_remote, and what `remote_report()`
+    (the caller's readers: arbiter_wait_s, remote_fetches,
+    remote_fetches_dataplane) has to add."""
 
     def __init__(
-        self, bases: list[str], survivors_of: list[tuple[int, ...]]
+        self,
+        bases: list[str],
+        survivors_of: list[tuple[int, ...]],
+        remote_of: list[dict[int, Callable[[int, np.ndarray], int]]],
+        book: Callable[[str, float], None],
+        remote_report: Callable[[], dict] | None = None,
     ):
         from seaweedfs_tpu.ec.ec_files import to_ext
 
-        # [volume][j]: the file of the volume's j-th survivor
-        self._paths = [
-            [base + to_ext(s) for s in survivors]
-            for base, survivors in zip(bases, survivors_of)
-        ]
         self._survivors = survivors_of
-        self._fds: dict[int, list[int]] = {}
-        self._read = EC_REPAIR_BYTES_READ.labels("local")
+        self._remote = remote_of
+        # [volume] {shard id: path} of the survivors read from disk
+        self._paths = [
+            {s: base + to_ext(s) for s in survivors if s not in remote}
+            for base, survivors, remote in zip(bases, survivors_of, remote_of)
+        ]
+        self._book = book
+        self._remote_report = remote_report
+        self._fetchers = min(DATA_SHARDS, max(
+            (len(s) - len(p) for s, p in zip(survivors_of, self._paths)),
+            default=0,
+        ))
+        # bytes of every remote fetch (append is GIL-atomic)
+        self._remote_bytes: list[int] = []
+        self._read_local = EC_REPAIR_BYTES_READ.labels("local")
+        self._read_remote = EC_REPAIR_BYTES_READ.labels("remote")
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc) -> None:
-        for fds in self._fds.values():
-            for fd in fds:
-                try:
+    @contextlib.contextmanager
+    def opened(self):
+        fds: dict[int, dict[int, int]] = {}
+        pool = (
+            ThreadPoolExecutor(max_workers=self._fetchers)
+            if self._fetchers > 1
+            else None
+        )
+        try:
+            yield fds, pool
+        finally:
+            if pool is not None:
+                # wait for in-flight remote fetches: they write into
+                # ring slots, the caller closes the readers' connections
+                # right after the driver returns, and a fetch still
+                # running on a pool thread would see its wire yanked
+                # (and leak the thread past return)
+                pool.shutdown(wait=True, cancel_futures=True)
+            for vol_fds in fds.values():
+                for fd in vol_fds.values():
                     os.close(fd)
-                except OSError:
-                    pass
 
-    def read(self, v: int, off: int, tile: np.ndarray) -> None:
-        """Fill tile [10, step] with volume v's survivor bytes at off."""
-        fds = self._fds.get(v)
-        if fds is None:
-            fds = self._fds[v] = []
-            for path in self._paths[v]:
-                fds.append(os.open(path, os.O_RDONLY))
-        step = tile.shape[1]
-        for j in range(DATA_SHARDS):
-            got = _pread_into(fds[j], tile[j], off)
-            self._read.inc(got)
-            if got != step:
-                raise ValueError(
-                    f"ec shard {self._survivors[v][j]} truncated: expected "
-                    f"{step} at {off} ({self._paths[v][j]})"
-                )
+    def _fetch(self, v: int, sid: int, off: int, row: np.ndarray) -> int:
+        t0 = time.perf_counter()
+        with trace.annotation("ec.remote_read"):
+            got = self._remote[v][sid](off, row)
+        self._book("remote_read_s", time.perf_counter() - t0)
+        return got
+
+    def read(self, src, reads: list[tuple[int, int, np.ndarray]]) -> None:
+        """Fill each (v, off, tile [10, step]) of `reads` with volume
+        v's survivor bytes at off: every remote span of them is asked
+        for first, the local ones are read while those travel."""
+        fds, pool = src
+        remote = [
+            (n, j, sid)
+            for n, (v, _, _) in enumerate(reads)
+            for j, sid in enumerate(self._survivors[v])
+            if sid not in self._paths[v]
+        ]
+        futures = {}
+        if pool is not None:
+            futures = {
+                (n, j): pool.submit(self._fetch, reads[n][0], sid, reads[n][1],
+                                    reads[n][2][j])
+                for n, j, sid in remote
+            }
+        for v, off, tile in reads:
+            vol_fds = fds.get(v)
+            if vol_fds is None:
+                vol_fds = fds[v] = {}
+                for sid, path in self._paths[v].items():
+                    vol_fds[sid] = os.open(path, os.O_RDONLY)
+            for j, sid in enumerate(self._survivors[v]):
+                if sid in vol_fds:
+                    got = _pread_into(vol_fds[sid], tile[j], off)
+                    self._read_local.inc(got)
+                    _check_span(sid, got, tile, off, self._paths[v][sid])
+        for n, j, sid in remote:
+            v, off, tile = reads[n]
+            fut = futures.get((n, j))
+            got = fut.result() if fut is not None else self._fetch(v, sid, off, tile[j])
+            self._read_remote.inc(got)
+            self._remote_bytes.append(got)
+            _check_span(sid, got, tile, off, "remote")
+
+    def books(self) -> dict:
+        out = {"survivor_bytes_remote": sum(self._remote_bytes)}
+        if self._remote_report is not None:
+            out.update(self._remote_report())
+        return out
+
+
+def _not_local(remote: dict | None, present: list[bool]) -> dict:
+    """The readers of `remote` for shards with no local file: a survivor
+    on this server's disk is read from there."""
+    return {i: r for i, r in (remote or {}).items() if not present[i]}
+
+
+def _check_span(sid: int, got: int, tile: np.ndarray, off: int, where: str) -> None:
+    if got != tile.shape[1]:
+        raise ValueError(
+            f"ec shard {sid} truncated: expected {tile.shape[1]} at {off} ({where})"
+        )
 
 
 def _create_empty(paths: list[str], durable: bool) -> None:
@@ -1272,12 +1388,13 @@ def stream_rebuild_ec_files(
     pool pulls their tiles over the wire in parallel with local preadv
     and the decode, and shards readable remotely are treated as
     present (not rebuilt). At least one survivor must be local — its
-    file size fixes the tile walk. Each remote fetch is one
-    `ec.remote_read` annotation on the thread that makes it and is
-    booked under remote_read_s; remote_report(), called once the pools
-    are joined, gives what the caller's readers have to add to the
-    report line and the root span's attributes (the volume server's:
-    arbiter_wait_s, remote_fetches, remote_fetches_dataplane).
+    file size fixes the tile walk. The gather is _Gather's, the batch
+    driver's too: each remote fetch is one `ec.remote_read` annotation
+    on the thread that makes it and is booked under remote_read_s;
+    remote_report(), called once the pools are joined, gives what the
+    caller's readers have to add to the report line and the root
+    span's attributes (the volume server's: arbiter_wait_s,
+    remote_fetches, remote_fetches_dataplane).
 
     `session` (an ec.repair_session.RebuildSession) is the repair-
     bandwidth-frugal hookup: tiles degraded serving already decoded are
@@ -1303,36 +1420,20 @@ def stream_rebuild_ec_files(
     if device_stage:
         rebuild_fn, fetch_fn = _tpu_rebuild_fns(want_crcs=want_crcs, book=op.book)
     tile_bytes = tile_bytes or VOLUME_TILE_BYTES
-    remote_readers = dict(remote_readers or {})
     fold_spans = device_stage and want_crcs
 
     from seaweedfs_tpu.ec.ec_files import shard_presence, to_ext
 
     present, local_missing = shard_presence(base_file_name)
-    local_ids = [i for i, p in enumerate(present) if p]
-    # a shard readable remotely exists in the cluster: it can serve as
-    # a survivor but must not be rebuilt here
-    targets = tuple(i for i in local_missing if i not in remote_readers)
+    remote_readers = _not_local(remote_readers, present)
+    survivors, targets = _survivor_choice(
+        base_file_name, present, local_missing, remote_readers
+    )
     if not targets:
         return []
-    remote_ids = [i for i in remote_readers if not present[i]]
-    if len(local_ids) + len(remote_ids) < DATA_SHARDS:
-        raise ValueError(
-            "too few shard files to rebuild: "
-            f"{len(local_ids) + len(remote_ids)} of {DATA_SHARDS}"
-        )
-    if not local_ids:
-        raise ValueError(
-            "rebuild needs at least one local survivor (its size fixes "
-            "the shard length)"
-        )
-    # prefer local survivors (free reads), top up from remote holders;
-    # the decode matrix keeps the chosen set in ascending order — any
-    # 10-of-14 subset reconstructs identical bytes
-    survivors = tuple(
-        sorted((local_ids + sorted(remote_ids))[:DATA_SHARDS])
+    shard_size = os.path.getsize(
+        base_file_name + to_ext(next(i for i in survivors if present[i]))
     )
-    shard_size = os.path.getsize(base_file_name + to_ext(local_ids[0]))
     # (range offset, range length, [crc per target]) from the writer
     # pool, folded into whole-file CRCs after the join (append is
     # GIL-atomic; order restored by sorting on offset)
@@ -1340,83 +1441,13 @@ def stream_rebuild_ec_files(
     # survivor bytes of every gather, from the reader pool (append is
     # GIL-atomic too), and the dispatcher's own count of its launches
     gathered: list[int] = []
-    gathered_remote: list[int] = []
     # rebuilt bytes of every write, from the writer pool
     written: list[int] = []
     launches = 0
     n_remote = sum(1 for i in survivors if not present[i])
-    read_local = EC_REPAIR_BYTES_READ.labels("local")
-    read_remote = EC_REPAIR_BYTES_READ.labels("remote")
-
-    @contextlib.contextmanager
-    def opened():
-        fds: dict[int, int] = {}
-        # remote survivor fetches fan out per tile: serialized, a
-        # tile's latency would be n_remote × RTT and a single slow
-        # holder would stall the whole tile walk
-        fetch_pool = (
-            ThreadPoolExecutor(max_workers=min(n_remote, DATA_SHARDS))
-            if n_remote > 1
-            else None
-        )
-        try:
-            for i in survivors:
-                if present[i]:
-                    fds[i] = os.open(base_file_name + to_ext(i), os.O_RDONLY)
-            yield fds, fetch_pool
-        finally:
-            if fetch_pool is not None:
-                # wait for in-flight remote fetches: they write into
-                # ring slots, the caller closes the readers' connections
-                # and channels right after the driver returns, and a
-                # fetch still running on a pool thread would see its
-                # wire yanked (and leak the thread past return)
-                fetch_pool.shutdown(wait=True, cancel_futures=True)
-            for fd in fds.values():
-                os.close(fd)
-
-    def remote_read(i: int, g_off: int, row: np.ndarray) -> int:
-        """One span of one remote survivor into its row of the slot, on
-        a fetch-pool thread (the reader's own where a lone remote
-        survivor has no pool)."""
-        t0 = time.perf_counter()
-        with trace.annotation("ec.remote_read"):
-            got = remote_readers[i](g_off, row)
-        op.book("remote_read_s", time.perf_counter() - t0)
-        return got
-
-    def gather(src, g_off: int, g_len: int, dest: np.ndarray) -> np.ndarray:
-        """One [k, g_len] survivor read at g_off into a staging-ring
-        view — the only place rebuild bytes cross a disk or the network,
-        so the repair accounting lives here."""
-        fds, fetch_pool = src
-        tile = dest.reshape(DATA_SHARDS, g_len)
-        futures = {}
-        if fetch_pool is not None:
-            futures = {
-                j: fetch_pool.submit(remote_read, i, g_off, tile[j])
-                for j, i in enumerate(survivors)
-                if i not in fds
-            }
-        for j, i in enumerate(survivors):
-            if i in fds:
-                got = _pread_into(fds[i], tile[j], g_off)
-                read_local.inc(got)
-            else:
-                fut = futures.get(j)
-                got = (
-                    fut.result()
-                    if fut is not None
-                    else remote_read(i, g_off, tile[j])
-                )
-                read_remote.inc(got)
-                gathered_remote.append(got)
-            if got != g_len:
-                raise ValueError(
-                    f"ec shard {i} truncated: expected {g_len} at {g_off}"
-                )
-        gathered.append(DATA_SHARDS * g_len)
-        return tile
+    gather = _Gather(
+        [base_file_name], [survivors], [remote_readers], op.book, remote_report
+    )
 
     def claim(offset):
         """(offset, covered, gaps) of the tile at `offset`."""
@@ -1437,6 +1468,7 @@ def stream_rebuild_ec_files(
         # (Σ gap bytes ≤ step, so they fit).
         _, covered, gaps = item
         parts: list = [("don", d_off, per_t) for d_off, per_t in covered]
+        reads = []
         cur = 0
         for gap in gaps:
             # the device stage folds the CRC of a power-of-two span in
@@ -1444,9 +1476,14 @@ def stream_rebuild_ec_files(
             # shard under 4 MiB tiles) goes as such spans
             spans = _pow2_spans(*gap, tile_bytes) if fold_spans else [gap]
             for g_off, g_len in spans:
-                dest = buf[cur : cur + DATA_SHARDS * g_len]
+                tile = buf[cur : cur + DATA_SHARDS * g_len].reshape(
+                    DATA_SHARDS, g_len
+                )
                 cur += DATA_SHARDS * g_len
-                parts.append(("raw", g_off, gather(src, g_off, g_len, dest)))
+                reads.append((0, g_off, tile))
+                parts.append(("raw", g_off, tile))
+                gathered.append(DATA_SHARDS * g_len)
+        gather.read(src, reads)
         return parts
 
     def dispatch(item, parts):
@@ -1511,10 +1548,8 @@ def stream_rebuild_ec_files(
         # survivors and their bytes those that crossed the wire, and the
         # rebuilt bytes written (weed_ec_repair_bytes_written_total)
         shape["remote_survivors"] = n_remote
-        shape["survivor_bytes_remote"] = sum(gathered_remote)
         shape["rebuilt_bytes"] = sum(written)
-        if remote_report is not None:
-            shape.update(remote_report())
+        shape.update(gather.books())
         out.update(shape)
         for key, value in shape.items():
             sp.annotate(key, value)
@@ -1532,7 +1567,7 @@ def stream_rebuild_ec_files(
         # one flat slot per tile
         slot_bytes=DATA_SHARDS * tile_bytes,
         outputs=[(base_file_name + to_ext(i), shard_size) for i in targets],
-        opened=opened,
+        opened=gather.opened,
         prepare=claim,
         fill=fill,
         dispatch=dispatch,
@@ -2292,6 +2327,8 @@ def stream_rebuild_ec_files_batch(
     want_crcs: bool = False,
     reader_threads: int | None = None,
     writer_threads: int | None = None,
+    remote_readers: list[dict[int, Callable[[int, np.ndarray], int]]] | None = None,
+    remote_report: Callable[[], dict] | None = None,
 ) -> list[list[int]]:
     """Rebuild N volumes' missing shard files through ONE sharded mesh
     program per tile round — the rebuild-side sibling of
@@ -2309,11 +2346,24 @@ def stream_rebuild_ec_files_batch(
     after group, so the pools, the ring, the reservation and the
     head-to-flush phases are paid once a call, and the program changes
     once a chunk (a chunk is a group, or a WEED_EC_PIPELINE_BATCH slice
-    of one). Every survivor must be LOCAL: the rack-gather/remote-reader
-    and repair-session features stay with the single-volume driver
-    (callers with remote survivors route there). Output bytes per
-    volume are identical to rebuild_ec_files (RS determinism over the
-    same ascending survivor choice).
+    of one). Output bytes per volume are identical to rebuild_ec_files
+    over the same survivor choice (RS determinism).
+
+    `remote_readers`, one map a volume beside base_file_names, gives
+    the readers of survivors that live on OTHER servers, as the
+    single-volume driver's `remote_readers` (a rack loss: four of a
+    volume's survivors local, six fetched). A volume's survivors are
+    chosen as that driver chooses them (_survivor_choice: local first,
+    topped up from remote holders, ascending), a shard readable
+    remotely is never a target, and a round's gather of every volume's
+    slot is that driver's (_Gather: the fetch pool, `ec.remote_read`,
+    remote_read_s, the repair byte counters, the truncation check).
+    remote_report(), once the pools are joined, adds the readers' own
+    books (arbiter_wait_s, remote_fetches, remote_fetches_dataplane,
+    summed over the call's volumes) to the report line and the root
+    span, beside survivor_bytes_remote and rebuilt_bytes. The repair
+    session (donations from degraded GETs) stays with the single-volume
+    driver.
 
     want_crcs=True lands `shard_crcs` in stats: one {rebuilt shard id:
     whole-file CRC-32C} dict per volume, in base_file_names order
@@ -2327,51 +2377,40 @@ def stream_rebuild_ec_files_batch(
     ring the process keeps (batch_round_tile_bytes: 2 MiB a survivor
     row for four volumes, which the chip read at 4.5 GB/s of volume
     repaired where 512 KiB read 2.6: PERF.md section 6, PR 39), and the
-    host arm takes BATCH_REBUILD_MIN_TILE_BYTES (512 KiB: a CPU-sandbox
-    record's number, `git show 484f53f:BENCH_r12.json`, for hosts
-    without a chip). The benchmark cells `batch-rebuild-2lost` (one
-    signature) and `batch-rebuild-mixed`
-    (seven) run the mesh arm on the chip
+    host arm (no accelerator, or no jax) takes
+    BATCH_REBUILD_MIN_TILE_BYTES (512 KiB: the number of a record
+    taken on a host without a chip, `git show 484f53f:BENCH_r12.json`). The benchmark cells `batch-rebuild-2lost` (one signature),
+    `batch-rebuild-mixed` (seven) and `rack-batch-rebuild` (one, six
+    remote survivors a volume) run the mesh arm on the chip
     (`rebuild_launches_per_gib`, `read_s_per_gib`,
-    `ring_fresh_bytes_per_gib`, `batch_rebuild_passes_per_op`): a change
-    of the rule is judged there.
+    `ring_fresh_bytes_per_gib`, `batch_rebuild_passes_per_op`,
+    `remote_read_s_per_gib`): a change of the rule is judged there.
 
     `stats` gets the pass's books: its stage seconds, `tiles` (rounds
     or work items of every chunk), `survivor_bytes`, `tile_bytes`,
     `mesh` (the last chunk's), `survivors` and `targets` (per-volume
     counts: the most targets of any volume), `batch_volumes`,
-    `batch_groups` (damage signatures: decode programs the call ran) and
-    `batch_passes` (1; 0 where there was nothing to stream); the ONE
-    root span carries the same.
+    `batch_groups` (damage signatures: decode programs the call ran),
+    `batch_passes` (1; 0 where there was nothing to stream) and the
+    gather's remote fields; the ONE root span carries the same.
 
     `durable=True` fsyncs every rebuilt shard before returning; a
-    failed call removes ALL its volumes' target files (the abort
+    failed call, a remote fetch that fails or comes back short among
+    the causes, removes ALL its volumes' target files (the abort
     contract scrub relies on: no partial rebuilt shard survives)."""
-    from seaweedfs_tpu.ec.ec_files import shard_presence, to_ext
+    from seaweedfs_tpu.ec.ec_files import shard_presence
 
     results: list[list[int]] = [[] for _ in base_file_names]
     if not base_file_names:
         return results
+    remote_of = list(remote_readers or [{} for _ in base_file_names])
     groups: dict[tuple, list[int]] = {}
-    sigs: list[tuple | None] = []
     for i, base in enumerate(base_file_names):
         present, missing = shard_presence(base)
-        targets = tuple(missing)
-        if not targets:
-            sigs.append(None)
-            continue
-        local_ids = [s for s, p in enumerate(present) if p]
-        if len(local_ids) < DATA_SHARDS:
-            raise ValueError(
-                f"too few local shard files to batch-rebuild {base}: "
-                f"{len(local_ids)} of {DATA_SHARDS}"
-            )
-        # same ascending first-k survivor choice as the single-volume
-        # driver with no remote holders: byte-identical output
-        survivors = tuple(sorted(local_ids)[:DATA_SHARDS])
-        sig = (survivors, targets)
-        sigs.append(sig)
-        groups.setdefault(sig, []).append(i)
+        remote_of[i] = _not_local(remote_of[i], present)
+        sig = _survivor_choice(base, present, missing, remote_of[i])
+        if sig[1]:
+            groups.setdefault(sig, []).append(i)
 
     if not groups:
         if stats is not None:
@@ -2383,50 +2422,7 @@ def stream_rebuild_ec_files_batch(
         return results
 
     if codec is None:
-        try:
-            import jax
-
-            if all(d.platform == "cpu" for d in jax.devices()):
-                # no accelerator: the interpreted Pallas kernels lose
-                # to the host backends by orders of magnitude, so run
-                # the batch through the host arm (same grouping and
-                # staging, one concatenated matrix apply per round)
-                from seaweedfs_tpu.ec.codec import new_encoder
-
-                try:
-                    codec = _HostBatchCodec(new_encoder(backend="native"))
-                except (ImportError, ValueError):
-                    codec = _HostBatchCodec(new_encoder(backend="cpu"))
-            else:
-                codec = _default_mesh_codec(
-                    max(len(idxs) for idxs in groups.values())
-                )
-        except ImportError:
-            # no jax: the single-volume pipeline per volume is the
-            # byte-identical fallback seam (it self-selects the host
-            # codec the same way rebuild_ec_files does)
-            from seaweedfs_tpu.ec import ec_files as _ec_files
-
-            all_crcs: list = []
-            for i, base in enumerate(base_file_names):
-                if sigs[i] is None:
-                    all_crcs.append({})
-                    continue
-                s: dict = {}
-                results[i] = _ec_files.rebuild_ec_files(
-                    base, durable=durable, stats=s, want_crcs=want_crcs
-                )
-                all_crcs.append(s.get("shard_crcs") or {})
-            if stats is not None:
-                stats["fallback"] = "host"
-                stats["batch_volumes"] = len(base_file_names)
-                stats["batch_groups"] = len(groups)
-                # one single-volume pass a damaged volume
-                stats["batch_passes"] = len(sigs) - sigs.count(None)
-                if want_crcs:
-                    stats["shard_crcs"] = all_crcs
-            return results
-
+        codec = _default_batch_codec(max(len(idxs) for idxs in groups.values()))
     limit = pipeline_batch_limit()
     # group-major: a group's volumes in chunks of at most `limit`, each
     # chunk one decode program over its rounds, all of them ONE pass
@@ -2435,6 +2431,7 @@ def stream_rebuild_ec_files_batch(
         for (survivors, targets), idxs in groups.items()
         for i in range(0, len(idxs), limit or len(idxs))
     ]
+    order = [i for idxs, _, _ in chunks for i in idxs]
     pass_stats: dict = {}
     _rebuild_batch_pass(
         [
@@ -2442,17 +2439,15 @@ def stream_rebuild_ec_files_batch(
             for idxs, survivors, targets in chunks
         ],
         codec, tile_bytes, pass_stats, durable, want_crcs,
-        reader_threads, writer_threads, groups=len(groups),
+        reader_threads, writer_threads, len(groups),
+        [remote_of[i] for i in order], remote_report,
     )
     for idxs, _, targets in chunks:
         for i in idxs:
             results[i] = list(targets)
     if stats is not None:
         # the pass's volumes in its order -> base_file_names order
-        crcs = dict(zip(
-            [i for idxs, _, _ in chunks for i in idxs],
-            pass_stats.pop("shard_crcs", None) or [],
-        ))
+        crcs = dict(zip(order, pass_stats.pop("shard_crcs", None) or []))
         stats.update(pass_stats)
         stats["batch_volumes"] = len(base_file_names)
         stats["batch_groups"] = len(groups)
@@ -2461,6 +2456,28 @@ def stream_rebuild_ec_files_batch(
                 crcs.get(i, {}) for i in range(len(base_file_names))
             ]
     return results
+
+
+def _default_batch_codec(batch: int):
+    """The batch rebuild's codec where the caller names none: the mesh
+    codec on an accelerator; with none (or no jax), the host arm, since
+    the interpreted Pallas kernels lose to the host backends by orders
+    of magnitude (same grouping and gather, one matrix apply a work
+    item)."""
+    try:
+        import jax
+
+        on_host = all(d.platform == "cpu" for d in jax.devices())
+    except ImportError:
+        on_host = True
+    if not on_host:
+        return _default_mesh_codec(batch)
+    from seaweedfs_tpu.ec.codec import new_encoder
+
+    try:
+        return _HostBatchCodec(new_encoder(backend="native"))
+    except (ImportError, ValueError):
+        return _HostBatchCodec(new_encoder(backend="cpu"))
 
 
 def _rebuild_shape(tiles: int, survivors, targets, survivor_bytes: int) -> dict:
@@ -2513,6 +2530,7 @@ def _rebuild_batch_pass(
     chunks: list[tuple[list[str], tuple[int, ...], tuple[int, ...]]],
     codec, tile_bytes, stats, durable, want_crcs,
     reader_threads, writer_threads, groups: int,
+    remotes: list[dict], remote_report: Callable[[], dict] | None,
 ) -> None:
     """Every chunk of a call, (bases, survivors, targets) each, through
     ONE pass of the shell: the rebuild-side mirror of _stream_batch_chunk
@@ -2523,16 +2541,18 @@ def _rebuild_batch_pass(
     and pwrites the rebuilt target rows. One reservation and one latch
     for every output file of the call, and the call's abort contract:
     any failure removes every target file of every volume. `groups` is
-    the call's count of damage signatures, for the root span. Stats get
-    the volumes' rebuilt-shard CRCs in the pass's volume order."""
+    the call's count of damage signatures, for the root span; `remotes`
+    the volumes' remote readers in the pass's order, for _Gather. Stats
+    get the volumes' rebuilt-shard CRCs in the pass's volume order."""
     from seaweedfs_tpu.ec.ec_files import to_ext
 
     bases = [base for chunk_bases, _, _ in chunks for base in chunk_bases]
     survivors_of = [s for chunk_bases, s, _ in chunks for _ in chunk_bases]
     targets_of = [t for chunk_bases, _, t in chunks for _ in chunk_bases]
+    # a local survivor's file size is the volume's shard length
     sizes = [
-        os.path.getsize(base + to_ext(survivors[0]))
-        for base, survivors in zip(bases, survivors_of)
+        os.path.getsize(base + to_ext(next(i for i in survivors if i not in remote)))
+        for base, survivors, remote in zip(bases, survivors_of, remotes)
     ]
     outputs = [
         (base + to_ext(t), size)
@@ -2563,6 +2583,7 @@ def _rebuild_batch_pass(
             bases, codec.rs, survivors_of, targets_of, sizes, outputs,
             out_at, tile_bytes or BATCH_REBUILD_MIN_TILE_BYTES,
             stats, durable, want_crcs, reader_threads, writer_threads, groups,
+            remotes, remote_report,
         )
     # the round's span follows from what the driver can see, the widest
     # chunk it stacks and the ring the process keeps: no caller passes a
@@ -2597,7 +2618,10 @@ def _rebuild_batch_pass(
     held = min(p.codec.mesh.devices.size for p in plans)  # see _stream_batch_chunk
     items = [(c, r) for c, p in enumerate(plans) for r in range(len(p.step_of))]
     round_crcs: list[list] = [[None] * len(p.step_of) for p in plans]
-    op = _Op("ec_stream.rebuild_batch", True, _DEVICE_BUSY)
+    op = _Op("ec_stream.rebuild_batch", True, {**_DEVICE_BUSY, **_GATHER_BUSY})
+    gather = _Gather(bases, survivors_of, remotes, op.book, remote_report)
+    # rebuilt bytes of every write, from the writer pool
+    written: list[int] = []
 
     def fill(src, item, buf):
         c, r = item
@@ -2605,9 +2629,12 @@ def _rebuild_batch_pass(
         buf3 = buf[: p.b * DATA_SHARDS * p.width].reshape(
             p.b, DATA_SHARDS, p.width
         )
-        for v, step in enumerate(p.step_of[r]):
-            if step:  # else volume done: output discarded
-                src.read(p.v0 + v, r * tile_bytes, buf3[v, :, :step])
+        # a volume that is done has no read: its output is discarded
+        gather.read(src, [
+            (p.v0 + v, r * tile_bytes, buf3[v, :, :step])
+            for v, step in enumerate(p.step_of[r])
+            if step
+        ])
         return buf3
 
     def dispatch(item, buf3):
@@ -2679,15 +2706,19 @@ def _rebuild_batch_pass(
                     r * tile_bytes,
                 )
                 EC_REPAIR_BYTES_WRITTEN.inc(step)
+                written.append(step)
 
     def report(out, sp, whole):
         last = plans[-1].codec
         out["mesh"] = {**last.report(), "devices_per_round": held}
         sp.annotate("mesh", "x".join(map(str, last.mesh.devices.shape)))
-        _report_batch_rebuild(out, sp, len(bases), groups, tile_bytes, _rebuild_shape(
-            len(items), plans[0].survivors, max(targets_of, key=len),
-            DATA_SHARDS * sum(sizes),
-        ))
+        _report_batch_rebuild(out, sp, len(bases), groups, tile_bytes, {
+            **_rebuild_shape(
+                len(items), plans[0].survivors, max(targets_of, key=len),
+                DATA_SHARDS * sum(sizes),
+            ),
+            "rebuilt_bytes": sum(written), **gather.books(),
+        })
         if want_crcs and whole:
             out["shard_crcs"] = [
                 crcs
@@ -2702,7 +2733,7 @@ def _rebuild_batch_pass(
         items=items,
         slot_bytes=max(p.b * DATA_SHARDS * p.width for p in plans),
         outputs=outputs,
-        opened=lambda: _Survivors(bases, survivors_of),
+        opened=gather.opened,
         fill=fill,
         dispatch=dispatch,
         fetch=fetch,
@@ -2728,6 +2759,7 @@ def _rebuild_batch_pass_host(
     targets_of: list[tuple[int, ...]], sizes: list[int],
     outputs: list[tuple[str, int]], out_at: list[int], tile_bytes: int,
     stats, durable, want_crcs, reader_threads, writer_threads, groups: int,
+    remotes: list[dict], remote_report: Callable[[], dict] | None,
 ) -> None:
     """Host arm of the batch rebuild: one shared pipeline whose work
     items are per-(volume, tile) survivor gathers, decoded in the
@@ -2757,12 +2789,19 @@ def _rebuild_batch_pass_host(
     # (volume, offset, step, [crc per target]); append is GIL-atomic,
     # order restored by sorting at fold time
     crc_parts: list[tuple[int, int, int, list[int]]] = []
+    written: list[int] = []
+    pooled = len(items) > _HOST_INLINE_TILES
+    op = _Op("ec_stream.rebuild_batch", extra_busy=_GATHER_BUSY) if pooled else None
+    # inline: the one thread's books, and the fetch pool's remote_read_s
+    busy = {"read_s": 0.0, "compute_s": 0.0, "write_s": 0.0, **_GATHER_BUSY}
+    book = op.book if pooled else functools.partial(_charge, busy, threading.Lock())
+    gather = _Gather(bases, survivors_of, remotes, book, remote_report)
 
     def fill(src, item, buf):
         v, off = item
         step = min(tile_bytes, sizes[v] - off)
         tile = buf[: DATA_SHARDS * step].reshape(DATA_SHARDS, step)
-        src.read(v, off, tile)
+        gather.read(src, [(v, off, tile)])
         return tile
 
     def fetch(item, tile, _handle):
@@ -2785,24 +2824,28 @@ def _rebuild_batch_pass_host(
                 off,
             )
             EC_REPAIR_BYTES_WRITTEN.inc(tile.shape[1])
+            written.append(tile.shape[1])
 
     def report(out, sp, whole):
         out["codec_arm"] = "host"
-        _report_batch_rebuild(out, sp, len(bases), groups, tile_bytes, _rebuild_shape(
-            len(items), survivors_of[0], max(targets_of, key=len),
-            DATA_SHARDS * sum(sizes),
-        ))
+        _report_batch_rebuild(out, sp, len(bases), groups, tile_bytes, {
+            **_rebuild_shape(
+                len(items), survivors_of[0], max(targets_of, key=len),
+                DATA_SHARDS * sum(sizes),
+            ),
+            "rebuilt_bytes": sum(written), **gather.books(),
+        })
         if want_crcs and whole:
             out["shard_crcs"] = _fold_host_batch_crcs(targets_of, crc_parts)
 
     nbytes = sum(s * len(t) for s, t in zip(sizes, targets_of))
-    if len(items) > _HOST_INLINE_TILES:
-        return _Op("ec_stream.rebuild_batch").run(
+    if pooled:
+        return op.run(
             nbytes=nbytes,
             items=items,
             slot_bytes=DATA_SHARDS * tile_bytes,
             outputs=outputs,
-            opened=lambda: _Survivors(bases, survivors_of),
+            opened=gather.opened,
             fill=fill,
             dispatch=lambda item, tile: None,  # the writers decode
             fetch=fetch,
@@ -2815,14 +2858,13 @@ def _rebuild_batch_pass_host(
             reader_threads=reader_threads,
             writer_threads=writer_threads,
         )
-    busy = {"read_s": 0.0, "compute_s": 0.0, "write_s": 0.0}
     wall0 = time.perf_counter()
     buf = np.empty(DATA_SHARDS * tile_bytes, dtype=np.uint8)
     fds: list[int] = []
     ok = False
     with trace.span(
         "ec_stream.rebuild_batch", nbytes=nbytes
-    ) as sp, _Survivors(bases, survivors_of) as src:
+    ) as sp, gather.opened() as src:
         try:
             for path, size in outputs:
                 fds.append(

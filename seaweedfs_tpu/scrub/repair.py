@@ -407,9 +407,11 @@ class RepairScheduler:
     # ------------------------------------------------------------------
     def _run_ec_batch(self, tasks: list["RepairTask"]) -> None:
         """One batched ec_rebuild repair for N concurrent tasks — the
-        shell's do_ec_rebuild_batch groups same-node local-survivor
-        volumes through the BatchRebuild verb and falls back to the
-        single-volume flow for the rest, so per-task semantics (and
+        shell's do_ec_rebuild_batch groups the volumes of one rebuilder
+        (survivors local or fetched from their holders, so a lost
+        server's volumes too) through the BatchRebuild verb and falls
+        back to the single-volume flow for the rest, so per-task
+        semantics (and
         the scheduler's backoff on failure) are unchanged; only the
         dispatch is amortized. Whole-batch failure backs off every
         task: the next scan retries them (batched again if still

@@ -1330,8 +1330,9 @@ class VolumeServer:
         # double-mount) shards the cluster still has
         t0 = time.perf_counter()
         readers, close_readers, gather_report = self._remote_rebuild_readers(
-            req.volume_id, {i for i, p in enumerate(present) if p}
+            {req.volume_id: {i for i, p in enumerate(present) if p}}
         )
+        readers = readers[req.volume_id]
         # the master lookup, before the driver's clock starts
         st = {"lookup_s": round(time.perf_counter() - t0, 4)}
         try:
@@ -1387,28 +1388,35 @@ class VolumeServer:
         return pb.VolumeEcShardsRebuildResponse(rebuilt_shard_ids=rebuilt)
 
     def VolumeEcShardsBatchRebuild(self, req, context):
-        """Rebuild N volumes' missing shards, batched: volumes whose
-        survivors are ALL local and whose missing shards are missing
-        cluster-wide ride one sharded mesh decode program per tile
-        round (ec_files.rebuild_ec_files_batch, grouped there by
-        damage signature) — the RepairScheduler's answer to a node
-        loss surfacing many small volumes with identical damage at
-        once. Volumes that DON'T fit that shape (a "missing" shard is
-        mounted elsewhere — regenerating it here would double-mount —
-        or survivors must be rack-gathered) fall through to the
-        single-volume rebuild path per volume, so the verb is safe to
-        aim at any mix. Reuses the BatchGenerate message pair: ids in,
-        empty response (rebuilt ids are logged; callers recompute
-        presence, as ec.rebuild already does).
+        """Rebuild N volumes' missing shards, batched: every volume with
+        a shard missing rides one sharded mesh decode program per tile
+        round and damage signature (ec_files.rebuild_ec_files_batch,
+        grouped there), in ONE pass of the pipeline shell — the
+        RepairScheduler's answer to a node loss surfacing many volumes
+        with identical damage at once. The master names, one lookup a
+        volume, the other servers that hold the shards this one lacks:
+        those are remote survivors, fetched by the batch driver's own
+        gather from their holders over the readers of
+        _remote_rebuild_readers (the single verb's, one set of kept
+        connections for the whole call), and never targets (regenerating
+        a shard mounted elsewhere would double-mount it). A rack loss,
+        four survivors here and six on two other servers, is one group.
+        Reuses the BatchGenerate message pair: ids in, empty response
+        (rebuilt ids are logged; callers recompute presence, as
+        ec.rebuild already does).
 
         ONE `ec.batch_rebuild` report line a call, written after the
         publish whatever the mix was: the batch driver's books
         (`batch_volumes`, `batch_groups`, `batch_passes`, the rebuild's
-        shape, `h2d_s` / `launch_s`, the waits), `lookup_s`, the seconds of the
-        per-volume master lookups that sorted the volumes, and
-        `fell_through`, the volumes sent down the single-volume path
-        (each leaves an `ec.rebuild` line of its own; `batch_volumes` 0
-        where all did). The last two are attributes of the
+        shape, `h2d_s` / `launch_s`, the waits, the gather's remote
+        fields summed over the volumes: `remote_read_s`,
+        `survivor_bytes_remote`, `rebuilt_bytes`, `arbiter_wait_s`,
+        `remote_fetches`, `remote_fetches_dataplane`), `lookup_s`, the
+        seconds of the master lookups, and `fell_through`, the volumes
+        sent down the single-volume path (VolumeEcShardsRebuild): none
+        since the batch gathers remote survivors, so it reads 0 and
+        stays for the line's readers. `batch_volumes` 0 where the call
+        had nothing to mend. The last two are attributes of the
         `volume.ec_rebuild_batch` span too."""
         with trace.span(
             "volume.ec_rebuild_batch",
@@ -1417,8 +1425,8 @@ class VolumeServer:
         ) as sp:
             if sp:
                 sp.annotate("vids", list(req.volume_ids))
-            batch: list[tuple[int, str]] = []
-            lookup_s, fell_through = 0.0, 0
+            batch: dict[int, str] = {}
+            skips: dict[int, set[int]] = {}
             for vid in req.volume_ids:
                 ev = self.store.find_ec_volume(vid)
                 base = (
@@ -1427,74 +1435,44 @@ class VolumeServer:
                     else self._base_name("", vid)
                 )
                 present, missing = ec_files.shard_presence(base)
-                if not missing:
-                    continue
-                t0 = time.perf_counter()
-                remote = self._cluster_present_shards(vid)
-                lookup_s += time.perf_counter() - t0
-                if (
-                    sum(present) >= ec_files.DATA_SHARDS
-                    and not (set(missing) & remote)
-                ):
-                    batch.append((vid, base))
-                else:
-                    fell_through += 1
-                    self._ec_shards_rebuild(
-                        pb.VolumeEcShardsRebuildRequest(volume_id=vid),
-                        context,
-                        base=base,
-                    )
-            st: dict = {"batch_volumes": 0, "batch_groups": 0}
-            if batch:
-                try:
+                if missing:
+                    batch[vid] = base
+                    skips[vid] = {i for i, p in enumerate(present) if p}
+            t0 = time.perf_counter()
+            readers, close_readers, gather_report = self._remote_rebuild_readers(
+                skips
+            )
+            st: dict = {
+                "batch_volumes": 0, "batch_groups": 0,
+                "lookup_s": round(time.perf_counter() - t0, 4),
+            }
+            try:
+                if batch:
                     ec_files.rebuild_ec_files_batch(
-                        [base for _, base in batch],
+                        list(batch.values()),
                         codec=self._batch_codec(len(batch)),
                         durable=True,
                         stats=st,
                         want_crcs=True,
+                        remote_readers=[readers[vid] for vid in batch],
+                        remote_report=gather_report,
                     )
-                except ValueError as e:
-                    context.abort(
-                        grpc.StatusCode.FAILED_PRECONDITION, str(e)
-                    )
-            st["lookup_s"] = round(lookup_s, 4)
-            st["fell_through"] = fell_through
+            except ValueError as e:
+                context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
+            finally:
+                close_readers()
+            st["fell_through"] = 0
             if sp:
                 sp.annotate("lookup_s", st["lookup_s"])
-                sp.annotate("fell_through", fell_through)
+                sp.annotate("fell_through", 0)
             with self._ec_publish(
                 "batch_rebuild", list(req.volume_ids), st
             ):
                 for (vid, base), crcs in zip(
-                    batch, st.get("shard_crcs") or []
+                    batch.items(), st.get("shard_crcs") or []
                 ):
                     self._log_rebuild_crcs(vid, base, {"shard_crcs": crcs})
         return pb.VolumeEcShardsBatchGenerateResponse()
-
-    def _cluster_present_shards(self, vid: int) -> set[int]:
-        """Shard ids of `vid` mounted on OTHER nodes per the master —
-        shards the batch-rebuild arm must not regenerate locally (the
-        single verb's _remote_rebuild_readers exclusion, presence-only).
-        Empty on no master / lookup failure — then every locally
-        missing shard is a target, exactly what the single verb does on
-        the same no-master / failed-lookup arms."""
-        if not self.master:
-            return set()
-        try:
-            with rpc.dial(self._master_grpc()) as ch:
-                resp = rpc.master_stub(ch).LookupEcVolume(
-                    master_pb2.LookupEcVolumeRequest(volume_id=vid),
-                    timeout=5,
-                )
-        except grpc.RpcError:
-            return set()
-        me = self._self_urls()
-        return {
-            e.shard_id
-            for e in resp.shard_id_locations
-            if any(l.url not in me for l in e.locations)
-        }
 
     @staticmethod
     def _publish_ecc(base: str, crcs) -> None:
@@ -1532,16 +1510,19 @@ class VolumeServer:
             )
             self._publish_ecc(base, dict(crcs))
 
-    def _remote_rebuild_readers(self, vid: int, skip: set[int]):
-        """(readers, closer, report): shard id → read_into(offset, dest)
-        callables against holders learned from the master, for
-        survivors not in `skip` (the locally-present set); `dest` is the
-        row of the driver's ring slot the span belongs in, and the
-        return the bytes received there.
+    def _remote_rebuild_readers(self, skips: dict[int, set[int]]):
+        """(readers, closer, report) of one rebuild call: for each
+        volume id of `skips`, shard id → read_into(offset, dest)
+        callables against holders learned from the master (one lookup a
+        volume), for survivors not in the volume's skip set (the
+        locally-present ones); `dest` is the row of the driver's ring
+        slot the span belongs in, and the return the bytes received
+        there. A volume whose lookup fails has no readers.
 
         A span comes over the holder's HTTP data plane (GET
         /ec/shard/read: sendfile there, recv_into `dest` here; one kept
-        connection per fetch thread and holder) and over
+        connection per fetch thread and holder, whichever of the call's
+        volumes it fetches for) and over
         VolumeEcShardRead where that wire is not to be had, by what the
         reader can see: a process whose gRPC is mTLS makes no HTTP
         fetch; a holder that answers the route with 401 / 403 / 404 /
@@ -1553,28 +1534,29 @@ class VolumeServer:
         call these concurrently, and grpc channels are thread-safe.
 
         report() is what the readers have to say of the operation they
-        served, for its report line and root span: arbiter_wait_s, the
+        served, summed over its volumes, for its report line and root
+        span: arbiter_wait_s, the
         seconds their reads stood at the bandwidth arbiter
         (thread-seconds of the driver's fetch pool, inside its
         remote_read_s), remote_fetches, the spans they fetched, and
         remote_fetches_dataplane, those of them that came over HTTP."""
-        none = ({}, (lambda: None), dict)
-        if not self.master:
-            return none
-        try:
+        # volume id -> shard id -> the other servers that hold it
+        locations: dict[int, dict[int, list[str]]] = {vid: {} for vid in skips}
+        if self.master and skips:
+            me = self._self_urls()
             with rpc.dial(self._master_grpc()) as ch:
-                resp = rpc.master_stub(ch).LookupEcVolume(
-                    master_pb2.LookupEcVolumeRequest(volume_id=vid),
-                    timeout=5,
-                )
-        except grpc.RpcError:
-            return none
-        me = self._self_urls()
-        locations: dict[int, list[str]] = {}
-        for entry in resp.shard_id_locations:
-            urls = [l.url for l in entry.locations if l.url not in me]
-            if urls and entry.shard_id not in skip:
-                locations[entry.shard_id] = urls
+                for vid, skip in skips.items():
+                    try:
+                        resp = rpc.master_stub(ch).LookupEcVolume(
+                            master_pb2.LookupEcVolumeRequest(volume_id=vid),
+                            timeout=5,
+                        )
+                    except grpc.RpcError:
+                        continue
+                    for entry in resp.shard_id_locations:
+                        urls = [l.url for l in entry.locations if l.url not in me]
+                        if urls and entry.shard_id not in skip:
+                            locations[vid][entry.shard_id] = urls
         from seaweedfs_tpu.client.operation import _RawHTTPConnection
         from seaweedfs_tpu.stats.metrics import EC_REMOTE_FETCH
 
@@ -1620,7 +1602,7 @@ class VolumeServer:
         arbiter_waits: list[float] = []
         fetched: list[str] = []
 
-        def over_http(url, sid, offset, view, t_o, hop) -> int | None:
+        def over_http(url, vid, sid, offset, view, t_o, hop) -> int | None:
             """The span over `url`'s data plane into `view`: the bytes
             received, or None where the RPC is to take this fetch."""
             key = (threading.get_ident(), url)
@@ -1631,12 +1613,12 @@ class VolumeServer:
             if answered is not None:
                 answered.wait(t_o)
             try:
-                return fetch_http(key, url, sid, offset, view, t_o, hop)
+                return fetch_http(key, url, vid, sid, offset, view, t_o, hop)
             finally:
                 if answered is None:
                     asked[url].set()
 
-        def fetch_http(key, url, sid, offset, view, t_o, hop) -> int | None:
+        def fetch_http(key, url, vid, sid, offset, view, t_o, hop) -> int | None:
             with wires_lock:
                 if url in rpc_only:
                     return None
@@ -1689,7 +1671,7 @@ class VolumeServer:
                         rpc_only.add(url)
                 return None
 
-        def over_grpc(url, sid, offset, view, t_o, call_md) -> int:
+        def over_grpc(url, vid, sid, offset, view, t_o, call_md) -> int:
             got = 0
             for r in stub(url).VolumeEcShardRead(
                 pb.VolumeEcShardReadRequest(
@@ -1705,7 +1687,7 @@ class VolumeServer:
                 got = end
             return got
 
-        def make_reader(sid: int, urls: list[str]):
+        def make_reader(vid: int, sid: int, urls: list[str]):
             def read_into(offset: int, dest) -> int:
                 view = memoryview(dest).cast("B")
                 size = len(view)
@@ -1735,12 +1717,15 @@ class VolumeServer:
                             None
                             if no_dataplane
                             else over_http(
-                                url, sid, offset, view, t_o, dict(call_md or ())
+                                url, vid, sid, offset, view, t_o,
+                                dict(call_md or ()),
                             )
                         )
                         if got is None:
                             wire = "grpc"
-                            got = over_grpc(url, sid, offset, view, t_o, call_md)
+                            got = over_grpc(
+                                url, vid, sid, offset, view, t_o, call_md
+                            )
                     except (grpc.RpcError, TimeoutError) as e:
                         last = e
                         continue
@@ -1770,7 +1755,10 @@ class VolumeServer:
             }
 
         return (
-            {sid: make_reader(sid, urls) for sid, urls in locations.items()},
+            {
+                vid: {sid: make_reader(vid, sid, urls) for sid, urls in held.items()}
+                for vid, held in locations.items()
+            },
             closer,
             report,
         )

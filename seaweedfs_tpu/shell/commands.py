@@ -742,13 +742,17 @@ def do_ec_rebuild(env: CommandEnv, vid: int, out, apply: bool = True) -> list[in
 def do_ec_rebuild_batch(
     env: CommandEnv, vids: list[int], out, apply: bool = True
 ) -> dict[int, list[int]]:
-    """Rebuild missing shards for many volumes, batching volumes that
-    can rebuild from purely local survivors on the same node through
-    ONE VolumeEcShardsBatchRebuild verb (the mesh-batched decode —
-    the RepairScheduler's node-loss fan-in). Volumes that need a
-    rack-gather, have no >=10-local-shard holder, or whose batch verb
-    fails take the single-volume do_ec_rebuild path, so the result is
-    never worse than calling it in a loop. Returns {vid: rebuilt ids}."""
+    """Rebuild missing shards for many volumes, batching the volumes
+    that share a rebuilder through ONE VolumeEcShardsBatchRebuild verb
+    (the mesh-batched decode — the RepairScheduler's node-loss fan-in).
+    A volume's rebuilder is the holder of at least one of its shards
+    with the most free slots, as do_ec_rebuild picks one among all
+    nodes: the verb reads the survivors it holds and fetches the rest
+    from their holders (a rack loss batches as a drive loss does).
+    Volumes no node holds a shard of, a rebuilder with one volume to
+    mend, and the volumes of a batch verb that fails take the
+    single-volume do_ec_rebuild path, so the result is never worse
+    than calling it in a loop. Returns {vid: rebuilt ids}."""
     import grpc as _grpc
 
     nodes = ec_common.collect_ec_nodes(env)
@@ -763,13 +767,10 @@ def do_ec_rebuild_batch(
         if not apply:
             results[vid] = missing
             continue
-        # the batch arm needs one node already holding >= 10 shards of
-        # the volume (all survivors local, no seed copy)
+        # the batch arm's rebuilder holds a shard of the volume (no
+        # seed copy: a local survivor fixes the shard length)
         cands = [
-            n
-            for n in nodes
-            if vid in n.ec_shards
-            and len(n.local_shard_ids(vid)) >= ec_common.DATA_SHARDS
+            n for n in nodes if vid in n.ec_shards and n.local_shard_ids(vid)
         ]
         if not cands:
             leftovers.append(vid)
@@ -830,8 +831,9 @@ class EcRebuildBatch(Command):
     name = "ec.rebuild.batch"
     help = (
         "ec.rebuild.batch [-volumeIds 1,2,3] [-force] — rebuild many "
-        "EC volumes, batching same-node local-survivor rebuilds "
-        "through one mesh decode program per damage signature"
+        "EC volumes, batching the volumes of one rebuilder (survivors "
+        "local or fetched from their holders) through one mesh decode "
+        "program per damage signature"
     )
 
     def run(self, env, args, out):

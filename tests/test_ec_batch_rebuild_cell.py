@@ -7,8 +7,11 @@ three calls of the verb:
 
   mixed     two damage signatures in one call ([3, 10] lost on two
             volumes, [5] on two): two groups, nobody falls through
-  fallen    a volume whose "lost" shard is mounted on another server
-            goes down the single-volume path, beside two that batch
+  fallen    a volume with a shard mounted on another server rides the
+            batch beside two others: that shard is a survivor held
+            elsewhere, not a target
+  all_fallen  the same volume alone in its call: a batch of one
+  idle      a call with nothing to mend still leaves its line
   shell     `ec.rebuild.batch -force` finds the damage itself, makes
             the ONE call and mounts
 
@@ -279,10 +282,11 @@ def mended(tmp_path_factory, bench):
             fallen = observed(damage, batch_rebuild([vids[4], vids[0], vids[1]]))
             fallen["moved_shard_regenerated"] = os.path.exists(base[vids[4]] + ".ec07")
             mount(damage)
-            # all fallen: the one volume of the call goes the single-volume way
+            # the volume with a shard held elsewhere, alone in its call
             damage = {vids[4]: [2]}
             all_fallen = observed(damage, batch_rebuild([vids[4]]))
             mount(damage)
+            idle = observed({}, batch_rebuild([vids[0]]))
 
             # shell: the damage found from the master's topology
             damage = {vids[0]: DRIVE, vids[1]: DRIVE, vids[2]: [5]}
@@ -308,8 +312,8 @@ def mended(tmp_path_factory, bench):
         for vid, (fid, body) in needles.items():
             with urllib.request.urlopen(f"http://127.0.0.1:{a.port}/{fid}", timeout=30) as r:
                 read_back[vid] = r.read() == body
-        yield {"mixed": mixed, "fallen": fallen, "all_fallen": all_fallen, "shell": shell,
-               "vids": vids, "read_back": read_back}
+        yield {"mixed": mixed, "fallen": fallen, "all_fallen": all_fallen, "idle": idle,
+               "shell": shell, "vids": vids, "read_back": read_back}
     finally:
         logger.removeHandler(handler)
         trace.reset()
@@ -419,31 +423,39 @@ def test_the_dispatcher_annotates_its_transfer_and_its_launch(mended):
 
 
 def test_a_shard_mounted_elsewhere_falls_through_beside_the_batch(mended, bench):
+    """The shard the master names B for is a survivor held elsewhere:
+    the volume rides the batch beside the two others, as a signature of
+    its own, and only the shard that was lost is rebuilt. Its ten local
+    survivors suffice, so nothing crosses the wire."""
     got = mended["fallen"]
-    batch = _reports(bench, got, "batch_rebuild")
-    single = _reports(bench, got, "rebuild")
-    assert len(batch) == len(single) == 1
-    assert (batch[0]["batch_volumes"], batch[0]["batch_groups"]) == (2, 1)
-    assert batch[0]["fell_through"] == 1 and batch[0]["lookup_s"] > 0
-    # the single-volume verb rebuilt the one shard that was lost, and did
-    # not regenerate the one the cluster still has
-    assert single[0]["targets"] == 1 and not got["moved_shard_regenerated"]
+    (batch,) = _reports(bench, got, "batch_rebuild")
+    assert _reports(bench, got, "rebuild") == []
+    assert (batch["batch_volumes"], batch["batch_groups"], batch["batch_passes"]) == (3, 2, 1)
+    assert batch["fell_through"] == 0 and batch["lookup_s"] > 0
+    assert batch["survivor_bytes_remote"] == 0
+    assert batch["rebuilt_bytes"] == (2 + 2 + 1) * SHARD_BYTES
+    assert not got["moved_shard_regenerated"]
     handler = [s for s in got["spans"] if s["name"] == "volume.ec_rebuild_batch"]
-    assert len(handler) == 1 and handler[0]["annot"]["fell_through"] == "1"
-    assert [s["parent"] for s in got["spans"] if s["name"] == "ec_stream.rebuild"] == [
+    assert len(handler) == 1 and handler[0]["annot"]["fell_through"] == "0"
+    assert [s["parent"] for s in got["spans"] if s["name"] == "ec_stream.rebuild_batch"] == [
         handler[0]["span"]]
+    assert not [s for s in got["spans"] if s["name"] == "ec_stream.rebuild"]
 
 
 def test_a_call_whose_volumes_all_fall_through_still_leaves_its_line(mended, bench):
-    """No volume batched: the line says so (`batch_volumes` 0) and is
-    written all the same, after the single-volume verb's own, so that a
-    window never loses an operation's line."""
-    got = mended["all_fallen"]
+    """Nothing falls through now that the batch gathers survivors held
+    elsewhere (the volume with a shard on B, alone in its call, is a
+    batch of one), so the line's point is held where no volume is
+    batched: a call with nothing to mend says so (`batch_volumes` 0) and
+    is written all the same, so that a window never loses an
+    operation's line."""
+    (alone,) = _reports(bench, mended["all_fallen"], "batch_rebuild")
+    assert (alone["batch_volumes"], alone["fell_through"]) == (1, 0)
+    got = mended["idle"]
     lines = [ln for ln in got["lines"] if " report={" in ln]
-    assert [re.search(r"ec\.\w+", ln).group(0) for ln in lines] == [
-        "ec.rebuild", "ec.batch_rebuild"]
+    assert [re.search(r"ec\.\w+", ln).group(0) for ln in lines] == ["ec.batch_rebuild"]
     (batch,) = _reports(bench, got, "batch_rebuild")
-    assert batch == {"batch_volumes": 0, "batch_groups": 0, "fell_through": 1,
+    assert batch == {"batch_volumes": 0, "batch_groups": 0, "fell_through": 0,
                      "lookup_s": batch["lookup_s"], "publish_s": batch["publish_s"]}
     assert not [s for s in got["spans"] if s["name"] == "ec_stream.rebuild_batch"]
 

@@ -410,7 +410,8 @@ def test_a_kept_connection_the_holder_let_go_is_dialed_again(rack, monkeypatch):
         real(self, host, port, timeout)
 
     monkeypatch.setattr(operation._RawHTTPConnection, "__init__", dial)
-    readers, closer, report = rack.a._remote_rebuild_readers(VID, set(PLACEMENT["A"]))
+    readers, closer, report = rack.a._remote_rebuild_readers({VID: set(PLACEMENT["A"])})
+    readers = readers[VID]
     try:
         row = np.zeros(TILE, dtype=np.uint8)
         with open(rack.origin + ec_files.to_ext(7), "rb") as f:
@@ -440,7 +441,8 @@ def test_a_holder_that_does_not_answer_in_time_fails_the_fetch_as_a_timeout(rack
     monkeypatch.setitem(rack.c.store.find_ec_volume(VID).shards, 2, types.SimpleNamespace(
         size=SHARD_BYTES, read_at=lambda offset, size: rpcs.append(offset) or b""))
     with deadline.scope(deadline.Deadline.after(0.5)):
-        readers, closer, _ = rack.a._remote_rebuild_readers(VID, set(PLACEMENT["A"]))
+        readers, closer, _ = rack.a._remote_rebuild_readers({VID: set(PLACEMENT["A"])})
+    readers = readers[VID]
     try:
         row = np.zeros(TILE, dtype=np.uint8)
         t0 = time.monotonic()
@@ -460,7 +462,8 @@ def test_readers_under_more_threads_than_cores_lose_no_fetch(rack):
     remote = PLACEMENT["C"] + PLACEMENT["D"]
     files = {sid: np.fromfile(rack.origin + ec_files.to_ext(sid), dtype=np.uint8)
              for sid in remote}
-    readers, closer, report = rack.a._remote_rebuild_readers(VID, set(PLACEMENT["A"]))
+    readers, closer, report = rack.a._remote_rebuild_readers({VID: set(PLACEMENT["A"])})
+    readers = readers[VID]
     span, each, wrong = 64 * KIB, 12, []
 
     def work(n: int) -> None:
@@ -491,7 +494,8 @@ def test_readers_fill_the_row_they_are_given(rack):
     """The contract itself, off the driver: read_into(offset, dest)
     returns the bytes it placed in `dest`, a span that runs past the
     shard is the ValueError it always was, the closer shuts the wires."""
-    readers, closer, report = rack.a._remote_rebuild_readers(VID, set(PLACEMENT["A"]))
+    readers, closer, report = rack.a._remote_rebuild_readers({VID: set(PLACEMENT["A"])})
+    readers = readers[VID]
     try:
         assert sorted(readers) == sorted(PLACEMENT["C"] + PLACEMENT["D"])
         row = np.zeros(TILE, dtype=np.uint8)
